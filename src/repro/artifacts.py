@@ -13,12 +13,16 @@ from __future__ import annotations
 
 import subprocess
 import time
+from pathlib import Path
 
 
 def git_sha() -> str:
     """Short HEAD sha, with a -dirty marker when the tree has uncommitted
     changes — numbers measured on a dirty tree must not be attributed to
-    the clean commit."""
+    the clean commit.  "unknown" outside a git checkout (an exported
+    tree), without starting a git process."""
+    if not (Path(__file__).resolve().parents[2] / ".git").exists():
+        return "unknown"
     try:
         sha = subprocess.run(
             ["git", "rev-parse", "--short", "HEAD"],

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.sparsity import BlockPattern, SparsityConfig, make_block_pattern
+from repro.parallel import hints
 
 Params = dict[str, Any]
 
@@ -276,6 +277,11 @@ def apply(params: Params, x: jax.Array, *, engine: str = "auto",
     if resolve_engine(engine) == "pallas":
         from repro.kernels import ops  # local import: kernels optional at runtime
         if UPDATE_HYP_LEAF in params:
+            if hints.current_mesh() is not None:
+                raise ValueError(
+                    "fused BP+UP under a device mesh: each device would "
+                    "apply the update from its own partial gradient — "
+                    "run the two-pass step (ArchConfig.fused_update off)")
             return ops.junction_train_update(
                 x, params["w"], params["idx"], params["rev_ob"],
                 params["rev_t"], params["rev_cnt"], bias=params.get("b"),
@@ -290,9 +296,16 @@ def apply(params: Params, x: jax.Array, *, engine: str = "auto",
                 act=act, w_scale=params.get("w_scale"),
                 x_scale=params.get("x_scale"), qfmt=params.get("qfmt"),
                 qlut=params.get("qlut"))
-        return ops.junction_matmul(
-            x, params["w"], params["idx"], params["rev_ob"], params["rev_t"],
-            params["rev_cnt"], bias=params.get("b"), act=act)
+
+        def junction(x, w, idx, rev_ob, rev_t, rev_cnt, *bias):
+            return ops.junction_matmul(x, w, idx, rev_ob, rev_t, rev_cnt,
+                                       bias=bias[0] if bias else None,
+                                       act=act)
+
+        return hints.shard_rows(
+            junction, x, params["w"], params["idx"], params["rev_ob"],
+            params["rev_t"], params["rev_cnt"],
+            *((params["b"],) if "b" in params else ()))
     if quantized:
         from repro.core import quantize as qz  # local: avoids import cycle
         return qz.apply_quant_jnp(params, x, act=act)

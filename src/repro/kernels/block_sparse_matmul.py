@@ -69,7 +69,7 @@ ONE block pattern that rides once in scalar prefetch — the paper's
   Accumulator slots are fp32 even for bf16 params.
 
   With ``with_health=True`` the update kernels additionally emit a tiny
-  **non-aliased** ``[E, 1]`` int32 health output — the in-kernel
+  **non-aliased** ``[E, 1, 128]`` int32 health output — the in-kernel
   divergence detector.  Because the in-place update means a non-finite
   ``dw`` silently destroys the parameter state (there is no HBM gradient
   to inspect downstream), the flush epilogue OR-reduces ``isfinite``
@@ -77,8 +77,9 @@ ONE block pattern that rides once in scalar prefetch — the paper's
   kernel, plus the bias update for biased layers) and accumulates a
   per-unit count of bad (e, ob) tiles: ``health[e] > 0`` ⇔ unit e wrote
   at least one non-finite parameter tile this step.  The slot is a
-  single revisited ``(1, 1)`` block per unit (zeroed at the first
-  (ob, m) step, written only at flushes) — one VMEM compare per tile,
+  single revisited ``(1, 1, 128)`` lane row per unit (zeroed at the
+  first (ob, m) step, written only at flushes; a vector block because the
+  TPU cannot store a scalar to VMEM) — one VMEM compare per tile,
   no gradient materialization, and the parameter outputs' aliasing
   contract is untouched.  ``ops.junction_train_update`` surfaces it as
   the cotangent of a dummy ``[E]`` health operand; ``train/steps.py``
@@ -411,7 +412,7 @@ def fwd(x, w, idx, bias, *, act: str = "none", bm: int | None = None,
                 acc = acc + jnp.dot(xk, w_ref[0, j, k],
                                     preferred_element_type=jnp.float32)
             acc_ref[:, j * bs:(j + 1) * bs] = acc
-        s = acc_ref[...] + b_ref[...].astype(jnp.float32)
+        s = acc_ref[...] + b_ref[0].astype(jnp.float32)
         if save_pre:
             rest[1][0] = s.astype(rest[1].dtype)
         o_ref[0] = act_fwd(s, act).astype(o_ref.dtype)
@@ -425,6 +426,7 @@ def fwd(x, w, idx, bias, *, act: str = "none", bm: int | None = None,
 
     outs = pl.pallas_call(
         fwd_kernel,
+        name="junction_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(E, M // bm, nob // bn),
@@ -433,14 +435,14 @@ def fwd(x, w, idx, bias, *, act: str = "none", bm: int | None = None,
                 pl.BlockSpec((1, bm, nib * bs), lambda e, m, o, idx: (e, m, 0)),
                 pl.BlockSpec((1, bn, kb, bs, bs),
                              lambda e, m, o, idx: (e, o, 0, 0, 0)),
-                pl.BlockSpec((1, bn * bs), lambda e, m, o, idx: (e, o)),
+                pl.BlockSpec((1, 1, bn * bs), lambda e, m, o, idx: (e, 0, o)),
             ],
             out_specs=out_specs,
             scratch_shapes=[pltpu.VMEM((bm, bn * bs), jnp.float32)],
         ),
         out_shape=out_shape,
         interpret=interpret,
-    )(idx, x, w, bias)
+    )(idx, x, w, bias.reshape(E, 1, -1))
     return (outs[0], outs[1]) if save_pre else (outs[0], None)
 
 
@@ -496,6 +498,7 @@ def gated_fwd(x, wg, wi, idx, *, bm: int | None = None,
 
     outs = pl.pallas_call(
         gated_fwd_kernel,
+        name="junction_gated_fwd",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(E, M // bm, nob // bn),
@@ -569,12 +572,13 @@ def fwd_int8(x, wq, idx, w_scale, bias, *, act: str = "none",
                 acc = acc + p.astype(jnp.float32) * (
                     sx * sc_ref[e, ob0 + j, k])
             acc_ref[:, j * bs:(j + 1) * bs] = acc
-        s = acc_ref[...] + b_ref[...].astype(jnp.float32)
+        s = acc_ref[...] + b_ref[0].astype(jnp.float32)
         o_ref[0] = act_fwd(s, act).astype(o_ref.dtype)
 
     prefetch = (idx, w_scale) + ((x_scale,) if has_xs else ())
     out = pl.pallas_call(
         fwd_int8_kernel,
+        name="junction_fwd_int8",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(E, M // bm, nob // bn),
@@ -582,7 +586,7 @@ def fwd_int8(x, wq, idx, w_scale, bias, *, act: str = "none",
                 pl.BlockSpec((1, bm, nib * bs), lambda e, m, o, *_: (e, m, 0)),
                 pl.BlockSpec((1, bn, kb, bs, bs),
                              lambda e, m, o, *_: (e, o, 0, 0, 0)),
-                pl.BlockSpec((1, bn * bs), lambda e, m, o, *_: (e, o)),
+                pl.BlockSpec((1, 1, bn * bs), lambda e, m, o, *_: (e, 0, o)),
             ],
             out_specs=[pl.BlockSpec((1, bm, bn * bs),
                                     lambda e, m, o, *_: (e, m, o))],
@@ -590,7 +594,7 @@ def fwd_int8(x, wq, idx, w_scale, bias, *, act: str = "none",
         ),
         out_shape=[jax.ShapeDtypeStruct((E, M, nob * bs), x.dtype)],
         interpret=interpret,
-    )(*prefetch, x, wq, bias)
+    )(*prefetch, x, wq, bias.reshape(E, 1, -1))
     return out[0]
 
 
@@ -632,7 +636,7 @@ def fwd_fxp(x, wq, idx, qfmt, lut, bias, *, bm: int | None = None,
         half = jnp.left_shift(jnp.int32(1), bf - 1)
         s = jnp.right_shift(acc_ref[...] + half, bf)   # round half up
         s = jnp.clip(s, -lim, lim - 1)                 # saturating adder
-        bcode = jnp.clip(jnp.round(b_ref[...].astype(jnp.float32) * scale),
+        bcode = jnp.clip(jnp.round(b_ref[0].astype(jnp.float32) * scale),
                          -lim, lim - 1).astype(jnp.int32)
         s = jnp.clip(s + bcode, -lim, lim - 1)         # q_add
         o_ref[0] = jnp.take(lut_ref[...], jnp.bitwise_and(s, T - 1),
@@ -640,6 +644,7 @@ def fwd_fxp(x, wq, idx, qfmt, lut, bias, *, bm: int | None = None,
 
     out = pl.pallas_call(
         fwd_fxp_kernel,
+        name="junction_fwd_fxp",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(E, M // bm, nob // bn),
@@ -647,7 +652,7 @@ def fwd_fxp(x, wq, idx, qfmt, lut, bias, *, bm: int | None = None,
                 pl.BlockSpec((1, bm, nib * bs), lambda e, m, o, *_: (e, m, 0)),
                 pl.BlockSpec((1, bn, kb, bs, bs),
                              lambda e, m, o, *_: (e, o, 0, 0, 0)),
-                pl.BlockSpec((1, bn * bs), lambda e, m, o, *_: (e, o)),
+                pl.BlockSpec((1, 1, bn * bs), lambda e, m, o, *_: (e, 0, o)),
                 # the whole activation table, VMEM-resident every step
                 pl.BlockSpec((T,), lambda e, m, o, *_: (0,)),
             ],
@@ -657,7 +662,7 @@ def fwd_fxp(x, wq, idx, qfmt, lut, bias, *, bm: int | None = None,
         ),
         out_shape=[jax.ShapeDtypeStruct((E, M, nob * bs), x.dtype)],
         interpret=interpret,
-    )(idx, qfmt, x, wq, bias, lut)
+    )(idx, qfmt, x, wq, bias.reshape(E, 1, -1), lut)
     return out[0]
 
 
@@ -714,6 +719,7 @@ def gated_fwd_int8(x, wgq, wiq, idx, wg_scale, wi_scale, *, x_scale=None,
     prefetch = (idx, wg_scale, wi_scale) + ((x_scale,) if has_xs else ())
     out = pl.pallas_call(
         gated_fwd_int8_kernel,
+        name="junction_gated_fwd_int8",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(E, M // bm, nob // bn),
@@ -854,11 +860,12 @@ def dx(dy, w, rev_ob, rev_t, rev_cnt, res, *, act: str = "none",
         in_specs.append(pl.BlockSpec((1, bm, nob * bs),
                                      lambda e, m, i, *_: (e, m, 0)))
         inputs.append(res)
-    in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     inputs.append(w_flat)
 
     return pl.pallas_call(
         dx_kernel,
+        name="junction_dx",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(E, M // bm, nib),
@@ -927,9 +934,10 @@ def gated_dx(dh, wg, wi, rev_ob, rev_t, rev_cnt, g, u, *,
         o_ref[0] = acc.astype(o_ref.dtype)
 
     row = pl.BlockSpec((1, bm, nob * bs), lambda e, m, i, *_: (e, m, 0))
-    hbm = pl.BlockSpec(memory_space=pltpu.ANY)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
         gated_dx_kernel,
+        name="junction_gated_dx",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(E, M // bm, nib),
@@ -998,7 +1006,7 @@ def dw(x, dy, idx, res, *, act: str = "none", with_bias: bool = True,
         def _flush():
             dw_ref[...] = accw_ref[...][None, None]
             if with_bias:
-                db_ref[...] = accb_ref[...][None]
+                db_ref[0] = accb_ref[...]
 
     in_specs = [pl.BlockSpec((1, bm, bs), lambda e, o, m, idx: (e, m, o))]
     inputs = [dy]
@@ -1016,12 +1024,13 @@ def dw(x, dy, idx, res, *, act: str = "none", with_bias: bool = True,
     out_shape = [jax.ShapeDtypeStruct((E, nob, kb, bs, bs), jnp.float32)]
     scratch = [pltpu.VMEM((kb, bs, bs), jnp.float32)]
     if with_bias:
-        out_specs.append(pl.BlockSpec((1, 1, bs), lambda e, o, m, idx: (e, o, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((E, nob, bs), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, bs), lambda e, o, m, idx: (e, 0, o)))
+        out_shape.append(jax.ShapeDtypeStruct((E, 1, nob * bs), jnp.float32))
         scratch.append(pltpu.VMEM((1, bs), jnp.float32))
 
     outs = pl.pallas_call(
         dw_kernel,
+        name="junction_dw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(E, nob, nm),
@@ -1089,6 +1098,7 @@ def gated_dw(x, dh, idx, g, u, *, bm: int | None = None,
     wout = pl.BlockSpec((1, 1, kb, bs, bs), lambda e, o, m, idx: (e, o, 0, 0, 0))
     outs = pl.pallas_call(
         gated_dw_kernel,
+        name="junction_gated_dw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(E, nob, nm),
@@ -1106,6 +1116,22 @@ def gated_dw(x, dh, idx, g, u, *, bm: int | None = None,
 
 # --------------------------------------------------- fused BP+UP (update_dw)
 N_SCALAR_PREFETCH_UPDATE = 2    # (idx, hyp) — alias indices count these
+
+# The health detector's output is one int32 lane row per unit, [E, 1, 128]:
+# a vector-shaped block the TPU can store to (Mosaic refuses scalar stores
+# to VMEM), revisited across every (ob, m) step of unit e.  Every lane of
+# the row holds the same count; the wrappers return lane 0 as the [E] flags.
+HEALTH_LANES = 128
+HEALTH_SPEC = pl.BlockSpec((1, 1, HEALTH_LANES), lambda e, o, m, *_: (e, 0, 0))
+
+
+def _health_shape(E: int):
+    return jax.ShapeDtypeStruct((E, 1, HEALTH_LANES), jnp.int32)
+
+
+def _flag_unhealthy(health_ref, ok):
+    """Count one bad (e, ob) tile into unit e's health row unless ``ok``."""
+    health_ref[...] += jnp.where(ok, 0, 1).astype(jnp.int32)
 
 
 def normalize_hyp(hyp, E: int, *, name: str = "hyp"):
@@ -1127,6 +1153,17 @@ def normalize_hyp(hyp, E: int, *, name: str = "hyp"):
             f"[{', '.join(HYP_COLS)}] row, or a per-unit [E={E}, 2] / "
             f"[E={E}, {HYP_K}] table, got {hyp.shape}")
     return hyp
+
+
+def _decay_power(b, t):
+    """``b ** t`` for a decay rate b in [0, 1] and a step count t >= 0, as
+    a (1, 1) vector: Mosaic has no scalar or vector ``powf``, so the power
+    is ``exp(t * log b)``, with ``pow(b, 0) = 1`` and ``pow(0, t) = 0``
+    selected exactly (the all-zero-row freeze relies on the first)."""
+    bv = jnp.full((1, 1), b, jnp.float32)
+    tv = jnp.full((1, 1), t, jnp.float32)
+    p = jnp.exp(tv * jnp.log(jnp.where(bv == 0.0, 1.0, bv)))
+    return jnp.where(tv == 0.0, 1.0, jnp.where(bv == 0.0, 0.0, p))
 
 
 def _epilogue_step(h, acc, w32, mom, vel, with_health):
@@ -1155,8 +1192,8 @@ def _epilogue_step(h, acc, w32, mom, vel, with_health):
     m1 = b1 * mom + (1.0 - b1) * g
     v2 = b2 * vel + (1.0 - b2) * jnp.square(g)
     t = h(COL_T)
-    c1 = 1.0 - jnp.power(b1, t)
-    c2 = 1.0 - jnp.power(b2, t)
+    c1 = 1.0 - _decay_power(b1, t)
+    c2 = 1.0 - _decay_power(b2, t)
     c1 = jnp.where(c1 == 0.0, 1.0, c1)
     c2 = jnp.where(c2 == 0.0, 1.0, c2)
     den = jnp.sqrt(v2 / c2) + h(COL_EPS)
@@ -1191,7 +1228,7 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
     accumulation order matches the two-pass path exactly (parity to
     fp32 round-off).
 
-    ``with_health=True`` adds a tiny non-aliased ``[E, 1]`` int32 output
+    ``with_health=True`` adds a tiny non-aliased ``[E]`` int32 output
     riding the same flush: each (e, ob) epilogue OR-reduces
     ``isfinite`` over the accumulator tiles it just wrote (both m and v
     for Adam, and the bias update for biased layers) and accumulates one
@@ -1257,7 +1294,7 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
             # health slot e is revisited across every (o, m) step: init once
             @pl.when(jnp.logical_and(o == 0, m == 0))
             def _zero_health():
-                health_ref[0, 0] = 0
+                health_ref[...] = jnp.zeros(health_ref.shape, jnp.int32)
 
         if has_res:
             grad = act_bwd(res_ref[0].astype(jnp.float32), act)
@@ -1289,18 +1326,18 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
             new_w_ref[0, 0] = new_w32.astype(new_w_ref.dtype)
             if with_bias:
                 new_b32, nmb, nvb, okb = _epilogue_step(
-                    h, accb_ref[...], b_ref[...].astype(jnp.float32),
-                    mom_b_ref[...] if has_mom else None,
-                    vel_b_ref[...] if has_vel else None, with_health)
+                    h, accb_ref[...], b_ref[0].astype(jnp.float32),
+                    mom_b_ref[0] if has_mom else None,
+                    vel_b_ref[0] if has_vel else None, with_health)
                 if has_mom:
-                    new_mom_b_ref[...] = nmb
+                    new_mom_b_ref[0] = nmb
                 if has_vel:
-                    new_vel_b_ref[...] = nvb
-                new_b_ref[...] = new_b32.astype(new_b_ref.dtype)
+                    new_vel_b_ref[0] = nvb
+                new_b_ref[0] = new_b32.astype(new_b_ref.dtype)
                 if with_health:
                     ok = jnp.logical_and(ok, okb)
             if with_health:
-                health_ref[0, 0] += jnp.where(ok, 0, 1).astype(jnp.int32)
+                _flag_unhealthy(health_ref, ok)
 
     in_specs = [pl.BlockSpec((1, bm, bs), lambda e, o, m, *_: (e, m, o))]
     inputs = [dy]
@@ -1314,7 +1351,9 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
         inputs.append(x)
 
     wspec = pl.BlockSpec((1, 1, kb, bs, bs), lambda e, o, m, *_: (e, o, 0, 0, 0))
-    bspec = pl.BlockSpec((1, bs), lambda e, o, m, *_: (e, o))
+    # per-unit bias operands ride as [E, 1, N]: the block's last two dims
+    # (1, bs) then equal / tile the array's for every E
+    bspec = pl.BlockSpec((1, 1, bs), lambda e, o, m, *_: (e, 0, o))
     aliases: dict[int, int] = {}
     out_specs, out_shape = [], []
 
@@ -1333,16 +1372,14 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
     if has_vel:
         alias_io(vel, wspec)
     if with_bias:
-        alias_io(b, bspec)
+        alias_io(b.reshape(E, 1, -1), bspec)
         if has_mom:
-            alias_io(mom_b, bspec)
+            alias_io(mom_b.reshape(E, 1, -1), bspec)
         if has_vel:
-            alias_io(vel_b, bspec)
+            alias_io(vel_b.reshape(E, 1, -1), bspec)
     if with_health:
-        # non-aliased [E, 1] detector output: one slot per unit, revisited
-        # across every (ob, m) step of that unit
-        out_specs.append(pl.BlockSpec((1, 1), lambda e, o, m, *_: (e, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((E, 1), jnp.int32))
+        out_specs.append(HEALTH_SPEC)
+        out_shape.append(_health_shape(E))
 
     scratch = [pltpu.VMEM((kb, bs, bs), jnp.float32)]
     if with_bias:
@@ -1350,6 +1387,7 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
 
     outs = pl.pallas_call(
         fused_update_dw,
+        name="junction_update_dw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=N_SCALAR_PREFETCH_UPDATE,
             grid=(E, nob, nm),
@@ -1365,10 +1403,11 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
     new_w = outs.pop(0)
     new_mom = outs.pop(0) if has_mom else None
     new_vel = outs.pop(0) if has_vel else None
-    new_b = outs.pop(0) if with_bias else None
-    new_mom_b = outs.pop(0) if (has_mom and with_bias) else None
-    new_vel_b = outs.pop(0) if (has_vel and with_bias) else None
-    health = outs.pop(0) if with_health else None
+    unit_rows = lambda a: a.reshape(E, -1)
+    new_b = unit_rows(outs.pop(0)) if with_bias else None
+    new_mom_b = unit_rows(outs.pop(0)) if (has_mom and with_bias) else None
+    new_vel_b = unit_rows(outs.pop(0)) if (has_vel and with_bias) else None
+    health = outs.pop(0)[:, 0, 0] if with_health else None
     return new_w, new_b, new_mom, new_mom_b, new_vel, new_vel_b, health
 
 
@@ -1383,7 +1422,7 @@ def update_gated_dw(x, dh, idx, g, u, wg, wi, mg, mi, hyp, *, vg=None,
     hyp is the per-unit ``[E, HYP_K]`` table (any shape ``normalize_hyp``
     accepts), row ``e`` read in the epilogue; the slots select the
     optimizer statically — mg/mi alone → SGD(+momentum), plus vg/vi →
-    Adam.  ``with_health=True`` appends the non-aliased ``[E, 1]``
+    Adam.  ``with_health=True`` appends the non-aliased ``[E]``
     int32 divergence detector (see ``update_dw``): the epilogue checks
     BOTH branch update tiles for non-finites."""
     E, M, _ = x.shape
@@ -1433,7 +1472,7 @@ def update_gated_dw(x, dh, idx, g, u, wg, wi, mg, mi, hyp, *, vg=None,
         if with_health:
             @pl.when(jnp.logical_and(o == 0, m == 0))
             def _zero_health():
-                health_ref[0, 0] = 0
+                health_ref[...] = jnp.zeros(health_ref.shape, jnp.int32)
 
         dhb = dh_ref[0].astype(jnp.float32)
         gb = g_ref[0].astype(jnp.float32)
@@ -1469,8 +1508,7 @@ def update_gated_dw(x, dh, idx, g, u, wg, wi, mg, mi, hyp, *, vg=None,
             new_wg_ref[0, 0] = new_g32.astype(new_wg_ref.dtype)
             new_wi_ref[0, 0] = new_i32.astype(new_wi_ref.dtype)
             if with_health:
-                ok = jnp.logical_and(okg, oki)
-                health_ref[0, 0] += jnp.where(ok, 0, 1).astype(jnp.int32)
+                _flag_unhealthy(health_ref, jnp.logical_and(okg, oki))
 
     row = pl.BlockSpec((1, bm, bs), lambda e, o, m, *_: (e, m, o))
     in_specs = [row, row, row]
@@ -1500,11 +1538,12 @@ def update_gated_dw(x, dh, idx, g, u, wg, wi, mg, mi, hyp, *, vg=None,
         alias_io(vg)
         alias_io(vi)
     if with_health:
-        out_specs.append(pl.BlockSpec((1, 1), lambda e, o, m, *_: (e, 0)))
-        out_shape.append(jax.ShapeDtypeStruct((E, 1), jnp.int32))
+        out_specs.append(HEALTH_SPEC)
+        out_shape.append(_health_shape(E))
 
     outs = pl.pallas_call(
         fused_update_gated_dw,
+        name="junction_update_gated_dw",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=N_SCALAR_PREFETCH_UPDATE,
             grid=(E, nob, nm),
@@ -1524,5 +1563,5 @@ def update_gated_dw(x, dh, idx, g, u, wg, wi, mg, mi, hyp, *, vg=None,
     new_mi = outs.pop(0) if has_mom else None
     new_vg = outs.pop(0) if has_vel else None
     new_vi = outs.pop(0) if has_vel else None
-    health = outs.pop(0) if with_health else None
+    health = outs.pop(0)[:, 0, 0] if with_health else None
     return new_wg, new_wi, new_mg, new_mi, new_vg, new_vi, health

@@ -114,6 +114,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     scale = float(1.0 / (D ** 0.5))
     out = pl.pallas_call(
         functools.partial(_kernel, sk_p // bk, scale, causal, window, Sk),
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda h, i, j: (h, i, 0)),
@@ -146,25 +147,50 @@ def mha(q, k, v, *, causal: bool = True, window: int = 0,
 
 
 # ===================================================== paged decode kernel
+def _head_mask(hkv: int, hd: int):
+    """[Hkv, Hkv*D] bool: row h marks the lanes of kv head h in the merged
+    heads x head_dim row."""
+    d = hd // hkv
+    head = jax.lax.broadcasted_iota(jnp.int32, (hkv, hd), 0) * d
+    lane = jax.lax.broadcasted_iota(jnp.int32, (hkv, hd), 1)
+    return jnp.logical_and(lane >= head, lane < head + d)
+
+
 def _decode_kernel(maxp: int, ps: int, hkv: int, scale: float,
                    pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                   kbuf, vbuf, sems, m_s, l_s, acc_s):
+                   kbuf, vbuf, sems, qbd_s, m_s, l_s, acc_s):
+    """One slot (grid axis 0) against its pages (axis 1).  Every query row
+    r and kv head h is one row r*Hkv + h of the softmax state; the queries
+    are held block-diagonally (row r*Hkv + h keeps query r's head-h lanes
+    and zeros elsewhere), so a page's scores for all heads are one matmul
+    against the merged [ps, Hkv*D] page and no lane slice at a head
+    boundary (80 lanes for stablelm) ever happens."""
     b = pl.program_id(0)
     j = pl.program_id(1)
     n = len_ref[b]
+    rep, hd = q_ref.shape[1], q_ref.shape[2]
+    cd = qbd_s.dtype
+    # bf16 operands: one MXU pass multiplies exactly and accumulates in
+    # f32; f32 operands ask for f32 contractions
+    prec = (jax.lax.Precision.HIGHEST if cd == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+
+    def copies(buf, page):
+        pid = pt_ref[b, page]
+        return (pltpu.make_async_copy(k_hbm.at[pid], kbuf.at[buf],
+                                      sems.at[buf, 0]),
+                pltpu.make_async_copy(v_hbm.at[pid], vbuf.at[buf],
+                                      sems.at[buf, 1]))
 
     def start(buf, page):
-        pid = pt_ref[b, page]
-        pltpu.make_async_copy(k_hbm.at[pid], kbuf.at[buf], sems.at[buf, 0]).start()
-        pltpu.make_async_copy(v_hbm.at[pid], vbuf.at[buf], sems.at[buf, 1]).start()
-
-    def wait(buf, page):
-        pid = pt_ref[b, page]
-        pltpu.make_async_copy(k_hbm.at[pid], kbuf.at[buf], sems.at[buf, 0]).wait()
-        pltpu.make_async_copy(v_hbm.at[pid], vbuf.at[buf], sems.at[buf, 1]).wait()
+        for c in copies(buf, page):
+            c.start()
 
     @pl.when(j == 0)
     def _init():
+        q = q_ref[0].astype(jnp.float32)                       # [rep, hd]
+        qbd = jnp.where(_head_mask(hkv, hd)[None], q[:, None, :], 0.0)
+        qbd_s[...] = qbd.reshape(rep * hkv, hd).astype(cd)
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
@@ -178,29 +204,32 @@ def _decode_kernel(maxp: int, ps: int, hkv: int, scale: float,
 
     @pl.when(j * ps < n)
     def _compute():
-        wait(j % 2, j)
-        q = q_ref[0].astype(jnp.float32)              # [Hkv, rep, D]
-        kp = kbuf[j % 2].astype(jnp.float32)          # [ps, Hkv, D]
-        vp = vbuf[j % 2].astype(jnp.float32)
-        rep = q.shape[1]
-        kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (rep, ps), 1)
-        valid = kpos < n
-        for h in range(hkv):
-            s = jax.lax.dot_general(q[h], kp[:, h],
-                                    (((1,), (1,)), ((), ()))) * scale  # [rep, ps]
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev, l_prev, acc_prev = m_s[h], l_s[h], acc_s[h]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-            p = jnp.exp(s - m_new[:, None])
-            corr = jnp.exp(m_prev - m_new)
-            m_s[h] = m_new
-            l_s[h] = l_prev * corr + jnp.sum(p, axis=1)
-            acc_s[h] = acc_prev * corr[:, None] + jax.lax.dot(p, vp[:, h])
+        for c in copies(j % 2, j):
+            c.wait()
+        kp = kbuf[j % 2].astype(cd)                            # [ps, hd]
+        vp = vbuf[j % 2].astype(cd)
+        s = jax.lax.dot_general(qbd_s[...], kp, (((1,), (1,)), ((), ())),
+                                precision=prec,
+                                preferred_element_type=jnp.float32) * scale
+        kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos < n, s, NEG_INF)                    # [R, ps]
+        m_prev = m_s[...]                                      # [R, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        m_s[...] = m_new
+        l_s[...] = l_s[...] * corr + jnp.sum(p, axis=1, keepdims=True)
+        acc_s[...] = acc_s[...] * corr + jax.lax.dot(
+            p.astype(cd), vp, precision=prec,
+            preferred_element_type=jnp.float32)               # [R, hd]
 
     @pl.when(j == maxp - 1)
     def _finish():
-        o_ref[0] = (acc_s[...] / jnp.maximum(l_s[...], 1e-30)[..., None]
-                    ).astype(o_ref.dtype)
+        # row r*Hkv + h holds head h's output on head h's lanes
+        out = (acc_s[...] / jnp.maximum(l_s[...], 1e-30)).reshape(
+            rep, hkv, hd)
+        o_ref[0] = jnp.sum(jnp.where(_head_mask(hkv, hd)[None], out, 0.0),
+                           axis=1).astype(o_ref.dtype)
 
 
 def flash_decode(q, k_pool, v_pool, page_table, seq_lens, *,
@@ -208,7 +237,9 @@ def flash_decode(q, k_pool, v_pool, page_table, seq_lens, *,
     """Single-query decode attention over a block-paged KV pool.
 
     q [B, Hkv, rep, D] — one query token per slot, grouped by kv head;
-    k_pool / v_pool [P, ps, Hkv, D] — the page pool (one layer's slice);
+    k_pool / v_pool [P, ps, Hkv*D] — the page pool (one layer's slice),
+    heads x head_dim merged on the lane axis so a page row is a multiple
+    of 128 lanes at any head_dim (stablelm's 80 included);
     page_table [B, maxp] int32 — pool page ids per slot, in token order
     (entry t covers positions [t*ps, (t+1)*ps));
     seq_lens [B] int32 — valid tokens per slot (0 for free slots).
@@ -218,48 +249,54 @@ def flash_decode(q, k_pool, v_pool, page_table, seq_lens, *,
     past a slot's length skipped.  seq_lens == 0 yields exact zeros.
     """
     B, Hkv, rep, D = q.shape
-    P, ps, hkv2, _ = k_pool.shape
-    assert hkv2 == Hkv
+    P, ps, hd = k_pool.shape
+    assert hd == Hkv * D, (k_pool.shape, q.shape)
     maxp = page_table.shape[1]
     if interpret is None:
         from repro.kernels import ops
         interpret = ops._auto_interpret()
     scale = float(1.0 / (D ** 0.5))
+    R = rep * Hkv
+    cd = jnp.promote_types(q.dtype, k_pool.dtype)
+    # the query in the pool's merged layout: [B, rep, Hkv*D]
+    qm = q.transpose(0, 2, 1, 3).reshape(B, rep, hd)
     # profiler attribution (same convention as ops._kernel_scope): the
     # decode-tick hot kernel shows up named, not as an anonymous
     # pallas_call, in a --profile trace
     with jax.named_scope(f"flash_decode_B{B}_H{Hkv}x{rep}_ps{ps}"):
-        return pl.pallas_call(
+        out = pl.pallas_call(
             functools.partial(_decode_kernel, maxp, ps, Hkv, scale),
+            name="flash_decode",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
                 grid=(B, maxp),
                 in_specs=[
-                    pl.BlockSpec((1, Hkv, rep, D),
-                                 lambda b, j, *_: (b, 0, 0, 0)),
-                    pl.BlockSpec(memory_space=pltpu.ANY),
-                    pl.BlockSpec(memory_space=pltpu.ANY),
+                    pl.BlockSpec((1, rep, hd), lambda b, j, *_: (b, 0, 0)),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec(memory_space=pl.ANY),
                 ],
-                out_specs=pl.BlockSpec((1, Hkv, rep, D),
-                                       lambda b, j, *_: (b, 0, 0, 0)),
+                out_specs=pl.BlockSpec((1, rep, hd),
+                                       lambda b, j, *_: (b, 0, 0)),
                 scratch_shapes=[
-                    pltpu.VMEM((2, ps, Hkv, D), k_pool.dtype),  # k page bufs
-                    pltpu.VMEM((2, ps, Hkv, D), v_pool.dtype),  # v page bufs
+                    pltpu.VMEM((2, ps, hd), k_pool.dtype),      # k page bufs
+                    pltpu.VMEM((2, ps, hd), v_pool.dtype),      # v page bufs
                     pltpu.SemaphoreType.DMA((2, 2)),
-                    pltpu.VMEM((Hkv, rep), jnp.float32),        # running max
-                    pltpu.VMEM((Hkv, rep), jnp.float32),        # running denom
-                    pltpu.VMEM((Hkv, rep, D), jnp.float32),     # weighted acc
+                    pltpu.VMEM((R, hd), cd),                    # queries
+                    pltpu.VMEM((R, 1), jnp.float32),            # running max
+                    pltpu.VMEM((R, 1), jnp.float32),            # running denom
+                    pltpu.VMEM((R, hd), jnp.float32),           # weighted acc
                 ],
             ),
-            out_shape=jax.ShapeDtypeStruct((B, Hkv, rep, D), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((B, rep, hd), q.dtype),
             interpret=interpret,
-        )(page_table, seq_lens, q, k_pool, v_pool)
+        )(page_table, seq_lens, qm, k_pool, v_pool)
+    return out.reshape(B, rep, Hkv, D).transpose(0, 2, 1, 3)
 
 
 def paged_decode_ref(q, k_pool, v_pool, page_table, seq_lens):
     """jnp oracle for flash_decode (also the serve engine's jnp path):
     gather the slot's pages, monolithic masked softmax in fp32.  Same
-    shapes/contract as flash_decode."""
+    shapes/contract as flash_decode (merged [P, ps, Hkv*D] pools)."""
     B, Hkv, rep, D = q.shape
     ps = k_pool.shape[1]
     maxp = page_table.shape[1]

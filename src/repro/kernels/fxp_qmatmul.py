@@ -57,6 +57,7 @@ def qmatmul(a_code, w_code, *, bf: int, bn: int, bm: int = 128,
     grid = (Mp // bm, Np // bn_tile, Kp // bk)
     out = pl.pallas_call(
         functools.partial(_kernel, bf, bn, Kp // bk),
+        name="fxp_qmatmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda m, n, k: (m, k)),

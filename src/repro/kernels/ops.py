@@ -181,7 +181,7 @@ def _junction_update_core(spec, x, ws, b, moms, mom_b, vels, vel_b, hyp,
 
     ``health`` is a dummy f32 [E] operand riding the same cotangent
     channel: when ``spec.with_health`` the update kernels' non-aliased
-    [E, 1] int32 divergence flags come back as its cotangent (count of
+    [E] int32 divergence flags come back as its cotangent (count of
     non-finite update tiles per unit), so the in-kernel detector
     surfaces through an ordinary jax.grad without materializing any
     gradient — the forward ignores the operand entirely."""
@@ -229,7 +229,7 @@ def _junction_update_bwd(spec, saved, dy):
         new_b = nb if spec.has_bias else jnp.zeros_like(b)
         new_mom_b = (nmb,) if mom_b else ()
         new_vel_b = (nvb,) if vel_b else ()
-    d_health = (flags.reshape(spec.E).astype(jnp.float32)
+    d_health = (flags.astype(jnp.float32)
                 if spec.with_health else jnp.zeros((spec.E,), jnp.float32))
     return (dxv, new_ws, new_b, new_moms, new_mom_b, new_vels, new_vel_b,
             jnp.zeros_like(hyp), d_health, None, None, None, None)

@@ -77,6 +77,7 @@ def selective_scan(dt, x, bc, cc, a, h0, *, chunk: int = 128,
     nc = S // chunk
     return pl.pallas_call(
         functools.partial(_kernel, nc),
+        name="selective_scan",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, bd), lambda b, d, s: (b, s, d)),   # dt

@@ -33,6 +33,7 @@ def lut_lookup(codes, table, *, bm: int = 256, interpret: bool = False):
     grid = (Mp // bm,)
     out = pl.pallas_call(
         _kernel,
+        name="sigmoid_lut",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, N), lambda m: (m, 0)),

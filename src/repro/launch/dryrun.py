@@ -94,12 +94,7 @@ def lower_cell(cfg: ArchConfig, shape: ShapeSpec, mesh, microbatches: int = 1):
         opt = adam(constant_schedule(1e-4),
                    master_copy=(cfg.param_dtype != "float32"))
         oshapes = jax.eval_shape(opt.init, pshapes)
-        # opt state mirrors params: reuse param specs where shaped, P() for
-        # the scalar placeholders on non-trainable (pattern) leaves
-        ospecs = {k: jax.tree.map(
-                      lambda t, s: sh.P() if len(t.shape) == 0 else s,
-                      oshapes[k], pspecs)
-                  for k in oshapes}
+        ospecs = sh.opt_state_specs(oshapes, pspecs)
         ostruct = sh.attach(oshapes, ospecs, mesh)
         batch = specs_mod.batch_struct(cfg, shape)
         bspecs = sh.batch_specs(cfg, batch, mesh)

@@ -9,21 +9,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 wants explicit Auto axis types; 0.4.x has no kwarg
-    from jax.sharding import AxisType
-
-    def _axis_kw(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # jax 0.4.x: every axis is Auto already
-    def _axis_kw(n: int) -> dict:
-        return {}
-
-
-def compat_mesh(shape, axes, devices=None) -> Mesh:
-    """jax.make_mesh across jax versions (with/without axis_types)."""
-    return jax.make_mesh(shape, axes, devices=devices, **_axis_kw(len(axes)))
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -35,11 +21,13 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
         raise RuntimeError(
             f"mesh {shape} needs {n} devices, have {len(devs)} — run under "
             "launch/dryrun.py (it forces 512 host devices)")
-    return compat_mesh(shape, axes, devices=devs[:n])
+    return jax.make_mesh(shape, axes, devices=devs[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> Mesh:
     """Small mesh over whatever devices exist (tests / examples)."""
     n = data * model
-    return compat_mesh((data, model), ("data", "model"),
-                       devices=jax.devices()[:n])
+    return jax.make_mesh((data, model), ("data", "model"),
+                         devices=jax.devices()[:n],
+                         axis_types=(AxisType.Auto,) * 2)

@@ -61,12 +61,14 @@ def main():
 
     from repro.configs import registry
     from repro.core.sparsity import SparsityConfig
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models import model as M
     from repro.obs import Recorder, percentile, profile_ctx
     from repro.serve.engine import (ContinuousEngine, Engine, Request,
                                     ServeConfig)
     from repro.train import checkpoint as ckpt_mod
 
+    enable_compile_cache()
     cfg = registry.get(args.arch)
     if args.reduce:
         cfg = cfg.reduced()

@@ -26,7 +26,7 @@ def _floats(s: str) -> list[float]:
     return [float(v) for v in s.split(",") if v]
 
 
-def _parse():
+def _parse(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--densities", default="0.25,0.5", metavar="D1,D2,...")
     ap.add_argument("--lrs", default="0.02,0.05,0.1", metavar="L1,L2,...")
@@ -59,18 +59,20 @@ def _parse():
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="wrap the sweep in a jax.profiler trace written "
                          "to DIR (kernels show up named by KernelSpec)")
-    return ap.parse_args()
+    return ap.parse_args(argv)
 
 
-def main():
-    args = _parse()
+def main(argv=None):
+    args = _parse(argv)
     import numpy as np
 
     from repro.configs.base import SweepConfig
     from repro.data.mnist import paper_dataset
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.obs import Recorder, profile_ctx
     from repro.search import CandidateSpec, bucket, run_sweep
 
+    enable_compile_cache()
     # output width = smallest block multiple holding the 32 padded classes
     out_w = -(-32 // args.block) * args.block
     layers = (1024, args.hidden, out_w)

@@ -53,6 +53,35 @@ def _parse():
     return ap.parse_args()
 
 
+def shard_train_step(cfg, step_fn, params, opt_state, mesh):
+    """The data/model-parallel form of a raw train step on ``mesh``:
+    params and optimizer state are placed by the parallel/sharding.py
+    rules, the batch is split over the data axis, and the activation
+    hints are active while the step traces.  Returns
+    ``(train_step, params, opt_state)`` — the step jitted with params and
+    state donated, and both trees placed on the mesh."""
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.parallel import hints
+    from repro.parallel import sharding as sh
+
+    pspecs = sh.param_specs(cfg, params, mesh)
+    psh = sh.to_shardings(pspecs, mesh)
+    osh = sh.to_shardings(sh.opt_state_specs(opt_state, pspecs), mesh)
+    bsh = NamedSharding(mesh, P(sh.dp_axes(mesh)))
+
+    def step(params, opt_state, batch, step):
+        with hints.use_mesh_hints(mesh):
+            return step_fn(params, opt_state, batch, step)
+
+    train_step = jax.jit(step, donate_argnums=(0, 1),
+                         in_shardings=(psh, osh, bsh, None),
+                         out_shardings=(psh, osh, None))
+    return (train_step, jax.device_put(params, psh),
+            jax.device_put(opt_state, osh))
+
+
 def main():
     args = _parse()
     if args.devices:
@@ -66,16 +95,16 @@ def main():
     from repro.configs import registry
     from repro.core.sparsity import SparsityConfig
     from repro.data.pipeline import LMTokenPipeline
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_local_mesh
     from repro.models import model as M
     from repro.obs import Recorder, profile_ctx
     from repro.optim import cosine_schedule, fused_adam, fused_sgd
-    from repro.parallel import hints
-    from repro.parallel import sharding as sh
     from repro.train import grad_compress
     from repro.train.steps import fused_update_eligible, make_train_step
     from repro.train.train_loop import TrainLoopConfig, run
 
+    enable_compile_cache()
     cfg = registry.get(args.arch)
     if args.reduce:
         cfg = cfg.reduced()
@@ -114,14 +143,10 @@ def main():
     step_fn = make_train_step(cfg, opt, microbatches=args.microbatches,
                               jit=False)
 
-    n_dev = args.data * args.model
-    if n_dev > 1:
+    if args.data * args.model > 1:
         mesh = make_local_mesh(args.data, args.model)
-        pspecs = sh.param_specs(cfg, params, mesh)
-        psh = sh.to_shardings(pspecs, mesh)
-        params = jax.tree.map(jax.device_put, params, psh)
-        with mesh, hints.use_mesh_hints(mesh):
-            train_step = jax.jit(step_fn, donate_argnums=(0, 1))
+        train_step, params, opt_state = shard_train_step(
+            cfg, step_fn, params, opt_state, mesh)
     else:
         train_step = jax.jit(step_fn, donate_argnums=(0, 1))
 

@@ -215,8 +215,10 @@ def paged_kv_update(cache: dict, k_new, v_new, positions, page_table,
                     keys=("k", "v")):
     """Scatter new KV rows into the block-paged pool.
 
-    cache: {"k": [P, ps, Hkv, hd], "v": ...} (one layer's pool slice);
-    k_new/v_new [B, S, Hkv, hd] — tokens to write; positions [B, S] —
+    cache: {"k": [P, ps, Hkv*hd], "v": ...} (one layer's pool slice,
+    heads x head_dim merged on the lane axis — models/model.
+    make_paged_cache); k_new/v_new [B, S, Hkv, hd] — tokens to write;
+    positions [B, S] —
     their absolute positions; page_table [B, maxp] — pool page ids in
     token order.  Token at position t lands in page page_table[b, t//ps]
     at offset t % ps, so a slot refill is a page-table swap, never a
@@ -228,21 +230,21 @@ def paged_kv_update(cache: dict, k_new, v_new, positions, page_table,
     pid, off = pid.reshape(-1), off.reshape(-1)
     out = dict(cache)
     for key, new in zip(keys, (k_new, v_new)):
-        flat = new.reshape(-1, *new.shape[2:]).astype(cache[key].dtype)
+        flat = new.reshape(pid.shape[0], -1).astype(cache[key].dtype)
         out[key] = cache[key].at[pid, off].set(flat)
     return out
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, seq_lens, *,
                            engine: str = "jnp"):
-    """q [B,1,H,D]; pools [P,ps,Hkv,D]; page_table [B,maxp];
+    """q [B,1,H,D]; pools [P,ps,Hkv*D]; page_table [B,maxp];
     seq_lens [B] (valid tokens per slot).  Routes through the Pallas
     flash_decode kernel under engine="pallas" (page table on scalar
     prefetch, per-page HBM→VMEM DMA) and the gather+masked-softmax
     reference otherwise.  Returns [B,1,H,D]."""
     from repro.kernels import flash_attention as fa
     B, _, H, D = q.shape
-    Hkv = k_pool.shape[2]
+    Hkv = k_pool.shape[2] // D
     rep = H // Hkv
     qf = q.reshape(B, Hkv, rep, D)
     if engine == "pallas":

@@ -13,6 +13,7 @@ the lowered HLO is O(1) in depth (critical for the 512-device dry-run), with
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any
 
 import jax
@@ -423,10 +424,13 @@ def paged_supported(cfg: ArchConfig) -> tuple[bool, str]:
 
 def make_paged_cache(cfg: ArchConfig, num_pages: int, page_size: int):
     """Zeroed block-paged KV pool: every seq-axis cache leaf (per
-    ``cache_seq_axes``) [L, B, S, ...] becomes a pool [L, P, ps, ...] —
-    memory scales with the page budget (tokens-in-flight), not
-    batch x max_len.  Slot state (page tables, lengths) lives outside
-    the tree, in the serve engine."""
+    ``cache_seq_axes``) [L, B, S, Hkv, hd] becomes a pool
+    [L, P, ps, Hkv*hd] — memory scales with the page budget
+    (tokens-in-flight), not batch x max_len.  Heads and head_dim share
+    the lane axis, so a page row is a multiple of 128 lanes at any
+    head_dim (stablelm's 80 included) and the paged decode kernel DMAs
+    and reads whole rows.  Slot state (page tables, lengths) lives
+    outside the tree, in the serve engine."""
     ok, why = paged_supported(cfg)
     if not ok:
         raise ValueError(f"paged cache unsupported: {why}")
@@ -435,8 +439,8 @@ def make_paged_cache(cfg: ArchConfig, num_pages: int, page_size: int):
 
     def mk(ax, t):
         assert ax == 2, (ax, t.shape)
-        return jnp.zeros((t.shape[0], num_pages, page_size) + t.shape[3:],
-                         t.dtype)
+        return jnp.zeros((t.shape[0], num_pages, page_size,
+                          math.prod(t.shape[3:])), t.dtype)
 
     return jax.tree.map(mk, axes, template)
 

@@ -71,6 +71,24 @@ def constrain(x: jax.Array, *axes) -> jax.Array:
         x, NamedSharding(mesh, P(*resolved)))
 
 
+def shard_rows(fn, x: jax.Array, *operands):
+    """``fn(x, *operands)``, run per device on its share of x's leading
+    (batch) dim when a mesh is in effect, the operands whole on every
+    device.  Pallas TPU (Mosaic) kernels cannot be partitioned by XLA, so
+    a kernel call under a mesh must be a shard_map.  Gradients of the
+    whole operands are summed over the devices (shard_map's transpose of
+    an unmapped input).  Outside a hints context: a plain call."""
+    mesh = current_mesh()
+    if mesh is None:
+        return fn(x, *operands)
+    sizes = dict(mesh.shape)
+    rows = P(_resolve(x.shape[0], ("pod", "data") if "pod" in sizes
+                      else "data", sizes))
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=(rows,) + (P(),) * len(operands),
+                         out_specs=rows, check_vma=False)(x, *operands)
+
+
 def constrain_tokens3d(x: jax.Array, cfg) -> jax.Array:
     """Anchor for [B, S, D] residual-stream activations.
 

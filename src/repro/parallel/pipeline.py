@@ -26,27 +26,19 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 
-def _axis_size(axis_name):
-    try:  # jax >= 0.6
-        return jax.lax.axis_size(axis_name)
-    except AttributeError:  # jax 0.4.x
-        return jax.lax.psum(1, axis_name)
-
-
 def _shift_right(x, axis_name):
     """stage s receives from s-1 (stage 0 receives zeros)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, i + 1) for i in range(n - 1)]
     return jax.lax.ppermute(x, axis_name, perm)
 
 
 def _shift_left(x, axis_name):
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i + 1, i) for i in range(n - 1)]
     return jax.lax.ppermute(x, axis_name, perm)
 
@@ -90,9 +82,9 @@ def gpipe_forward(stage_fn: Callable, params_stacked, x_microbatches,
         return jax.lax.psum(outs, axis)
 
     spec_p = jax.tree.map(lambda _: P(axis), params_stacked)
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(spec_p, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(spec_p, P()), out_specs=P(),
+                       check_vma=False)
     return fn(params_stacked, x_microbatches)
 
 
@@ -179,10 +171,10 @@ def async_pipeline_epoch(stage_fn: Callable, loss_grad_fn: Callable,
         return jax.tree.map(lambda t: t[None], params), losses
 
     spec_p = jax.tree.map(lambda _: P(axis), params_stacked)
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(spec_p, P(), P()),
-                   out_specs=(spec_p, P(axis)),
-                   check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(spec_p, P(), P()),
+                       out_specs=(spec_p, P(axis)),
+                       check_vma=False)
     new_params, losses = fn(params_stacked, xs, ys)
     return new_params, losses
 

@@ -174,6 +174,17 @@ def param_specs(cfg: ArchConfig, params_tree: Any, mesh: Mesh):
     return rec(params_tree, [], 0)
 
 
+def opt_state_specs(opt_state: Any, pspecs: Any):
+    """PartitionSpec tree for an optimizer state that mirrors params slot
+    by slot (``{slot: params-like tree}``, e.g. Adam's m/v): each leaf
+    takes its parameter's spec, and the scalar placeholders kept for
+    non-trainable (pattern) leaves replicate.  Works on arrays and
+    ShapeDtypeStructs; a slot-free state (``()``) passes through."""
+    return {k: jax.tree.map(lambda t, s: P() if len(t.shape) == 0 else s,
+                            v, pspecs)
+            for k, v in opt_state.items()} if opt_state else opt_state
+
+
 def batch_specs(cfg: ArchConfig, batch_tree: Any, mesh: Mesh):
     seq_ax = "model" if cfg.strategy == "sp" else None
 

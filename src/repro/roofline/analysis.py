@@ -71,8 +71,6 @@ def analyze_compiled(compiled, lowered=None) -> Roofline:
     text = compiled.as_text()
     costs = hlo_mod.analyze(text)
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):  # some backends return [dict]
-        ca = ca[0]
     raw = {k: float(v) for k, v in ca.items()
            if k in ("flops", "bytes accessed", "transcendentals")} if ca else {}
     try:

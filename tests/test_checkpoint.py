@@ -173,8 +173,8 @@ def test_elastic_reshard_subprocess(tmp_path):
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.train import checkpoint as C
         n = int(sys.argv[1])
-        from repro.launch.mesh import compat_mesh
-        mesh = compat_mesh((n,), ("data",), devices=jax.devices())
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((n,), ("data",), axis_types=(AxisType.Auto,))
         sh = NamedSharding(mesh, P("data"))
         t = {{"w": jax.device_put(jnp.arange(32, dtype=jnp.float32), sh)}}
         if sys.argv[2] == "save":

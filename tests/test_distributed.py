@@ -19,7 +19,7 @@ def _run(ndev: int, body: str) -> str:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={ndev}"
         sys.path.insert(0, {SRC!r})
         import jax, jax.numpy as jnp, numpy as np
-        from repro.launch.mesh import compat_mesh
+        from jax.sharding import AxisType
     """) + textwrap.dedent(body)
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, env=dict(os.environ), timeout=600)
@@ -30,7 +30,7 @@ def _run(ndev: int, body: str) -> str:
 def test_gpipe_forward_exact_and_async_converges():
     out = _run(4, """
         from repro.parallel import pipeline as PP
-        mesh = compat_mesh((4,), ("stage",), devices=jax.devices())
+        mesh = jax.make_mesh((4,), ("stage",), axis_types=(AxisType.Auto,))
         D = 16
         def stage_fn(p, x): return jnp.tanh(x @ p["w"] + p["b"])
         k = jax.random.PRNGKey(0)
@@ -97,6 +97,55 @@ def test_sharded_train_matches_single_device():
         print("SHARD_OK", worst)
     """)
     assert "SHARD_OK" in out
+
+
+def test_data_parallel_pallas_step_matches_one_device():
+    """launch/train.py --data 4: the junction kernels run per device under
+    shard_map (XLA cannot partition a Pallas call), params and optimizer
+    state live sharded, and two steps match the one-device step."""
+    out = _run(4, """
+        import dataclasses
+        from repro.configs import registry
+        from repro.core.sparsity import SparsityConfig
+        from repro.data.pipeline import LMTokenPipeline
+        from repro.launch.mesh import make_local_mesh
+        from repro.launch.train import shard_train_step
+        from repro.models import model as M
+        from repro.optim import constant_schedule, fused_adam
+        from repro.train.steps import make_train_step
+
+        cfg = dataclasses.replace(
+            registry.get("stablelm-3b").reduced().with_sparsity(
+                SparsityConfig(density=0.25, block=32, where="ffn")),
+            engine="pallas", dtype="float32")
+        opt = fused_adam(constant_schedule(1e-3), grad_clip=1.0)
+        batches = [jax.tree.map(jnp.asarray, b) for b, _ in
+                   zip(LMTokenPipeline(cfg, 4, 32), range(2))]
+        fresh = lambda: M.init(cfg, jax.random.PRNGKey(0))
+
+        p1 = fresh()
+        s1 = opt.init(p1)
+        one = make_train_step(cfg, opt)
+        mesh = make_local_mesh(4, 1)
+        p4 = fresh()
+        four, p4, s4 = shard_train_step(cfg, make_train_step(cfg, opt, jit=False),
+                                        p4, opt.init(p4), mesh)
+        w = p4["layers"]["mlp"]["wi"]["w"]
+        assert len(w.sharding.device_set) == 4, w.sharding
+        assert w.addressable_shards[0].data.size * 4 == w.size
+        assert s4["m"]["layers"]["mlp"]["wi"]["w"].sharding == w.sharding
+        for i, b in enumerate(batches):
+            p1, s1, m1 = one(p1, s1, b, jnp.asarray(i))
+            p4, s4, m4 = four(p4, s4, b, jnp.asarray(i))
+            assert abs(float(m1["loss"]) - float(m4["loss"])) < 1e-4, (
+                float(m1["loss"]), float(m4["loss"]))
+        worst = max(float(jnp.max(jnp.abs(a - b)))
+                    for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p4))
+                    if jnp.issubdtype(a.dtype, jnp.inexact))
+        assert worst < 1e-4, worst
+        print("DP_OK", worst)
+    """)
+    assert "DP_OK" in out
 
 
 def test_grad_compression_cross_pod():

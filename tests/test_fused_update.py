@@ -210,6 +210,56 @@ def test_expert_gated_junction_fused_matches_two_pass():
                                rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("adam", [False, True], ids=["momentum", "adam"])
+def test_expert_biased_junction_fused_matches_two_pass(adam):
+    """E=3 biased units with per-unit hyp rows (the population sweep's
+    operands): the [E, 1, N] bias and bias-slot blocks of update_dw update
+    each unit's bias from its own gradient, matching the two-pass
+    per-unit reference."""
+    from repro.kernels import block_sparse_matmul as bsm
+    from repro.search import population as pop
+    E, n_in, n_out, bs, M = 3, 128, 96, 32, 40
+    pat = make_block_pattern(n_in, n_out, 0.5, bs)
+    patt = tuple(jnp.asarray(a) for a in (pat.idx, pat.rev_ob, pat.rev_t,
+                                          pat.rev_cnt))
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    w = jax.random.normal(ks[0], (E, pat.n_out_blocks, pat.fan_in_blocks,
+                                  bs, bs)) * 0.1
+    b = jax.random.normal(ks[1], (E, n_out)) * 0.3
+    x = jax.random.normal(ks[2], (E, M, n_in))
+    co = jax.random.normal(ks[3], (E, M, n_out))
+    hyp = jnp.zeros((E, bsm.HYP_K)).at[:, bsm.COL_LR].set(
+        jnp.asarray([0.01, 0.03, 0.05])).at[:, bsm.COL_GS].set(1.0)
+    hyp = hyp.at[:, bsm.COL_B1].set(0.9)
+    if adam:
+        hyp = (hyp.at[:, bsm.COL_B2].set(0.95).at[:, bsm.COL_EPS].set(1e-8)
+               .at[:, bsm.COL_T].set(1.0))
+    slot = lambda a: jax.random.normal(ks[4], a.shape) * 0.01
+    m_w, m_b = slot(w), slot(b)
+    v_w, v_b = jnp.abs(slot(w)), jnp.abs(slot(b))
+
+    def fused(w, b, mw, mb, vw, vb):
+        y = ops.junction_train_update(
+            x, w, *patt, bias=b, act="sigmoid", hyp=hyp, mom=mw, mom_b=mb,
+            vel=vw if adam else None, vel_b=vb if adam else None)
+        return jnp.sum(y * co)
+
+    got = jax.grad(fused, range(6))(w, b, m_w, m_b, v_w, v_b)
+    gw, gb = jax.grad(lambda w, b: jnp.sum(ops.junction_matmul(
+        x, w, *patt, bias=b, act="sigmoid") * co), (0, 1))(w, b)
+    slots = ([{"w": m_w, "b": m_b}, {"w": v_w, "b": v_b}] if adam
+             else [{"w": m_w, "b": m_b}])
+    (ref_p,), ref_s = pop._two_pass_update(
+        [{"w": w, "b": b}], tuple([s] for s in slots),
+        [{"w": gw, "b": gb}], hyp)
+    want = [ref_p["w"], ref_p["b"], ref_s[0][0]["w"], ref_s[0][0]["b"]]
+    if adam:
+        want += [ref_s[1][0]["w"], ref_s[1][0]["b"]]
+    for g, r, name in zip(got, want, ("w", "b", "m_w", "m_b", "v_w", "v_b")):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r), rtol=2e-5,
+                                   atol=2e-6, err_msg=name)
+
+
 def test_bf16_params_fp32_momentum():
     """bf16 junction weights update through an fp32 momentum accumulator:
     the fused path keeps dw in fp32 end-to-end (the two-pass path rounds
@@ -373,6 +423,12 @@ def test_moe_fused_adam_three_steps_matches_two_pass():
 
 
 # ------------------------------------------------- no-dw-in-HBM acceptance
+def _has_dw_kernel(jaxpr_text: str) -> bool:
+    """A weight-gradient kernel (plain or gated) by its pallas_call name;
+    the fused update kernels are junction_update_dw / _gated_dw."""
+    return "junction_dw" in jaxpr_text or "junction_gated_dw" in jaxpr_text
+
+
 @pytest.mark.parametrize("make_opt", [
     lambda: fused_sgd(constant_schedule(1e-2), momentum=0.9),
     lambda: fused_adam(constant_schedule(1e-3)),
@@ -388,17 +444,16 @@ def test_fused_step_jaxpr_has_no_dw_kernel(make_opt):
         raw = make_train_step(cfg, opt, jit=False)
         txt = str(jax.make_jaxpr(raw)(params, opt.init(params), _batch(cfg),
                                       jnp.asarray(0)))
-        assert "fused_update_dw" in txt, cfg.name
-        # "dw_kernel" also catches "gated_dw_kernel"
-        assert "dw_kernel" not in txt, cfg.name
+        assert "junction_update_dw" in txt, cfg.name
+        assert not _has_dw_kernel(txt), cfg.name
         if cfg.family == "moe":
-            assert "fused_update_gated_dw" in txt
+            assert "junction_update_gated_dw" in txt
         # two-pass sanity: the reference step still runs the dw kernels
         raw_ref = make_train_step(
             dataclasses.replace(cfg, fused_update=False), opt, jit=False)
         txt_ref = str(jax.make_jaxpr(raw_ref)(params, opt.init(params),
                                               _batch(cfg), jnp.asarray(0)))
-        assert "dw_kernel" in txt_ref and "fused_update_dw" not in txt_ref
+        assert _has_dw_kernel(txt_ref) and "junction_update_dw" not in txt_ref
 
 
 # ------------------------------------- newly-eligible configs (ISSUE 7)
@@ -423,7 +478,7 @@ def test_grad_clip_runs_fused_and_matches_clipped_reference(make_opt):
     st = opt.init(params)
     txt = str(jax.make_jaxpr(make_train_step(cfg, opt, jit=False))(
         params, st, batch, jnp.asarray(0)))
-    assert "fused_update_dw" in txt and "dw_kernel" in txt
+    assert "junction_update_dw" in txt and _has_dw_kernel(txt)
     ts = make_train_step(cfg, opt, donate=False)
     ts_ref = make_train_step(dataclasses.replace(cfg, fused_update=False),
                              opt, donate=False)

@@ -99,6 +99,48 @@ def test_health_flags_fire_on_nonfinite_update():
     assert not bool(jnp.all(jnp.isfinite(w)))
 
 
+@pytest.mark.parametrize("gated", [False, True], ids=["biased", "gated"])
+def test_health_flags_per_unit_at_e3(gated):
+    """E=3 Adam units: poisoning unit 1's rows flags unit 1 only (the
+    [E, 1, 128] health rows are per unit), and on the clean run the flags
+    are zero and the health operand changes no numerics."""
+    from repro.core.sparsity import make_block_pattern
+    E, bs, M = 3, 32, 24
+    pat = make_block_pattern(N_IN, N_OUT, 0.5, bs)
+    patt = tuple(jnp.asarray(a) for a in (pat.idx, pat.rev_ob, pat.rev_t,
+                                          pat.rev_cnt))
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    shape = (E, pat.n_out_blocks, pat.fan_in_blocks, bs, bs)
+    w = jax.random.normal(ks[0], shape) * 0.1
+    wi = jax.random.normal(ks[1], shape) * 0.1 if gated else None
+    b = None if gated else jnp.zeros((E, N_OUT))
+    zeros = lambda a: None if a is None else jnp.zeros(a.shape, jnp.float32)
+    hyp = jnp.asarray([0.01, 0.9, 0.95, 1e-8, 0.0, 1.0, 1.0], jnp.float32)
+    clean = jax.random.normal(ks[2], (E, M, N_IN))
+
+    def updated(x, health):
+        def loss(w, wi, b, h):
+            y = ops.junction_train_update(
+                x, w, *patt, wi=wi, bias=b,
+                act="none" if gated else "sigmoid", hyp=hyp,
+                mom=zeros(w), mom_wi=zeros(wi), mom_b=zeros(b),
+                vel=zeros(w), vel_wi=zeros(wi), vel_b=zeros(b), health=h)
+            return jnp.sum(jnp.where(jnp.isfinite(y), y, 0.0))
+        if health is None:
+            return jax.grad(lambda w: loss(w, wi, b, None))(w), None
+        gw, gh = jax.grad(lambda w, h: loss(w, wi, b, h), (0, 1))(
+            w, jnp.zeros((E,), jnp.float32))
+        return gw, gh
+
+    w_h, h = updated(clean, health=True)
+    w_p, _ = updated(clean, health=None)
+    np.testing.assert_array_equal(np.asarray(h), np.zeros(E))
+    np.testing.assert_array_equal(np.asarray(w_h), np.asarray(w_p))
+    _, h_bad = updated(clean.at[1, 0, 0].set(jnp.nan), health=True)
+    h_bad = np.asarray(h_bad)
+    assert h_bad[1] > 0 and h_bad[0] == 0 and h_bad[2] == 0, h_bad
+
+
 # ------------------------------------------------------- population level
 @pytest.mark.parametrize("engine", ["jnp", "pallas"])
 def test_population_health_isolates_bad_member(engine):

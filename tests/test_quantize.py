@@ -326,8 +326,8 @@ def test_serve_quantize_at_load_greedy_stable_and_jaxpr():
     tok = jnp.zeros((2, 1), jnp.int32)
     txt = str(jax.make_jaxpr(step)(pq, cache, tok,
                                    jnp.asarray(8, jnp.int32)))
-    assert "fwd_int8_kernel" in txt
-    assert "fwd_kernel" not in txt.replace("fwd_int8_kernel", "")
+    assert "junction_fwd_int8" in txt
+    assert "junction_fwd" not in txt.replace("junction_fwd_int8", "")
 
 
 # ------------------------------------------------- config / cohort plumbing

@@ -21,10 +21,7 @@ def test_scan_flops_multiplied():
     ws = jax.ShapeDtypeStruct((128, 128), jnp.float32)
     c = _compile(f, xs, ws)
     one = 2 * 128 * 128 * 128
-    ca = c.cost_analysis()
-    if isinstance(ca, list):  # jax 0.4.x returns [dict]
-        ca = ca[0]
-    raw = ca["flops"]
+    raw = c.cost_analysis()["flops"]
     assert raw < 2 * one                      # XLA undercounts
     costs = H.analyze(c.as_text())
     assert abs(costs.dot_flops - 10 * one) / (10 * one) < 0.05
@@ -72,8 +69,8 @@ def test_collective_bytes_parsed():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.roofline import hlo as H
-        from repro.launch.mesh import compat_mesh
-        mesh = compat_mesh((4,), ("d",), devices=jax.devices())
+        from jax.sharding import AxisType
+        mesh = jax.make_mesh((4,), ("d",), axis_types=(AxisType.Auto,))
         def f(x):
             return jnp.sum(x * 2.0)
         xs = jax.ShapeDtypeStruct((1024, 256), jnp.float32,

@@ -130,6 +130,21 @@ def test_population_fused_matches_independent_singles(momentum):
                 rtol=1e-4, atol=1e-5, err_msg=f"member {e} layer {li} b")
 
 
+def test_population_forward_per_unit_bias_matches_jnp():
+    """E=3 units, each with its own non-zero bias: the [E, 1, N] bias
+    block of the forward kernel adds unit e's row to unit e only."""
+    specs = _specs(E=3)
+    params = init_population(jax.random.PRNGKey(0), specs)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(params))
+    params = [dict(l, b=jax.random.normal(k, l["b"].shape))
+              for l, k in zip(params, keys)]
+    x, _ = _mnist_batch(40, specs[0].layers[0], specs[0].layers[-1])
+    got = pop.population_forward(params, x, act="sigmoid", engine="pallas")
+    want = pop.population_forward(params, x, act="sigmoid", engine="jnp")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+
+
 def test_population_mnist_shape_fused_vs_independent_jnp():
     """The paper-shape population (1024 -> 512 -> 128, bs=128, E=4,
     distinct lrs + momentum) through the fused pallas path vs E

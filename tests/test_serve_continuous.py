@@ -52,8 +52,8 @@ def test_flash_decode_matches_reference(lens):
     P = 1 + B * maxp
     ks = jax.random.split(jax.random.PRNGKey(len(lens)), 3)
     q = jax.random.normal(ks[0], (B, Hkv, rep, D), jnp.float32)
-    k_pool = jax.random.normal(ks[1], (P, ps, Hkv, D), jnp.float32)
-    v_pool = jax.random.normal(ks[2], (P, ps, Hkv, D), jnp.float32)
+    k_pool = jax.random.normal(ks[1], (P, ps, Hkv * D), jnp.float32)
+    v_pool = jax.random.normal(ks[2], (P, ps, Hkv * D), jnp.float32)
     pt = np.zeros((B, maxp), np.int32)
     nxt = 1
     for b, n in enumerate(lens):
@@ -67,6 +67,32 @@ def test_flash_decode_matches_reference(lens):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
     assert not np.any(np.asarray(got)[np.asarray(sl) == 0])
+
+
+@pytest.mark.parametrize("Hkv,rep", [(4, 1), (2, 3)], ids=["mha", "gqa"])
+def test_flash_decode_matches_reference_hd80(Hkv, rep):
+    """head_dim 80 (stablelm-3b): the merged [P, ps, Hkv*80] page layout
+    keeps the kernel off 80-lane head slices, and it still matches the
+    reference, MHA and GQA, bf16 pools included."""
+    from repro.kernels.flash_attention import flash_decode, paged_decode_ref
+    lens = [0, 3, 16, 21]
+    B, D, ps, maxp = len(lens), 80, 8, 3
+    P = 1 + B * maxp
+    ks = jax.random.split(jax.random.PRNGKey(80), 3)
+    q = jax.random.normal(ks[0], (B, Hkv, rep, D), jnp.float32)
+    pools = [jax.random.normal(k, (P, ps, Hkv * D), jnp.float32)
+             for k in ks[1:]]
+    pt = jnp.asarray(1 + np.arange(B * maxp, dtype=np.int32).reshape(B, maxp))
+    sl = jnp.asarray(lens, jnp.int32)
+    for dt, tol in ((jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)):
+        qd, kp, vp = q.astype(dt), *(p.astype(dt) for p in pools)
+        got = flash_decode(qd, kp, vp, pt, sl, interpret=True)
+        want = paged_decode_ref(qd, kp, vp, pt, sl)
+        assert got.shape == (B, Hkv, rep, D) and got.dtype == dt
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=tol, rtol=tol)
+        assert not np.any(np.asarray(got, np.float32)[0])
 
 
 # -------------------------------------------------------- engine semantics

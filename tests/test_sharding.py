@@ -14,21 +14,10 @@ from repro.configs import registry
 from repro.models import model as M
 from repro.parallel import sharding as sh
 
-try:
-    _leaves_with_path = jax.tree.leaves_with_path
-except AttributeError:  # jax 0.4.x
-    from jax.tree_util import tree_leaves_with_path as _leaves_with_path
-
-def _abstract_mesh(sizes, names):
-    try:  # jax >= 0.5: AbstractMesh(axis_sizes, axis_names)
-        return AbstractMesh(sizes, names)
-    except TypeError:  # jax 0.4.x: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(names, sizes)))
-
 
 MESHES = {
-    "single": _abstract_mesh((16, 16), ("data", "model")),
-    "multi": _abstract_mesh((2, 16, 16), ("pod", "data", "model")),
+    "single": AbstractMesh((16, 16), ("data", "model")),
+    "multi": AbstractMesh((2, 16, 16), ("pod", "data", "model")),
 }
 ARCHS = list(registry.ARCHS)
 
@@ -49,9 +38,9 @@ def test_param_specs_divisible(arch, mesh_name):
     sizes = dict(mesh.shape)
     specs = sh.param_specs(cfg, pshapes, mesh)
 
-    leaves = _leaves_with_path(pshapes)
+    leaves = jax.tree.leaves_with_path(pshapes)
     spec_leaves = {jax.tree_util.keystr(k): v
-                   for k, v in _leaves_with_path(
+                   for k, v in jax.tree.leaves_with_path(
                        specs, is_leaf=lambda x: isinstance(x, P))}
     for key, leaf in leaves:
         spec = spec_leaves[jax.tree_util.keystr(key)]
@@ -70,7 +59,7 @@ def test_param_specs_divisible(arch, mesh_name):
 def test_sp_strategy_never_model_shards_weights(arch):
     cfg, pshapes = _pshapes(arch)
     specs = sh.param_specs(cfg, pshapes, MESHES["single"])
-    for k, spec in _leaves_with_path(
+    for k, spec in jax.tree.leaves_with_path(
             specs, is_leaf=lambda x: isinstance(x, P)):
         assert "model" not in [a for a in spec if isinstance(a, str)], (k, spec)
 
@@ -82,7 +71,7 @@ def test_cache_specs_shard_sequence(arch):
     specs = sh.cache_specs(cfg, cshapes, MESHES["single"])
     # at least one leaf must shard on model (seq or state channels)
     found = any("model" in [a for a in spec if isinstance(a, str)]
-                for _, spec in _leaves_with_path(
+                for _, spec in jax.tree.leaves_with_path(
                     specs, is_leaf=lambda x: isinstance(x, P)))
     assert found, f"{arch}: cache entirely replicated on model axis"
 
@@ -102,10 +91,6 @@ def test_junction_matmul_shard_map_smoke():
     shard_map)."""
     import numpy as np
     from jax.sharding import Mesh
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer jax: promoted out of experimental
-        from jax.sharding import shard_map
 
     from repro.core.sparsity import make_block_pattern
     from repro.kernels import ops
@@ -126,9 +111,9 @@ def test_junction_matmul_shard_map_smoke():
         return ops.junction_matmul(x, w, idx, rob, rt, rc, bias=b, act="silu")
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    wrapped = shard_map(apply_fn, mesh=mesh,
-                        in_specs=(P("data"), P(), P()), out_specs=P("data"),
-                        check_rep=False)
+    wrapped = jax.shard_map(apply_fn, mesh=mesh,
+                            in_specs=(P("data"), P(), P()),
+                            out_specs=P("data"), check_vma=False)
 
     y_ref = apply_fn(x, w, b)
     y_map = wrapped(x, w, b)

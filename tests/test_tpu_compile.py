@@ -1,0 +1,151 @@
+"""The main-path Pallas kernels compile for a TPU v5e chip.
+
+No chip is needed: the TPU compiler compiles for a described (not
+attached) ``v5e:2x2`` topology, and refuses what the chip would refuse —
+block shapes off the (8, 128) tiling, scalar stores to VMEM, unaligned
+lane slices — which interpret mode on the CPU never sees.  Shapes are
+stablelm-3b's FFN junction (2560 -> 6912 at density 0.25, block 128) and
+its KV pages (32 heads x head_dim 80), plus a head_dim-128 GQA decode.
+
+The topology is described inside a module fixture, never at import: one
+process at a time may load the TPU library, and only the worker that runs
+this file should.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.sparsity import make_block_pattern
+from repro.kernels import block_sparse_matmul as bsm
+from repro.kernels import flash_attention as fa
+
+D_MODEL, D_FF, BLOCK, ROWS = 2560, 6912, 128, 1024
+PAT = make_block_pattern(D_MODEL, D_FF, 0.25, BLOCK)
+NOB, KB = PAT.idx.shape
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    log_dir = os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    cache_on = jax.config.jax_enable_compilation_cache
+    # a compile for a described chip is written to the cache but cannot be
+    # read back without one: keep the cache out of these compiles
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_on)
+        if log_dir == "disabled":
+            os.environ.pop("TPU_LOG_DIR", None)
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """ShapeDtypeStruct factory on one described v5e chip."""
+    one = SingleDeviceSharding(topo.devices[0])
+    return lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one)
+
+
+def _compiled(fn, *args, kernel: str) -> str:
+    # the program's own matmul precision: the suite's "highest" default
+    # (conftest.py) would ask the MXU for an fp32 contraction of bf16 tiles
+    with jax.default_matmul_precision("default"):
+        txt = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in txt
+    assert kernel in txt
+    return txt
+
+
+def _junction_operands(chip, E):
+    x = chip((E, ROWS, D_MODEL))
+    w = chip((E, NOB, KB, BLOCK, BLOCK))
+    dy = chip((E, ROWS, D_FF))
+    idx = chip(PAT.idx.shape, jnp.int32)
+    return x, w, dy, idx
+
+
+@pytest.mark.parametrize("E", [1, 4])
+def test_fwd_with_bias_compiles(chip, E):
+    x, w, _, idx = _junction_operands(chip, E)
+    _compiled(lambda x, w, i, b: bsm.fwd(x, w, i, b, act="sigmoid")[0],
+              x, w, idx, chip((E, D_FF)), kernel="junction_fwd")
+
+
+def test_gated_fwd_compiles(chip):
+    x, w, _, idx = _junction_operands(chip, 1)
+    _compiled(lambda x, w, i: bsm.gated_fwd(x, w, w, i, save_res=True),
+              x, w, idx, kernel="junction_gated_fwd")
+
+
+def test_dx_compiles(chip):
+    _, w, dy, _ = _junction_operands(chip, 1)
+    rev = [chip(a.shape, jnp.int32)
+           for a in (PAT.rev_ob, PAT.rev_t, PAT.rev_cnt)]
+    _compiled(lambda dy, w, ro, rt, rc: bsm.dx(dy, w, ro, rt, rc, dy,
+                                               act="silu"),
+              dy, w, *rev, kernel="junction_dx")
+
+
+def test_dw_with_bias_compiles(chip):
+    x, _, dy, idx = _junction_operands(chip, 1)
+    _compiled(lambda x, dy, i: bsm.dw(x, dy, i, dy, act="silu"),
+              x, dy, idx, kernel="junction_dw")
+
+
+def test_fwd_int8_compiles(chip):
+    x, _, _, idx = _junction_operands(chip, 1)
+    _compiled(lambda x, w, i, s, b: bsm.fwd_int8(x, w, i, s, b),
+              x, chip((1, NOB, KB, BLOCK, BLOCK), jnp.int8), idx,
+              chip((1, NOB, KB), jnp.float32), chip((1, D_FF), jnp.float32),
+              kernel="junction_fwd_int8")
+
+
+@pytest.mark.parametrize("E", [1, 4])
+def test_update_dw_with_health_compiles(chip, E):
+    """Adam with a bias: every per-unit operand (bias and its m/v slots,
+    the health flags) is an [E, ...] array the chip must tile."""
+    x, w, dy, idx = _junction_operands(chip, E)
+    slot = chip((E, NOB, KB, BLOCK, BLOCK), jnp.float32)
+    b, b_slot = chip((E, D_FF)), chip((E, D_FF), jnp.float32)
+
+    def step(x, dy, i, w, b, m, mb, hyp):
+        return bsm.update_dw(x, dy, i, dy, w, b, m, mb, hyp, vel=m,
+                             vel_b=mb, act="sigmoid", with_health=True)
+
+    _compiled(step, x, dy, idx, w, b, slot, b_slot,
+              chip((E, bsm.HYP_K), jnp.float32), kernel="junction_update_dw")
+
+
+@pytest.mark.parametrize("E", [1, 4])
+def test_update_gated_dw_with_health_compiles(chip, E):
+    x, w, dy, idx = _junction_operands(chip, E)
+    slot = chip((E, NOB, KB, BLOCK, BLOCK), jnp.float32)
+
+    def step(x, dy, i, w, m, hyp):
+        return bsm.update_gated_dw(x, dy, i, dy, dy, w, w, m, m, hyp, vg=m,
+                                   vi=m, with_health=True)
+
+    _compiled(step, x, dy, idx, w, slot, chip((E, bsm.HYP_K), jnp.float32),
+              kernel="junction_update_gated_dw")
+
+
+@pytest.mark.parametrize("hkv,rep,hd", [(32, 1, 80), (8, 8, 128)],
+                         ids=["mha_hd80", "gqa_hd128"])
+def test_flash_decode_compiles(chip, hkv, rep, hd):
+    """stablelm-3b's head_dim 80 is not a lane multiple: the merged
+    [P, ps, Hkv*hd] page layout keeps every DMA and load lane-aligned."""
+    slots, ps, maxp = 8, 16, 36
+    pool = chip((slots * maxp + 1, ps, hkv * hd))
+    _compiled(lambda q, k, v, pt, n: fa.flash_decode(q, k, v, pt, n,
+                                                     interpret=False),
+              chip((slots, hkv, rep, hd)), pool, pool,
+              chip((slots, maxp), jnp.int32), chip((slots,), jnp.int32),
+              kernel="flash_decode")
