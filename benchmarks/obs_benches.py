@@ -1,14 +1,14 @@
 """Telemetry-overhead benchmark (ISSUE 10): the flight recorder on vs off.
 
 ``bench.obs.overhead`` times the two hot producer paths with a real
-Recorder (JSONL sink on disk, events + histograms + gauges live) against
+Recorder (JSONL sink on disk, events + histograms live) against
 the identical run with no recorder:
 
 * the guardian-instrumented regression train loop (train/train_loop.py —
   per-step TrainStep events, the guardian's host-side sentinel checks
   riding along), and
 * a continuous-serve trace (serve/engine.ContinuousEngine — per-request
-  spans, TTFT/ITL observations, occupancy gauges every tick).
+  spans, TTFT/ITL observations, the scheduler spans' durations).
 
 ``us_per_call`` is the recorder-ON wall time; ``derived`` carries the
 per-path and overall on/off ratios — the acceptance gate's number.  By
@@ -68,7 +68,7 @@ def bench(fast=True):
     dt_train_on = train_pass(rec, "on")
     rec.close()
 
-    # ---- serve path: a continuous trace with spans/hists/gauges live
+    # ---- serve path: a continuous trace with spans and histograms live
     cfg = ArchConfig(
         name="bench-obs", family="dense", n_layers=2, d_model=128,
         n_heads=4, kv_heads=2, head_dim=32, d_ff=256, vocab=128,

@@ -5,14 +5,18 @@
 
 Takes one or more ``--obs`` sink files (obs/telemetry.py) — a train
 run, a serve trace, a sweep, or any mix — and prints the merged
-timeline as four sections: train throughput curve, guardian/checkpoint
+timeline as five sections: train throughput curve, guardian/checkpoint
 event log, per-request serve latency table (p50/p99 via the shared
-nearest-rank ``obs.percentile``), and the sweep round table.
+nearest-rank ``obs.percentile``; TTFT from admission and from arrival),
+the sweep round table, and the host time of each program span
+(``obs.span``'s ``span.<name>_s`` histograms, from the recorder's
+summary frame).
 
 ``--check-spans`` additionally validates every ``serve.span`` event's
-lifecycle (enqueue ≤ admit ≤ first token ≤ finish, tokens produced,
-guard-terminated requests allowed a missing first token) and exits
-non-zero on any violation — the CI obs smoke gate.
+lifecycle (enqueue ≤ admit ≤ first token ≤ finish, a queue wait that is
+not negative, tokens produced, guard-terminated requests allowed a
+missing first token) and exits non-zero on any violation — the CI obs
+smoke gate.
 
 ``--json OUT`` writes the machine-readable report stamped with the
 ``repro.artifacts.artifact_meta`` schema, same as BENCH_*.json and
@@ -42,6 +46,8 @@ def check_span(ev: dict) -> str | None:
     if not ev.get("enqueue_tick", 0) <= ev.get("admit_tick", -1):
         return (f"span rid={rid}: admitted (tick {ev.get('admit_tick')}) "
                 f"before enqueue (tick {ev.get('enqueue_tick')})")
+    if ev.get("queue_s", 0.0) < 0:
+        return f"span rid={rid}: negative queue wait {ev.get('queue_s')}"
     if ev.get("admit_tick", 0) > ev.get("finish_tick", -1):
         return (f"span rid={rid}: finished (tick {ev.get('finish_tick')}) "
                 f"before admit (tick {ev.get('admit_tick')})")
@@ -99,6 +105,9 @@ def build_report(events: list[dict]) -> dict:
     if spans:
         walls = [e["wall_s"] for e in spans]
         ttfts = [e["ttft_s"] for e in spans if e.get("ttft_s", -1) >= 0]
+        # from arrival: the queue wait before admission plus TTFT
+        arrs = [e["ttft_s"] + e["queue_s"] for e in spans
+                if e.get("ttft_s", -1) >= 0 and "queue_s" in e]
         outcomes: dict[str, int] = {}
         for e in spans:
             outcomes[e["outcome"]] = outcomes.get(e["outcome"], 0) + 1
@@ -108,6 +117,8 @@ def build_report(events: list[dict]) -> dict:
             "wall_p99_s": percentile(walls, 99),
             "ttft_p50_s": percentile(ttfts, 50) if ttfts else None,
             "ttft_p99_s": percentile(ttfts, 99) if ttfts else None,
+            "ttft_arrival_p50_s": percentile(arrs, 50) if arrs else None,
+            "ttft_arrival_p99_s": percentile(arrs, 99) if arrs else None,
             "spans": sorted(spans, key=lambda e: e["rid"]),
         }
 
@@ -128,7 +139,15 @@ def build_report(events: list[dict]) -> dict:
     summaries = by_kind.get("summary", [])
     if summaries:
         report["recorder_summary"] = summaries[-1]
+        hists = summaries[-1].get("histograms", {})
+        report["spans"] = {k[len("span."):-len("_s")]: v
+                           for k, v in sorted(hists.items())
+                           if k.startswith("span.") and v.get("count")}
     return report
+
+
+def _ms(v) -> str:
+    return f"{v * 1e3:.1f}" if v is not None else "-"
 
 
 def _print_report(report: dict, log=print) -> None:
@@ -147,26 +166,30 @@ def _print_report(report: dict, log=print) -> None:
             f"{e['detail']}")
     sv = report.get("serve")
     if sv:
-        t50 = (f"{sv['ttft_p50_s']*1e3:.1f}" if sv["ttft_p50_s"] is not None
-               else "-")
-        t99 = (f"{sv['ttft_p99_s']*1e3:.1f}" if sv["ttft_p99_s"] is not None
-               else "-")
         log(f"[obs] serve: {sv['requests']} requests {sv['outcomes']}, "
             f"wall p50 {sv['wall_p50_s']*1e3:.1f}ms "
             f"p99 {sv['wall_p99_s']*1e3:.1f}ms, "
-            f"ttft p50 {t50}ms p99 {t99}ms")
+            f"ttft p50 {_ms(sv['ttft_p50_s'])}ms "
+            f"p99 {_ms(sv['ttft_p99_s'])}ms, from arrival "
+            f"p50 {_ms(sv['ttft_arrival_p50_s'])}ms "
+            f"p99 {_ms(sv['ttft_arrival_p99_s'])}ms")
         for s in sv["spans"]:
             log(f"[obs]   rid {s['rid']:>4} {s['outcome']:<8} "
                 f"enq {s['enqueue_tick']:>4} adm {s['admit_tick']:>4} "
                 f"tok1 {s['first_token_tick']:>4} "
                 f"fin {s['finish_tick']:>4} "
                 f"chunks {s['prefill_chunks']} n {s['n_tokens']} "
+                f"queue {_ms(s.get('queue_s'))}ms "
                 f"wall {s['wall_s']*1e3:.1f}ms")
     for r in report.get("sweep", []):
         who = (f" member {r['member']} (cohort {r['cohort']} "
                f"slot {r['slot']})" if "member" in r else
                f" live={r.get('live')}")
         log(f"[obs] sweep round {r['round']}: {r['action']}{who}")
+    for name, h in report.get("spans", {}).items():
+        log(f"[obs] span {name}: {h['count']} x, total "
+            f"{h['mean'] * h['count']:.3f}s, p50 {_ms(h['p50'])}ms "
+            f"p99 {_ms(h['p99'])}ms max {_ms(h['max'])}ms")
 
 
 def main(argv=None) -> int:

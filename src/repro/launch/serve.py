@@ -47,10 +47,10 @@ def main():
                     help="[continuous] synthetic trace: one request every "
                          "N scheduler ticks (0: all arrive at tick 0)")
     ap.add_argument("--obs", default=None, metavar="PATH",
-                    help="flight-recorder JSONL sink: per-request spans + "
-                         "TTFT/ITL histograms + occupancy gauges "
-                         "(continuous engine); render with "
-                         "repro.launch.obs_report")
+                    help="flight-recorder JSONL sink: per-request spans "
+                         "(queue wait, TTFT) + TTFT/ITL histograms + the "
+                         "host time of each scheduler span (continuous "
+                         "engine); render with repro.launch.obs_report")
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="wrap the run in a jax.profiler trace written to "
                          "DIR (kernels show up named by KernelSpec)")
@@ -141,6 +141,8 @@ def main():
         print(f"[serve] decode_ticks={st['decode_ticks']} "
               f"prefill_chunks={st['prefill_chunks']} "
               f"peak_pages={st['peak_pages']}/{st['num_pages']} "
+              f"occupancy={st['decode_slot_ticks']}/"
+              f"{args.slots * st['decode_ticks']} "
               f"traces={st['decode_traces']}/{st['prefill_traces']} "
               f"p50_lat={percentile(waits, 50) * 1e3:.1f}ms "
               f"p99_lat={percentile(waits, 99) * 1e3:.1f}ms")

@@ -54,7 +54,8 @@ def _parse(argv=None):
     ap.add_argument("--out", default="SWEEP_mnist.json")
     ap.add_argument("--obs", default=None, metavar="PATH",
                     help="flight-recorder JSONL sink: rank/prune/"
-                         "quarantine round events; render with "
+                         "quarantine round events and the host time of "
+                         "each scheduler span; render with "
                          "repro.launch.obs_report")
     ap.add_argument("--profile", default=None, metavar="DIR",
                     help="wrap the sweep in a jax.profiler trace written "
@@ -131,6 +132,8 @@ def main(argv=None):
                   f"({recorder.n_events} events)")
     led = result.ledger
     led.save(args.out)
+    print(f"[sweep] traces: step {led.meta['step_traces']}, eval "
+          f"{led.meta['eval_traces']} ({n_cohorts} cohort(s))")
 
     for m in sorted(led.members, key=lambda m: (m.pruned_at is None,
                                                 m.rounds_survived)):

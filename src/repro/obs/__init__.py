@@ -11,10 +11,11 @@ from repro.obs.telemetry import (  # noqa: F401
     percentile,
     profile_ctx,
     read_events,
+    span,
 )
 
 __all__ = [
     "Checkpoint", "Guardian", "Histogram", "NOT_SAMPLED", "Recorder",
     "RequestSpan", "SweepRound", "TrainStep", "percentile", "profile_ctx",
-    "read_events",
+    "read_events", "span",
 ]

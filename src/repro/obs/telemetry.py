@@ -12,11 +12,10 @@ The recorder carries three aggregate families plus an event stream:
 
 * **counters** — monotonically increasing ints (``count``): steps run,
   requests finished per outcome, checkpoints written;
-* **gauges** — latest-value floats (``gauge``): pages in use, slots
-  decoding, current lr_scale;
+* **gauges** — latest-value floats (``gauge``): the current lr_scale;
 * **histograms** — bounded sample windows (``observe``) with
   nearest-rank percentiles (:func:`percentile`): step latency, TTFT,
-  inter-token latency;
+  inter-token latency, and each :func:`span`'s duration;
 * **events** — typed frozen dataclasses (:class:`TrainStep`,
   :class:`Guardian`, :class:`Checkpoint`, :class:`RequestSpan`,
   :class:`SweepRound`) appended to a bounded in-memory ring and, when a
@@ -57,7 +56,54 @@ taken) → ``prefill_chunks`` fixed-shape chunks → ``first_token_tick`` /
 ``ttft_s`` (sampled off the final prefill chunk's logits) →
 ``finish_tick`` with ``outcome`` ∈ {``eos``, ``max_new``, ``guard``}.
 ``ttft_s`` / ``first_token_tick`` are ``-1`` when the request never
-produced a token (guard-terminated during prefill).
+produced a token (guard-terminated during prefill).  ``queue_s`` is the
+wall time from the scheduler iteration in which the engine's tick
+reached the request's ``arrival`` (the ``serve()`` entry, for arrival 0)
+to its admission, so TTFT from arrival is ``queue_s + ttft_s``.
+
+Spans
+-----
+:func:`span` is the program's one span API: a context manager that opens
+``jax.profiler.TraceAnnotation("repro." + name)``.  Under a profiler
+(``profile_ctx``, ``launch/* --profile``, or any ``jax.profiler.trace``)
+the span lands in the trace's ``/host:CPU`` plane on the Python thread,
+on the clock the device's ``XLA Ops`` use, so an idle gap on the device
+can be put down to the host work open at that instant.  That clock is
+the wall clock less the trace's ``profile_start_time`` (a stat of its
+``Task Environment`` plane): a Recorder event's ``ts`` lands inside the
+span it was emitted in.  With a Recorder, the span also observes its
+duration into the histogram ``span.<name>_s``, so a run without a
+profiler still shows where host time goes (``obs_report`` prints them).
+Without a profiler a span costs one ``TraceAnnotation`` construction.
+
+The no-extra-device-sync contract covers spans: a span never fetches,
+never blocks and adds no traced op (jaxprs are identical inside and
+outside one).  The names, and how they nest (an inner span is open
+only inside its outer one):
+
+* ``repro.sweep.setup`` — ``search/scheduler.run_sweep`` from entry to
+  the first cohort step: the data's host→device copies and each
+  cohort's weights, slots, hyp table, step and eval builders and padded
+  targets;
+* ``repro.sweep.first_step`` — a cohort's first step of the call (its
+  trace, lowering and compile-cache fetch), ``repro.sweep.step`` —
+  each later cohort step (hyp stamp, dispatch, bookkeeping); both hold
+  ``repro.sweep.fetch``, the fetch of the step's losses and health;
+* ``repro.sweep.eval`` — one cohort's eval in a round, its fetch
+  included; ``repro.sweep.prune`` — rank, halve and the mask/hyp writes;
+* ``repro.serve.setup`` — ``serve/engine.ContinuousEngine.serve``'s
+  paged cache, slots and sorted queue;
+* ``repro.serve.admit`` — one scheduler iteration's admission loop,
+  page allocation included (one per iteration);
+* ``repro.serve.prefill`` — one prefill chunk: its build, dispatch and
+  the first token's sampling; ``repro.serve.decode`` — one decode
+  tick: the host build of tokens, positions and page table, dispatch,
+  per-slot bookkeeping and ``finish``; both hold ``repro.serve.fetch``,
+  the fetch of the chunk's last logits or the tick's tokens.
+
+Between two ticks the engine is always inside one of the serve spans
+but for a few list comprehensions; the sweep leaves each global step's
+batch gather outside its cohort spans.
 """
 from __future__ import annotations
 
@@ -72,7 +118,12 @@ from typing import Any, ClassVar, IO, Iterable, Optional
 __all__ = [
     "Checkpoint", "Guardian", "Histogram", "Recorder", "RequestSpan",
     "SweepRound", "TrainStep", "percentile", "profile_ctx", "read_events",
+    "span",
 ]
+
+#: prefix of every program span in a profiler trace (``chipbench.`` is
+#: the benchmark harness's own)
+SPAN_PREFIX = "repro."
 
 #: histogram value meaning "producer did not sync this value on this
 #: path" — recorded instead of forcing a device→host transfer
@@ -109,6 +160,40 @@ def profile_ctx(trace_dir: str | None):
         return contextlib.nullcontext()
     import jax
     return jax.profiler.trace(trace_dir)
+
+
+_trace_annotation = None      # jax.profiler.TraceAnnotation, at first use
+
+
+class _Span:
+    __slots__ = ("_ann", "_rec", "_key", "_t0")
+
+    def __init__(self, name: str, recorder: "Recorder | None"):
+        global _trace_annotation
+        if _trace_annotation is None:
+            from jax.profiler import TraceAnnotation as _trace_annotation
+        self._ann = _trace_annotation(SPAN_PREFIX + name)
+        self._rec = recorder
+        self._key = f"span.{name}_s" if recorder is not None else None
+
+    def __enter__(self) -> "_Span":
+        self._ann.__enter__()
+        if self._rec is not None:
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._rec is not None:
+            self._rec.observe(self._key, time.perf_counter() - self._t0)
+        self._ann.__exit__(*exc)
+
+
+def span(name: str, recorder: "Recorder | None" = None) -> _Span:
+    """A host span ``repro.<name>`` in the profiler's trace and, with a
+    ``recorder``, a ``span.<name>_s`` duration sample (see the module
+    docstring's "Spans" section).  Host-only: never fetches or blocks.
+    jax is imported at first use, so ``--help`` paths stay jax-free."""
+    return _Span(name, recorder)
 
 
 def _ensure_host(name: str, v: Any) -> Any:
@@ -181,6 +266,7 @@ class RequestSpan:
     n_tokens: int
     ttft_s: float           # admit -> first token wall time; -1: no token
     wall_s: float           # admit -> finish wall time
+    queue_s: float          # arrival -> admit wall time
 
 
 @dataclasses.dataclass(frozen=True)

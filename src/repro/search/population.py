@@ -338,7 +338,8 @@ def _repack_slots(new_slots: tuple, like):
 
 def make_population_step(act: str = "sigmoid", *, engine: str = "auto",
                          fused: bool = True, jit: bool = True,
-                         donate: bool = True, with_health: bool = False):
+                         donate: bool = True, with_health: bool = False,
+                         traces: dict | None = None):
     """step(params, slots, hyp, mask, x, t) -> (params, slots, losses[E])
     — or (params, slots, losses, health[E]) with ``with_health``.
 
@@ -360,11 +361,16 @@ def make_population_step(act: str = "sigmoid", *, engine: str = "auto",
     non-finite.  Fused path: the in-kernel [E] health flags (the grads
     never exist in HBM to inspect); two-pass path: a non-finite scan over
     the materialized per-member grads.  Member independence means a bad
-    member flags ONLY its own slot."""
+    member flags ONLY its own slot.
+
+    ``traces`` (a dict) counts the step's traces under ``"step"``: a
+    traced-time side effect, like ``ContinuousEngine.decode_traces``."""
     engine = sl.resolve_engine(engine)
     use_fused = fused and engine == "pallas"
 
     def step(params, mom, hyp, mask, x, t):
+        if traces is not None:
+            traces["step"] = traces.get("step", 0) + 1
         slots = sl.normalize_slots(mom)
         if use_fused:
             aug = sl.inject_update_ctx(params, slots, hyp)
@@ -402,12 +408,15 @@ def make_population_step(act: str = "sigmoid", *, engine: str = "auto",
 
 
 def make_population_eval(act: str = "sigmoid", *, engine: str = "auto",
-                         jit: bool = True):
+                         jit: bool = True, traces: dict | None = None):
     """eval(params, x, t) -> per-member losses [E] (no update, no mask —
-    the scheduler ranks live members and ignores pruned slots)."""
+    the scheduler ranks live members and ignores pruned slots).
+    ``traces`` counts its traces under ``"eval"``, as the step's."""
     engine = sl.resolve_engine(engine)
 
     def evaluate(params, x, t):
+        if traces is not None:
+            traces["eval"] = traces.get("eval", 0) + 1
         y = population_forward(params, x, act=act, engine=engine)
         return member_losses(y, t)
 

@@ -147,23 +147,10 @@ def _quarantine(st: CohortState, rec: MemberRecord, rnd: int,
             detail={"step": global_step}))
 
 
-def run_sweep(specs: Sequence[pop.CandidateSpec], x_train, t_train,
-              x_eval, t_eval, cfg: SweepConfig, *,
-              tag: str = "",
-              recorder: "obs.Recorder | None" = None) -> SweepResult:
-    """Train all candidates population-parallel and successively halve.
-
-    x_* [N, n_in] float, t_* [N, n_classes] one-hot (padded per cohort to
-    its output width).  Returns the lineage ledger (winner marked) and
-    the final cohort states.
-
-    ``recorder`` (obs.Recorder) gets one ``obs.SweepRound`` event per
-    scheduler decision — rank (once per round, the scored table in
-    ``detail``), prune and quarantine (one per affected member, its
-    cohort/slot attached), winner — so a sweep's ledger and its
-    telemetry share one timeline.  All values are host floats the
-    scheduler already fetched for ranking."""
-    specs = list(specs)
+def _setup(specs, x_train, t_train, x_eval, t_eval, cfg: SweepConfig,
+           tag: str, traces: dict):
+    """The ledger, each cohort's state (weights, slots, hyp table, step
+    and eval, padded targets) and the data on the device."""
     x_train = np.asarray(x_train, np.float32)
     t_train = np.asarray(t_train, np.float32)
     x_eval = np.asarray(x_eval, np.float32)[:cfg.eval_samples]
@@ -174,8 +161,6 @@ def run_sweep(specs: Sequence[pop.CandidateSpec], x_train, t_train,
                               steps_per_round=cfg.steps_per_round,
                               n_candidates=len(specs)))
     key = jax.random.PRNGKey(cfg.seed)
-    x_train_d = jnp.asarray(x_train)
-    x_eval_d = jnp.asarray(x_eval)
     states: list[CohortState] = []
     for ci, cohort in enumerate(ch.bucket(specs)):
         spec0 = cohort.specs[0]
@@ -197,15 +182,41 @@ def run_sweep(specs: Sequence[pop.CandidateSpec], x_train, t_train,
             records=records,
             step=pop.make_population_step(spec0.act, engine=cfg.engine,
                                           fused=cfg.fused,
-                                          with_health=cfg.quarantine),
+                                          with_health=cfg.quarantine,
+                                          traces=traces),
             evaluate=pop.make_population_eval(spec0.act,
-                                              engine=cfg.engine),
+                                              engine=cfg.engine,
+                                              traces=traces),
             # targets are constant per cohort: pad + upload once, slice
             # per minibatch on device
             t_train_pad=jnp.asarray(_pad_targets(t_train, spec0.layers[-1])),
             t_eval_pad=jnp.asarray(_pad_targets(t_eval, spec0.layers[-1]))))
+    return ledger, states, jnp.asarray(x_train), jnp.asarray(x_eval)
 
-    n_train = x_train.shape[0]
+
+def run_sweep(specs: Sequence[pop.CandidateSpec], x_train, t_train,
+              x_eval, t_eval, cfg: SweepConfig, *,
+              tag: str = "",
+              recorder: "obs.Recorder | None" = None) -> SweepResult:
+    """Train all candidates population-parallel and successively halve.
+
+    x_* [N, n_in] float, t_* [N, n_classes] one-hot (padded per cohort to
+    its output width).  Returns the lineage ledger (winner marked) and
+    the final cohort states.
+
+    ``recorder`` (obs.Recorder) gets one ``obs.SweepRound`` event per
+    scheduler decision — rank (once per round, the scored table in
+    ``detail``), prune and quarantine (one per affected member, its
+    cohort/slot attached), winner — so a sweep's ledger and its
+    telemetry share one timeline.  All values are host floats the
+    scheduler already fetched for ranking."""
+    specs = list(specs)
+    traces = {"step": 0, "eval": 0}
+    with obs.span("sweep.setup", recorder):
+        ledger, states, x_train_d, x_eval_d = _setup(
+            specs, x_train, t_train, x_eval, t_eval, cfg, tag, traces)
+
+    n_train = x_train_d.shape[0]
     global_step = 0
     n_live = len(specs)
     for rnd in range(cfg.rounds):
@@ -214,35 +225,42 @@ def run_sweep(specs: Sequence[pop.CandidateSpec], x_train, t_train,
             bi = jnp.asarray(_batch_indices(
                 n_train, min(cfg.batch_size, n_train), global_step))
             xb = jnp.take(x_train_d, bi, axis=0)
+            # the first cohort step traces, lowers and fetches the step
+            step_span = "sweep.first_step" if global_step == 0 else \
+                "sweep.step"
             for st in states:
                 if not any(r.pruned_at is None for r in st.records):
                     continue        # whole cohort pruned: steps are no-ops
-                if st.is_adam:
-                    # stamp the per-step bias-correction time into every
-                    # row: all live members step in lockstep, and on a
-                    # quarantined (zeroed) row t is harmless — lr = 0 and
-                    # the masked gradients are exact zeros, so the
-                    # kernels still write w' = w, m' = v' = 0
-                    from repro.kernels import block_sparse_matmul as bsm
-                    st.hyp = st.hyp.at[:, bsm.COL_T].set(
-                        jnp.float32(global_step + 1))
-                out = st.step(
-                    st.params, st.mom, st.hyp, st.mask, xb,
-                    jnp.take(st.t_train_pad, bi, axis=0))
-                if cfg.quarantine:
-                    st.params, st.mom, losses, health = out
-                    health = np.asarray(health)
-                else:
-                    st.params, st.mom, losses = out
-                    health = None
-                for rec, loss in zip(st.records, np.asarray(losses)):
-                    if rec.pruned_at is None:
-                        rec.loss_curve.append(float(loss))
-                        if cfg.quarantine and (
-                                not math.isfinite(float(loss))
-                                or health[rec.slot] > 0):
-                            _quarantine(st, rec, rnd, global_step,
-                                        recorder=recorder)
+                with obs.span(step_span, recorder):
+                    if st.is_adam:
+                        # stamp the per-step bias-correction time into
+                        # every row: all live members step in lockstep,
+                        # and on a quarantined (zeroed) row t is harmless
+                        # — lr = 0 and the masked gradients are exact
+                        # zeros, so the kernels still write w' = w,
+                        # m' = v' = 0
+                        from repro.kernels import block_sparse_matmul as bsm
+                        st.hyp = st.hyp.at[:, bsm.COL_T].set(
+                            jnp.float32(global_step + 1))
+                    out = st.step(
+                        st.params, st.mom, st.hyp, st.mask, xb,
+                        jnp.take(st.t_train_pad, bi, axis=0))
+                    with obs.span("sweep.fetch", recorder):
+                        if cfg.quarantine:
+                            st.params, st.mom, losses, health = out
+                            health = np.asarray(health)
+                        else:
+                            st.params, st.mom, losses = out
+                            health = None
+                        losses = np.asarray(losses)
+                    for rec, loss in zip(st.records, losses):
+                        if rec.pruned_at is None:
+                            rec.loss_curve.append(float(loss))
+                            if cfg.quarantine and (
+                                    not math.isfinite(float(loss))
+                                    or health[rec.slot] > 0):
+                                _quarantine(st, rec, rnd, global_step,
+                                            recorder=recorder)
             global_step += 1
 
         # -- eval: vectorized per-member loss, live members only ranked
@@ -250,39 +268,18 @@ def run_sweep(specs: Sequence[pop.CandidateSpec], x_train, t_train,
         for ci, st in enumerate(states):
             if not any(r.pruned_at is None for r in st.records):
                 continue
-            ev = np.asarray(st.evaluate(st.params, x_eval_d, st.t_eval_pad))
-            for rec, loss in zip(st.records, ev):
-                if rec.pruned_at is None:
-                    rec.eval_losses.append(float(loss))
-                    rec.rounds_survived = rnd + 1
-                    scored.append((_score(loss, st.out_width), ci, rec.slot))
-        if recorder is not None and scored:
-            recorder.emit(obs.SweepRound(
-                action="rank", round=rnd,
-                detail={"live": len(scored), "scores": [
-                    {"member": states[ci].records[slot].member,
-                     "cohort": ci, "slot": slot,
-                     "score": s if math.isfinite(s) else None}
-                    for s, ci, slot in sorted(scored)]}))
-
-        # -- halve: keep the globally best keep_fraction, zero the rest
-        if rnd < cfg.rounds - 1 and len(scored) > 1:
-            scored.sort()
-            n_keep = max(1, int(math.ceil(len(scored) * cfg.keep_fraction)))
-            for sc, ci, slot in scored[n_keep:]:
-                st = states[ci]
-                st.mask = st.mask.at[slot].set(0.0)
-                st.hyp = st.hyp.at[slot].set(0.0)
-                st.records[slot].pruned_at = rnd
-                if recorder is not None:
-                    recorder.count("sweep.pruned")
-                    recorder.emit(obs.SweepRound(
-                        action="prune", round=rnd,
-                        member=st.records[slot].member, cohort=ci,
-                        slot=slot,
-                        detail={"score": sc if math.isfinite(sc)
-                                else None}))
-            n_live = n_keep
+            with obs.span("sweep.eval", recorder):
+                ev = np.asarray(st.evaluate(st.params, x_eval_d,
+                                            st.t_eval_pad))
+                for rec, loss in zip(st.records, ev):
+                    if rec.pruned_at is None:
+                        rec.eval_losses.append(float(loss))
+                        rec.rounds_survived = rnd + 1
+                        scored.append((_score(loss, st.out_width), ci,
+                                       rec.slot))
+        with obs.span("sweep.prune", recorder):
+            n_live = _rank_and_halve(states, scored, rnd, cfg, n_live,
+                                     recorder)
 
     # -- winner: best width-normalized final eval score among survivors
     best = min(((_score(m.eval_losses[-1], st.out_width), m.member)
@@ -300,4 +297,36 @@ def run_sweep(specs: Sequence[pop.CandidateSpec], x_train, t_train,
     ledger.meta["live_at_end"] = n_live
     ledger.meta["quarantined"] = sum(
         1 for m in ledger.members if m.quarantined_at is not None)
+    ledger.meta["step_traces"] = traces["step"]
+    ledger.meta["eval_traces"] = traces["eval"]
     return SweepResult(ledger=ledger, states=states)
+
+
+def _rank_and_halve(states, scored, rnd, cfg, n_live, recorder):
+    """Emit the round's rank event and, before the last round, keep the
+    globally best keep_fraction of live members and zero the rest.
+    Returns the live count."""
+    if recorder is not None and scored:
+        recorder.emit(obs.SweepRound(
+            action="rank", round=rnd,
+            detail={"live": len(scored), "scores": [
+                {"member": states[ci].records[slot].member,
+                 "cohort": ci, "slot": slot,
+                 "score": s if math.isfinite(s) else None}
+                for s, ci, slot in sorted(scored)]}))
+    if rnd >= cfg.rounds - 1 or len(scored) <= 1:
+        return n_live
+    scored.sort()
+    n_keep = max(1, int(math.ceil(len(scored) * cfg.keep_fraction)))
+    for sc, ci, slot in scored[n_keep:]:
+        st = states[ci]
+        st.mask = st.mask.at[slot].set(0.0)
+        st.hyp = st.hyp.at[slot].set(0.0)
+        st.records[slot].pruned_at = rnd
+        if recorder is not None:
+            recorder.count("sweep.pruned")
+            recorder.emit(obs.SweepRound(
+                action="prune", round=rnd,
+                member=st.records[slot].member, cohort=ci, slot=slot,
+                detail={"score": sc if math.isfinite(sc) else None}))
+    return n_keep

@@ -219,8 +219,8 @@ _FREE, _PREFILL, _DECODE = 0, 1, 2
 
 class _Slot:
     __slots__ = ("state", "req", "pages", "cache_len", "prefill_pos", "out",
-                 "last_tok", "t_admit", "t_wall", "t_first", "t_last",
-                 "first_tick", "chunks")
+                 "last_tok", "t_admit", "t_wall", "t_queue", "t_first",
+                 "t_last", "first_tick", "chunks")
 
     def __init__(self):
         self.state = _FREE
@@ -232,6 +232,7 @@ class _Slot:
         self.last_tok = 0         # sampled, not yet fed through decode
         self.t_admit = 0
         self.t_wall = 0.0
+        self.t_queue = 0.0        # arrival -> admission wall time
         # span bookkeeping (obs.RequestSpan): first-token wall time /
         # tick, previous-token wall time (inter-token latency), and the
         # number of fixed-shape prefill chunks this request consumed
@@ -251,16 +252,23 @@ class ContinuousEngine:
     over the static engine).  ``stats`` carries per-request latencies
     and the page accounting afterwards.
 
-    With a ``recorder`` (obs.Recorder) attached, every finished request
-    emits one ``obs.RequestSpan`` reconstructing its whole lifecycle
-    (enqueue → admit → prefill chunks → first token → finish, with the
-    outcome eos | max_new | guard), TTFT and inter-token latencies land
-    in histograms, and page-pool / slot-occupancy gauges refresh every
-    scheduler tick.  All of it rides values the scheduler already
-    pulled to host (the sampled token, the guard flag) — no extra
-    syncs, no traced ops, and the ``decode_traces == 1`` /
-    ``prefill_traces == 1`` compile-once contract holds with telemetry
-    on (regression-tested)."""
+    ``stats["decode_slot_ticks"]`` sums the decoding slots over the
+    decode ticks (occupancy is that over ``slots * decode_ticks``) and
+    ``stats["peak_pages"]`` is the page high-water mark.
+
+    Each scheduler iteration runs inside the ``obs.span``s
+    ``repro.serve.{admit,prefill,decode}`` (``fetch`` nested in the last
+    two; obs/telemetry.py, "Spans"), so a profiler trace puts every gap
+    between two device ticks down to the host work in it.  With a
+    ``recorder`` (obs.Recorder) attached, every finished request emits
+    one ``obs.RequestSpan`` reconstructing its whole lifecycle (enqueue
+    → admit → prefill chunks → first token → finish, with the outcome
+    eos | max_new | guard, and its queue wait), TTFT, inter-token
+    latencies and span durations land in histograms.  All of it rides
+    values the scheduler already pulled to host (the sampled token, the
+    guard flag) — no extra syncs, no traced ops, and the
+    ``decode_traces == 1`` / ``prefill_traces == 1`` compile-once
+    contract holds with telemetry on (regression-tested)."""
 
     def __init__(self, cfg: ArchConfig, params,
                  serve_cfg: ServeConfig | None = None,
@@ -332,6 +340,8 @@ class ContinuousEngine:
         B, ps = scfg.slots, scfg.page_size
         maxp = self.pages_per_slot
         num_pages = scfg.num_pages or (B * maxp + 1)
+        rec = self.rec
+        t_serve0 = time.perf_counter()
         for r in requests:
             need = len(r.prompt) + r.max_new_tokens
             if need > self.max_seq:
@@ -341,23 +351,26 @@ class ContinuousEngine:
             if -(-need // ps) > num_pages - 1:
                 raise ValueError(
                     f"request {r.rid} needs more pages than the pool holds")
-        pool_acct = PagePool(num_pages, ps)
-        pool = M.make_paged_cache(self.cfg, num_pages, ps)
-        slots = [_Slot() for _ in range(B)]
-        # FIFO within arrival order (stable sort keeps submission order)
-        queue = collections.deque(sorted(requests, key=lambda r: r.arrival))
-        root = jax.random.PRNGKey(scfg.seed)
+        with obs.span("serve.setup", rec):
+            pool_acct = PagePool(num_pages, ps)
+            pool = M.make_paged_cache(self.cfg, num_pages, ps)
+            slots = [_Slot() for _ in range(B)]
+            # FIFO within arrival order (stable sort keeps submission order)
+            queue = collections.deque(sorted(requests,
+                                             key=lambda r: r.arrival))
+            arrivals = list(queue)      # the tick stamps these in order
+            root = jax.random.PRNGKey(scfg.seed)
         self.nonfinite_terminated = 0
         eos = scfg.eos_token
         guard = scfg.guard_nonfinite
         outputs: dict[int, np.ndarray] = {}
         lat: dict[int, dict] = {}
+        t_arrive: dict[int, float] = {}   # rid -> wall time the tick reached it
+        n_arrived = 0
         tick = 0
-        decode_ticks = prefill_chunks = 0
+        decode_ticks = prefill_chunks = decode_slot_ticks = 0
         pf_cursor = 0               # round-robin over prefilling slots
-        t_serve0 = time.perf_counter()
-
-        rec = self.rec
+        t_iter = t_serve0           # start of this scheduler iteration
 
         def finish(s: _Slot, outcome: str):
             r = s.req
@@ -368,7 +381,8 @@ class ContinuousEngine:
                           "ttft_s": ttft, "first_token_tick": s.first_tick,
                           "prefill_chunks": s.chunks,
                           "n_tokens": len(s.out),
-                          "wall_s": time.perf_counter() - s.t_wall}
+                          "wall_s": time.perf_counter() - s.t_wall,
+                          "queue_s": s.t_queue}
             if rec is not None:
                 rec.count(f"serve.finish.{outcome}")
                 if ttft >= 0:
@@ -378,7 +392,7 @@ class ContinuousEngine:
                     admit_tick=s.t_admit, first_token_tick=s.first_tick,
                     finish_tick=tick, prefill_chunks=s.chunks,
                     n_tokens=len(s.out), ttft_s=ttft,
-                    wall_s=lat[r.rid]["wall_s"]))
+                    wall_s=lat[r.rid]["wall_s"], queue_s=s.t_queue))
             pool_acct.release(s.pages)
             s.__init__()            # back to FREE
 
@@ -400,107 +414,112 @@ class ContinuousEngine:
 
         while queue or any(s.state != _FREE for s in slots):
             # ---- admission: refill free slots from the arrival queue
-            for s in slots:
-                if s.state != _FREE or not queue:
-                    continue
-                if queue[0].arrival > tick:
-                    break
-                need = pool_acct.pages_for(
-                    len(queue[0].prompt) + queue[0].max_new_tokens)
-                pages = pool_acct.alloc(need)
-                if pages is None:
-                    break           # pool full: stays queued, retry next tick
-                r = queue.popleft()
-                s.state = _PREFILL
-                s.req = r
-                s.pages = pages
-                s.cache_len = 0
-                s.prefill_pos = 0
-                s.out = []
-                s.t_admit = tick
-                s.t_wall = time.perf_counter()
+            with obs.span("serve.admit", rec):
+                while (n_arrived < len(arrivals)
+                       and arrivals[n_arrived].arrival <= tick):
+                    t_arrive[arrivals[n_arrived].rid] = t_iter
+                    n_arrived += 1
+                for s in slots:
+                    if s.state != _FREE or not queue:
+                        continue
+                    if queue[0].arrival > tick:
+                        break
+                    need = pool_acct.pages_for(
+                        len(queue[0].prompt) + queue[0].max_new_tokens)
+                    pages = pool_acct.alloc(need)
+                    if pages is None:
+                        break       # pool full: stays queued, retry next tick
+                    r = queue.popleft()
+                    s.state = _PREFILL
+                    s.req = r
+                    s.pages = pages
+                    s.cache_len = 0
+                    s.prefill_pos = 0
+                    s.out = []
+                    s.t_admit = tick
+                    s.t_wall = time.perf_counter()
+                    s.t_queue = s.t_wall - t_arrive[r.rid]
 
             # ---- one prefill chunk (round-robin), interleaved with decode
             pf_slots = [i for i, s in enumerate(slots) if s.state == _PREFILL]
             if pf_slots:
-                i = pf_slots[pf_cursor % len(pf_slots)]
-                pf_cursor += 1
-                s = slots[i]
-                prompt = s.req.prompt
-                C = scfg.prefill_chunk
-                cl = min(C, len(prompt) - s.prefill_pos)
-                buf = np.zeros((1, C), np.int32)
-                buf[0, :cl] = prompt[s.prefill_pos:s.prefill_pos + cl]
-                ptrow = self._page_row(s, maxp)
-                last_logits, pool = self._prefill_chunk(
-                    self.params, pool, jnp.asarray(buf),
-                    jnp.asarray(s.prefill_pos, jnp.int32),
-                    jnp.asarray(ptrow), jnp.asarray(cl, jnp.int32))
-                prefill_chunks += 1
-                s.chunks += 1
-                s.prefill_pos += cl
-                s.cache_len = s.prefill_pos
-                if s.prefill_pos == len(prompt):
-                    row = np.asarray(last_logits)[0]
-                    bad = not np.all(np.isfinite(row))
-                    if guard and bad:
-                        self.nonfinite_terminated += 1
-                        s.out.append(eos if eos >= 0 else 0)
-                        finish(s, "guard")
-                    else:
-                        key = jax.random.fold_in(root, 2 * tick)
-                        oc = step_done(s, self._sample_host(row, key))
-                        if oc:
-                            finish(s, oc)
+                with obs.span("serve.prefill", rec):
+                    i = pf_slots[pf_cursor % len(pf_slots)]
+                    pf_cursor += 1
+                    s = slots[i]
+                    prompt = s.req.prompt
+                    C = scfg.prefill_chunk
+                    cl = min(C, len(prompt) - s.prefill_pos)
+                    buf = np.zeros((1, C), np.int32)
+                    buf[0, :cl] = prompt[s.prefill_pos:s.prefill_pos + cl]
+                    ptrow = self._page_row(s, maxp)
+                    last_logits, pool = self._prefill_chunk(
+                        self.params, pool, jnp.asarray(buf),
+                        jnp.asarray(s.prefill_pos, jnp.int32),
+                        jnp.asarray(ptrow), jnp.asarray(cl, jnp.int32))
+                    prefill_chunks += 1
+                    s.chunks += 1
+                    s.prefill_pos += cl
+                    s.cache_len = s.prefill_pos
+                    if s.prefill_pos == len(prompt):
+                        with obs.span("serve.fetch", rec):
+                            row = np.asarray(last_logits)[0]
+                        bad = not np.all(np.isfinite(row))
+                        if guard and bad:
+                            self.nonfinite_terminated += 1
+                            s.out.append(eos if eos >= 0 else 0)
+                            finish(s, "guard")
                         else:
-                            s.state = _DECODE
+                            key = jax.random.fold_in(root, 2 * tick)
+                            oc = step_done(s, self._sample_host(row, key))
+                            if oc:
+                                finish(s, oc)
+                            else:
+                                s.state = _DECODE
 
             # ---- decode tick: ONE fixed-shape call for the whole batch
             dec = [i for i, s in enumerate(slots) if s.state == _DECODE]
             if dec:
-                tokens = np.zeros((B, 1), np.int32)
-                positions = np.zeros((B,), np.int32)
-                pt = np.zeros((B, maxp), np.int32)   # scratch page default
-                for i in dec:
-                    s = slots[i]
-                    tokens[i, 0] = s.last_tok
-                    positions[i] = s.cache_len
-                    pt[i] = self._page_row(s, maxp)
-                key = jax.random.fold_in(root, 2 * tick + 1)
-                tok, bad, pool = self._tick(
-                    self.params, pool, jnp.asarray(tokens),
-                    jnp.asarray(positions), jnp.asarray(pt), key)
-                decode_ticks += 1
-                tok, bad = np.asarray(tok), np.asarray(bad)
-                for i in dec:
-                    s = slots[i]
-                    s.cache_len += 1
-                    if guard and bad[i]:
-                        self.nonfinite_terminated += 1
-                        s.out.append(eos if eos >= 0 else 0)
-                        finish(s, "guard")
-                    else:
-                        oc = step_done(s, int(tok[i]))
-                        if oc:
-                            finish(s, oc)
+                with obs.span("serve.decode", rec):
+                    tokens = np.zeros((B, 1), np.int32)
+                    positions = np.zeros((B,), np.int32)
+                    pt = np.zeros((B, maxp), np.int32)  # scratch page default
+                    for i in dec:
+                        s = slots[i]
+                        tokens[i, 0] = s.last_tok
+                        positions[i] = s.cache_len
+                        pt[i] = self._page_row(s, maxp)
+                    key = jax.random.fold_in(root, 2 * tick + 1)
+                    tok, bad, pool = self._tick(
+                        self.params, pool, jnp.asarray(tokens),
+                        jnp.asarray(positions), jnp.asarray(pt), key)
+                    decode_ticks += 1
+                    decode_slot_ticks += len(dec)
+                    with obs.span("serve.fetch", rec):
+                        tok, bad = np.asarray(tok), np.asarray(bad)
+                    for i in dec:
+                        s = slots[i]
+                        s.cache_len += 1
+                        if guard and bad[i]:
+                            self.nonfinite_terminated += 1
+                            s.out.append(eos if eos >= 0 else 0)
+                            finish(s, "guard")
+                        else:
+                            oc = step_done(s, int(tok[i]))
+                            if oc:
+                                finish(s, oc)
             elif not pf_slots and queue:
                 # idle: jump the clock to the next arrival
                 tick = max(tick, queue[0].arrival - 1)
             if rec is not None:
-                # occupancy gauges every tick: host dict writes off
-                # accounting the scheduler keeps anyway
-                rec.gauge("serve.pages_in_use", pool_acct.in_use)
-                rec.gauge("serve.pages_free", pool_acct.free_pages)
-                states = [s.state for s in slots]
-                rec.gauge("serve.slots_decode", states.count(_DECODE))
-                rec.gauge("serve.slots_prefill", states.count(_PREFILL))
-                rec.gauge("serve.slots_free", states.count(_FREE))
                 rec.count("serve.ticks")
             tick += 1
+            t_iter = time.perf_counter()
 
         self.stats = {
             "ticks": tick, "decode_ticks": decode_ticks,
             "prefill_chunks": prefill_chunks,
+            "decode_slot_ticks": decode_slot_ticks,
             "peak_pages": pool_acct.peak_in_use,
             "num_pages": num_pages, "page_size": ps,
             "wall_s": time.perf_counter() - t_serve0,

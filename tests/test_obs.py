@@ -18,9 +18,15 @@ Layers under test, bottom-up:
     contract holds with the recorder attached (decode_traces ==
     prefill_traces == 1);
   * no-retrace regression — the jaxpr of the fused train step is
-    IDENTICAL with and without a recorder attached to the loop.
+    IDENTICAL with and without a recorder attached to the loop;
+  * obs.span — the scheduler's and the engine's spans in a CPU profiler
+    trace: every name, nested as documented, on the Recorder's clock, with
+    no retrace and no change to the fused population step's jaxpr; the
+    sweep's per-call trace counts.
 """
+import glob
 import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +36,7 @@ import pytest
 from repro import artifacts
 from repro.obs import (Guardian, Histogram, NOT_SAMPLED, Recorder,
                        RequestSpan, SweepRound, TrainStep, percentile,
-                       read_events)
+                       read_events, span)
 
 # shared e2e fixtures: the guardian's poisoned-stream regression setup
 from test_guardian import (PoisonPipeline, _junction, _make_regression_step,
@@ -375,16 +381,26 @@ def test_serve_spans_full_lifecycle_compile_once(tmp_path):
     # histograms: one ttft per request; itl for the later tokens
     assert rec.hists["serve.ttft_s"].count == 5
     assert rec.hists["serve.itl_s"].count == 5 * (NEW - 1)
-    # occupancy gauges refreshed on the final tick: everything drained
-    assert rec.gauges["serve.pages_in_use"] == 0
-    assert rec.gauges["serve.slots_free"] == 2
+    # occupancy counters, hand-counted: each request's first token comes
+    # off its last prefill chunk and the other NEW-1 from decode ticks,
+    # one slot-tick each; two 3-page requests (20 tokens, pages of 8) run
+    # at once on 2 slots
+    assert st["decode_slot_ticks"] == 5 * (NEW - 1)
+    assert st["peak_pages"] == 2 * 3
     assert rec.counters["serve.finish.max_new"] == 5
+    # queue wait: request 2 arrives at tick 4 with both slots busy until
+    # request 0 finishes, so it waits; nobody waits a negative time
+    assert all(v["queue_s"] >= 0 for v in st["latency"].values())
+    assert st["latency"][2]["admitted"] > 4
+    assert st["latency"][2]["queue_s"] > 0
 
     # the report builder renders the run and agrees with the checker
     report = build_report(events)
     assert report["serve"]["requests"] == 5
     assert report["serve"]["outcomes"] == {"max_new": 5}
     assert report["serve"]["ttft_p99_s"] is not None
+    assert (report["serve"]["ttft_arrival_p99_s"]
+            >= report["serve"]["ttft_p99_s"])
 
 
 def test_serve_guard_span_outcome(tmp_path):
@@ -441,3 +457,193 @@ def test_fused_train_step_jaxpr_unchanged_by_recorder(tmp_path):
 
     jaxpr_after = str(jax.make_jaxpr(train_step)(*args))
     assert jaxpr_after == jaxpr_before
+
+
+# ------------------------------------------------------------ program spans
+def _trace_spans(trace_dir):
+    """(profile start on the wall clock in ns, [(name, start_ns, end_ns)]
+    of every repro.* span) from the trace jax.profiler wrote."""
+    from jax.profiler import ProfileData
+    path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    prof = ProfileData.from_file(path)
+    start = next(v for p in prof.planes if p.name == "Task Environment"
+                 for k, v in p.stats if k == "profile_start_time")
+    spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+             for p in prof.planes if p.name.startswith("/host:")
+             for line in p.lines for ev in line.events
+             if ev.name.startswith("repro.")]
+    return start, spans
+
+
+def _inside(inner, outers):
+    return any(s <= inner[1] and inner[2] <= e for _, s, e in outers)
+
+
+def _by_name(spans):
+    out = {}
+    for sp in spans:
+        out.setdefault(sp[0], []).append(sp)
+    return out
+
+
+def test_span_times_into_recorder_and_adds_nothing_else():
+    """A span with a recorder observes its duration into span.<name>_s;
+    without one it records nothing; neither touches jax state."""
+    rec = Recorder()
+    with span("unit.work", rec):
+        pass
+    with span("unit.work"):
+        pass
+    h = rec.hists["span.unit.work_s"]
+    assert h.count == 1 and h.total >= 0
+    assert rec.counters == {} and rec.gauges == {} and rec.n_events == 0
+
+
+def _sweep_specs(n_in=128, n_out=64):
+    return [CandidateSpec(lr=lr, momentum=0.0, density=d,
+                          layers=(n_in, n_out), block=32, init_seed=i)
+            for i, (d, lr) in enumerate((d, lr) for d in (0.5, 0.25)
+                                        for lr in (0.05, 0.1))]
+
+
+def _sweep_data(n_in=128, n_out=64):
+    rng = np.random.default_rng(0)
+    x = rng.standard_normal((128, n_in)).astype(np.float32)
+    t = np.eye(n_out, dtype=np.float32)[rng.integers(0, n_out, 128)]
+    return x, t, x[:32], t[:32]
+
+
+def test_sweep_spans_in_profiler_trace(tmp_path):
+    """Every sweep span, nested as documented, with the counts the
+    schedule implies (2 cohorts, 2 rounds of 3 steps, nobody pruned),
+    and one trace of each cohort's step and eval per call."""
+    cfg = SweepConfig(rounds=2, steps_per_round=3, batch_size=32,
+                      eval_samples=32, keep_fraction=1.0, engine="jnp",
+                      fused=False)
+    rec = Recorder()
+    with jax.profiler.trace(str(tmp_path)):
+        res = run_sweep(_sweep_specs(), *_sweep_data(), cfg, recorder=rec)
+    _, spans = _trace_spans(str(tmp_path))
+    by = _by_name(spans)
+    cohorts, steps = 2, cfg.rounds * cfg.steps_per_round
+    assert {k: len(v) for k, v in by.items()} == {
+        "repro.sweep.setup": 1,
+        "repro.sweep.first_step": cohorts,
+        "repro.sweep.step": cohorts * (steps - 1),
+        "repro.sweep.fetch": cohorts * steps,
+        "repro.sweep.eval": cohorts * cfg.rounds,
+        "repro.sweep.prune": cfg.rounds}
+    steps_ = by["repro.sweep.first_step"] + by["repro.sweep.step"]
+    assert all(_inside(f, steps_) for f in by["repro.sweep.fetch"])
+    setup_end = by["repro.sweep.setup"][0][2]
+    assert all(s >= setup_end for n, s, _ in spans
+               if n != "repro.sweep.setup")
+    assert max(e for _, _, e in by["repro.sweep.first_step"]) <= min(
+        s for _, s, _ in by["repro.sweep.step"])
+    # top-level spans never overlap one another
+    top = sorted((sp for sp in spans if sp[0] != "repro.sweep.fetch"),
+                 key=lambda sp: sp[1])
+    assert all(a[2] <= b[1] for a, b in zip(top, top[1:]))
+    assert res.ledger.meta["step_traces"] == cohorts
+    assert res.ledger.meta["eval_traces"] == cohorts
+    assert rec.hists["span.sweep.fetch_s"].count == cohorts * steps
+
+
+def test_sweep_trace_counts_are_per_call():
+    """run_sweep builds its cohorts' step and eval anew on every call:
+    each call's ledger counts its own traces, one per cohort and kind."""
+    cfg = SweepConfig(rounds=1, steps_per_round=2, batch_size=32,
+                      eval_samples=32, engine="jnp", fused=False)
+    metas = [run_sweep(_sweep_specs(), *_sweep_data(), cfg).ledger.meta
+             for _ in range(2)]
+    assert [(m["step_traces"], m["eval_traces"]) for m in metas] == \
+        [(2, 2), (2, 2)]
+
+
+def test_population_step_jaxpr_unchanged_by_spans_and_trace_counter():
+    """The fused population step's jaxpr is identical with and without
+    a trace counter and inside or outside an open span: spans and
+    counters add no traced op."""
+    from repro.search import hyp_table, init_population, population as pop
+    specs = _sweep_specs(n_in=64, n_out=64)[:2]
+    params = init_population(jax.random.PRNGKey(0), specs)
+    x, t, _, _ = _sweep_data(n_in=64, n_out=64)
+    args = (params, pop.init_slots(params, specs), hyp_table(specs),
+            jnp.ones((2,), jnp.float32), jnp.asarray(x[:16]),
+            jnp.asarray(t[:16]))
+
+    def jaxpr(traces):
+        return str(jax.make_jaxpr(pop.make_population_step(
+            engine="pallas", fused=True, jit=False, with_health=True,
+            traces=traces))(*args))
+
+    plain = jaxpr(None)
+    traces = {}
+    with span("sweep.step", Recorder()):
+        counted = jaxpr(traces)
+    assert counted == plain
+    assert "junction_update_dw" in plain
+    assert traces == {"step": 1}
+
+
+def test_serve_spans_in_profiler_trace_one_clock(tmp_path):
+    """Every serve span, nested as documented and counted as the stats
+    say; each request's span event (emitted at finish, inside a decode
+    tick) has a ts inside a repro.serve.decode span: one clock.  The
+    engine still compiles each step once with spans and a recorder."""
+    cfg = _serve_cfg()
+    params = M.init(cfg, jax.random.PRNGKey(0))
+    prompts = np.random.default_rng(0).integers(
+        1, cfg.vocab, size=(5, 12)).astype(np.int32)
+    NEW = 8
+    p = str(tmp_path / "serve.jsonl")
+    rec = Recorder(p)
+    ce = ContinuousEngine(
+        cfg, params,
+        ServeConfig(max_new_tokens=NEW, eos_token=-1, slots=2, page_size=8,
+                    prefill_chunk=8, max_seq=32),
+        recorder=rec)
+    with jax.profiler.trace(str(tmp_path / "trace")):
+        ce.serve([Request(rid=i, prompt=prompts[i], max_new_tokens=NEW,
+                          arrival=2 * i) for i in range(5)])
+    rec.close()
+    st = ce.stats
+    assert st["decode_traces"] == 1 and st["prefill_traces"] == 1
+
+    start, spans = _trace_spans(str(tmp_path / "trace"))
+    by = _by_name(spans)
+    assert {k: len(v) for k, v in by.items()} == {
+        "repro.serve.setup": 1,
+        "repro.serve.admit": st["ticks"],
+        "repro.serve.prefill": st["prefill_chunks"],
+        "repro.serve.decode": st["decode_ticks"],
+        "repro.serve.fetch": st["decode_ticks"] + 5}
+    work = by["repro.serve.prefill"] + by["repro.serve.decode"]
+    assert all(_inside(f, work) for f in by["repro.serve.fetch"])
+    top = sorted((sp for sp in spans if sp[0] != "repro.serve.fetch"),
+                 key=lambda sp: sp[1])
+    assert all(a[2] <= b[1] for a, b in zip(top, top[1:]))
+
+    _, events = read_events(p)
+    finished = [e for e in events if e["kind"] == "serve.span"]
+    assert len(finished) == 5
+    slack_ns = 1e3          # a float ts of ~1.8e9 s resolves ~0.24 us
+    for e in finished:
+        ts = e["ts"] * 1e9 - start
+        assert any(s - slack_ns <= ts <= end + slack_ns
+                   for _, s, end in by["repro.serve.decode"]), e["rid"]
+        assert e["queue_s"] >= 0
+    # the recorder timed every span too
+    assert rec.hists["span.serve.admit_s"].count == st["ticks"]
+    report = build_report(events)
+    assert report["spans"]["serve.decode"]["count"] == st["decode_ticks"]
+
+
+def test_check_span_rejects_negative_queue_wait():
+    ev = {"kind": "serve.span", "rid": 3, "outcome": "max_new",
+          "enqueue_tick": 0, "admit_tick": 1, "first_token_tick": 2,
+          "finish_tick": 4, "prefill_chunks": 1, "n_tokens": 3,
+          "ttft_s": 0.01, "wall_s": 0.02, "queue_s": 0.005}
+    assert check_span(ev) is None
+    assert "queue" in check_span(dict(ev, queue_s=-0.001))
