@@ -237,9 +237,10 @@ def flash_decode(q, k_pool, v_pool, page_table, seq_lens, *,
     """Single-query decode attention over a block-paged KV pool.
 
     q [B, Hkv, rep, D] — one query token per slot, grouped by kv head;
-    k_pool / v_pool [P, ps, Hkv*D] — the page pool (one layer's slice),
-    heads x head_dim merged on the lane axis so a page row is a multiple
-    of 128 lanes at any head_dim (stablelm's 80 included);
+    k_pool / v_pool [P, ps, Hkv*D] — the page pool (the model's paged
+    paths pass every layer's pages viewed flat, page ids offset to one
+    layer's), heads x head_dim merged on the lane axis so a page row is a
+    multiple of 128 lanes at any head_dim (stablelm's 80 included);
     page_table [B, maxp] int32 — pool page ids per slot, in token order
     (entry t covers positions [t*ps, (t+1)*ps));
     seq_lens [B] int32 — valid tokens per slot (0 for free slots).

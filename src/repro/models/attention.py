@@ -215,15 +215,16 @@ def paged_kv_update(cache: dict, k_new, v_new, positions, page_table,
                     keys=("k", "v")):
     """Scatter new KV rows into the block-paged pool.
 
-    cache: {"k": [P, ps, Hkv*hd], "v": ...} (one layer's pool slice,
-    heads x head_dim merged on the lane axis — models/model.
-    make_paged_cache); k_new/v_new [B, S, Hkv, hd] — tokens to write;
-    positions [B, S] —
-    their absolute positions; page_table [B, maxp] — pool page ids in
-    token order.  Token at position t lands in page page_table[b, t//ps]
-    at offset t % ps, so a slot refill is a page-table swap, never a
-    cache copy.  Free/prefilling slots are pointed at the reserved
-    scratch page by the engine, so their writes are harmless."""
+    cache: {"k": [P, ps, Hkv*hd], "v": ...} (the page pool, heads x
+    head_dim merged on the lane axis — models/model.make_paged_cache; the
+    paged model paths pass the stacked pool viewed flat, with page ids
+    offset to the layer's pages); k_new/v_new [B, S, Hkv, hd] — tokens
+    to write; positions [B, S] — their absolute positions; page_table
+    [B, maxp] — pool page ids in token order.  Token at position t lands
+    in page page_table[b, t//ps] at offset t % ps, so a slot refill is a
+    page-table swap, never a cache copy.  Free/prefilling slots are
+    pointed at the reserved scratch page by the engine, so their writes
+    are harmless."""
     ps = cache[keys[0]].shape[1]
     pid = jnp.take_along_axis(page_table, positions // ps, axis=1)   # [B, S]
     off = positions % ps

@@ -194,8 +194,11 @@ def _scan_layers(fn, x, layer_params, cfg, with_cache=None):
 
 
 def _scan_layers_inplace_cache(fn, x, layer_params, cfg, cache):
-    """Decode-path layer scan: the cache rides in the scan *carry* and is
-    updated in place per layer (dynamic-update-slice on the stacked dim).
+    """Static decode-path layer scan: the cache rides in the scan *carry*;
+    each layer's cache is sliced out of the stacked dim and written back
+    whole (dynamic-update-slice), so a copy of one layer's cache is made
+    per layer.  The paged paths use their own scan (_scan_layers_paged),
+    which writes only the new rows.
 
     Passing the cache as scan xs/ys makes XLA allocate a second, stacked
     output cache — for decode_32k that doubles the resident KV bytes
@@ -217,6 +220,28 @@ def _scan_layers_inplace_cache(fn, x, layer_params, cfg, cache):
     (x, cache), _ = jax.lax.scan(body, (x, cache),
                                  (layer_params, jnp.arange(L)))
     return x, cache
+
+
+def _scan_layers_paged(fn, x, layer_params, pool, page_table):
+    """Paged-path layer scan: the pool [L, P, ps, W] rides the carry viewed
+    flat as [L*P, ps, W] (a bitcast of the donated buffer), and layer i
+    addresses its own pages through ``page_table + i*P``.  So
+    fn(x, lp, flat_pool, page_table_i) -> (x, flat_pool, aux) scatters its
+    new rows straight into the carry and reads only the pages the table
+    names: no layer's pool is sliced out or written back.  Each layer keeps
+    its own scratch page (i*P), so free slots still write harmlessly."""
+    L, P = jax.tree.leaves(pool)[0].shape[:2]
+    flat = jax.tree.map(lambda t: t.reshape(L * P, *t.shape[2:]), pool)
+
+    def body(carry, inp):
+        x, flat = carry
+        lp, i = inp
+        x, flat, _ = fn(x, lp, flat, page_table + i * P)
+        return (x, flat), None
+
+    (x, flat), _ = jax.lax.scan(
+        body, (x, flat), (layer_params, jnp.arange(L, dtype=jnp.int32)))
+    return x, jax.tree.map(lambda t: t.reshape(L, P, *t.shape[1:]), flat)
 
 
 def _embed_in(cfg: ArchConfig, params, batch, pos0: int = 0):
@@ -448,7 +473,8 @@ def make_paged_cache(cfg: ArchConfig, num_pages: int, page_size: int):
 def _attn_block_paged(lp, x, cfg: ArchConfig, cache_i, positions, page_table,
                       *, decode: bool):
     """Paged twin of _attn_mlp_block: attention through the paged pool
-    slice, FFN/MoE unchanged.  Returns (x, new_cache_i, aux)."""
+    (one layer's pages, addressed by page_table), FFN/MoE unchanged.
+    Returns (x, new_cache_i, aux)."""
     h = norm_apply(lp["norm1"], x, cfg.norm, cfg.norm_eps)
     if decode:
         a, new_cache = attn.gqa_decode_paged(lp["attn"], h, cfg, cache_i,
@@ -478,11 +504,11 @@ def paged_decode_step(cfg: ArchConfig, params: Params, pool, token, positions,
         raise ValueError(f"paged decode unsupported: {why}")
     x = embed_tokens(params["embed"], token, cfg)
 
-    def fn(x, lp, ci):
-        return _attn_block_paged(lp, x, cfg, ci, positions, page_table,
+    def fn(x, lp, flat, pt):
+        return _attn_block_paged(lp, x, cfg, flat, positions, pt,
                                  decode=True)
 
-    x, pool = _scan_layers_inplace_cache(fn, x, params["layers"], cfg, pool)
+    x, pool = _scan_layers_paged(fn, x, params["layers"], pool, page_table)
     x = norm_apply(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     logits = unembed(params["embed"], x, cfg)
     return logits, pool
@@ -506,10 +532,11 @@ def paged_prefill_chunk(cfg: ArchConfig, params: Params, pool, tokens, base,
     positions = base + jnp.arange(C)
     pt = page_table_row[None, :]
 
-    def fn(x, lp, ci):
-        return _attn_block_paged(lp, x, cfg, ci, positions, pt, decode=False)
+    def fn(x, lp, flat, pt_i):
+        return _attn_block_paged(lp, x, cfg, flat, positions, pt_i,
+                                 decode=False)
 
-    x, pool = _scan_layers_inplace_cache(fn, x, params["layers"], cfg, pool)
+    x, pool = _scan_layers_paged(fn, x, params["layers"], pool, pt)
     x = norm_apply(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     last = jax.lax.dynamic_slice_in_dim(x, chunk_len - 1, 1, axis=1)
     logits = unembed(params["embed"], last, cfg)

@@ -4,6 +4,7 @@ vs reference, continuous-vs-static greedy parity, the compile-once
 traces with EOS early-free, and the stale nonfinite_terminated
 regression."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -178,6 +179,67 @@ def test_eos_frees_slot_early(setup):
                                ServeConfig(eos_token=-1, **scfg))
     ce_full.serve(mk())
     assert ticks_eos < ce_full.stats["decode_ticks"]
+
+
+# ------------------------------------------- paged layer scan vs per-layer
+def _per_layer_scan(fn, x, layer_params, pool, page_table):
+    """Reference for models/model._scan_layers_paged: each layer's pool is
+    sliced out of the stacked pool, run with the un-offset page table, and
+    written back whole."""
+    L = jax.tree.leaves(pool)[0].shape[0]
+
+    def body(carry, inp):
+        x, pool = carry
+        lp, i = inp
+        ci = jax.tree.map(
+            lambda t: jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False),
+            pool)
+        x, nc, _ = fn(x, lp, ci, page_table)
+        pool = jax.tree.map(
+            lambda t, u: jax.lax.dynamic_update_index_in_dim(t, u, i, 0),
+            pool, nc)
+        return (x, pool), None
+
+    (x, pool), _ = jax.lax.scan(body, (x, pool), (layer_params, jnp.arange(L)))
+    return x, pool
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+@pytest.mark.parametrize("engine", ["jnp", "pallas"])
+def test_paged_scan_matches_per_layer_reference(monkeypatch, engine, step):
+    """The paged tick and prefill chunk address each layer's pages in the
+    flat stacked pool (page ids offset by i*P): logits and the whole pool
+    come out exactly as when each layer's pool is sliced out and written
+    back.  Slot 1 points at the scratch page; slot 2 writes the first row
+    of a page, and the chunk crosses a page boundary."""
+    cfg = _cfg(engine=engine, n_layers=3)
+    params = M.init(cfg, jax.random.PRNGKey(1))
+    B, ps, maxp = 3, 8, 3
+    P = 1 + B * maxp
+    pool = M.make_paged_cache(cfg, P, ps)
+    ks = jax.random.split(jax.random.PRNGKey(2), 3)
+    pool = {k: jax.random.normal(key, v.shape, v.dtype)
+            for (k, v), key in zip(sorted(pool.items()), ks[:2])}
+    pt = jnp.asarray([[1, 2, 3], [0, 0, 0], [7, 5, 9]], jnp.int32)
+    if step == "decode":
+        args = (jnp.asarray([[3], [4], [5]], jnp.int32),
+                jnp.asarray([13, 0, 8], jnp.int32), pt)
+        run = M.paged_decode_step
+    else:
+        tokens = jax.random.randint(ks[2], (1, 8), 1, cfg.vocab, jnp.int32)
+        args = (tokens, jnp.int32(5), pt[2], jnp.int32(6))
+        run = M.paged_prefill_chunk
+    call = lambda: jax.jit(functools.partial(run, cfg))(params, pool, *args)
+    got_logits, got_pool = call()
+    monkeypatch.setattr(M, "_scan_layers_paged", _per_layer_scan)
+    want_logits, want_pool = call()
+    np.testing.assert_array_equal(np.asarray(got_logits),
+                                  np.asarray(want_logits))
+    for k in ("k", "v"):
+        np.testing.assert_array_equal(np.asarray(got_pool[k]),
+                                      np.asarray(want_pool[k]))
+        assert not np.array_equal(np.asarray(got_pool[k]),
+                                  np.asarray(pool[k]))
 
 
 # ------------------------------------------------------ page-pool accounting

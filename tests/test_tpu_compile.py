@@ -1,4 +1,5 @@
-"""The main-path Pallas kernels compile for a TPU v5e chip.
+"""The main-path Pallas kernels, and the paged serve tick and prefill
+chunk, compile for a TPU v5e chip.
 
 No chip is needed: the TPU compiler compiles for a described (not
 attached) ``v5e:2x2`` topology, and refuses what the chip would refuse —
@@ -11,16 +12,22 @@ The topology is described inside a module fixture, never at import: one
 process at a time may load the TPU library, and only the worker that runs
 this file should.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import registry
 from repro.core.sparsity import make_block_pattern
 from repro.kernels import block_sparse_matmul as bsm
 from repro.kernels import flash_attention as fa
+from repro.kernels import ops
+from repro.models import model as M
+from repro.serve.engine import ContinuousEngine, ServeConfig
 
 D_MODEL, D_FF, BLOCK, ROWS = 2560, 6912, 128, 1024
 PAT = make_block_pattern(D_MODEL, D_FF, 0.25, BLOCK)
@@ -54,13 +61,15 @@ def chip(topo):
         shape, dtype, sharding=one)
 
 
-def _compiled(fn, *args, kernel: str) -> str:
+def _compiled(fn, *args, kernel: str | None) -> str:
     # the program's own matmul precision: the suite's "highest" default
     # (conftest.py) would ask the MXU for an fp32 contraction of bf16 tiles
+    fn = fn if hasattr(fn, "lower") else jax.jit(fn)
     with jax.default_matmul_precision("default"):
-        txt = jax.jit(fn).lower(*args).compile().as_text()
-    assert "tpu_custom_call" in txt
-    assert kernel in txt
+        txt = fn.lower(*args).compile().as_text()
+    if kernel is not None:
+        assert "tpu_custom_call" in txt
+        assert kernel in txt
     return txt
 
 
@@ -149,3 +158,65 @@ def test_flash_decode_compiles(chip, hkv, rep, hd):
               chip((slots, hkv, rep, hd)), pool, pool,
               chip((slots, maxp), jnp.int32), chip((slots,), jnp.int32),
               kernel="flash_decode")
+
+
+# an HLO instruction: "%name = <result type> <opcode>(" (the type may be a tuple)
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%\S+\s=\s(.*?)\s([a-z][a-z0-9\-]*)\(")
+
+
+def _instrs_by_size(txt: str, sizes: set[int]) -> list[tuple[str, str]]:
+    """(opcode, line) of every instruction, fused ones included, whose
+    result holds an array with one of ``sizes`` elements."""
+    out = []
+    for line in txt.splitlines():
+        m = _HLO_INSTR.match(line)
+        if not m:
+            continue
+        for dims in re.findall(r"[a-z][a-z0-9]*\[([\d,]+)\]", m.group(1)):
+            n = 1
+            for d in dims.split(","):
+                n *= int(d)
+            if n in sizes:
+                out.append((m.group(2), line.strip()[:160]))
+                break
+    return out
+
+
+@pytest.mark.parametrize("step", ["tick", "prefill_chunk"])
+def test_paged_serve_step_writes_pool_in_place(chip, monkeypatch, step):
+    """The engine's decode tick and prefill chunk, pool donated, at
+    stablelm-3b's KV widths (32 heads x 80, pages of 16): each layer's new
+    rows are scattered into the stacked pool, and no copy, dynamic-slice or
+    dynamic-update-slice makes a buffer the size of one layer's pool or of
+    the whole pool (the per-layer slice and write-back the flat-pool layer
+    scan removed)."""
+    monkeypatch.setattr(ops, "_auto_interpret", lambda: False)
+    L, slots, ps, C, max_seq = 3, 8, 16, 64, 256
+    cfg = dataclasses.replace(
+        registry.get("stablelm-3b"), n_layers=L, d_model=256, d_ff=512,
+        vocab=256, raw_vocab=256, max_seq=max_seq, engine="pallas",
+        param_dtype="bfloat16")
+    on_chip = lambda t: jax.tree.map(lambda s: chip(s.shape, s.dtype), t)
+    params = on_chip(jax.eval_shape(
+        lambda: M.init(cfg, jax.random.PRNGKey(0))))
+    eng = ContinuousEngine(cfg, params, ServeConfig(
+        slots=slots, page_size=ps, prefill_chunk=C, max_seq=max_seq))
+    maxp = eng.pages_per_slot
+    P = slots * maxp + 1
+    pool = on_chip(jax.eval_shape(lambda: M.make_paged_cache(cfg, P, ps)))
+    i32 = jnp.int32
+    if step == "tick":
+        txt = _compiled(eng._tick, params, pool, chip((slots, 1), i32),
+                        chip((slots,), i32), chip((slots, maxp), i32),
+                        chip((2,), jnp.uint32), kernel="flash_decode")
+    else:
+        txt = _compiled(eng._prefill_chunk, params, pool, chip((1, C), i32),
+                        chip((), i32), chip((maxp,), i32), chip((), i32),
+                        kernel=None)
+    layer = P * ps * cfg.kv_heads * cfg.head_dim
+    found = _instrs_by_size(txt, {layer, L * layer})
+    assert "scatter" in {op for op, _ in found}
+    copies = [line for op, line in found
+              if op in ("copy", "copy-start", "copy-done", "dynamic-slice",
+                        "dynamic-update-slice")]
+    assert not copies, "\n".join(copies)
