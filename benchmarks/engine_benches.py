@@ -215,7 +215,7 @@ def _time_moe_update(params, x, mode, n=3, optim="sgd"):
     step0 = jnp.zeros((), jnp.int32)
 
     def loss(p):
-        y, aux = moe_mod.moe_apply(p, x, cfg)
+        y, aux, _ = moe_mod.moe_apply(p, x, cfg)
         return jnp.sum(y) + aux
 
     if mode == "pallas":
@@ -245,7 +245,7 @@ def _moe_cfg(engine: str) -> ArchConfig:
     return ArchConfig(
         name="bench-moe", family="moe", n_layers=1, d_model=d, n_heads=8,
         kv_heads=8, head_dim=d // 8, d_ff=4 * d, vocab=256, dtype="float32",
-        moe=MoEConfig(num_experts=E, top_k=K, d_expert=f, group_size=2048),
+        moe=MoEConfig(num_experts=E, top_k=K, d_expert=f),
         sparsity=SparsityConfig(density=density, block=block, where="ffn"),
         engine=engine)
 
@@ -256,7 +256,7 @@ def _time_moe_fwd_bwd(params, x, engine, n=1):
     @jax.jit
     def step(params, x):
         def loss(p, x):
-            y, aux = moe_mod.moe_apply(p, x, cfg)
+            y, aux, _ = moe_mod.moe_apply(p, x, cfg)
             return jnp.sum(y) + aux
         # allow_int: the shared block pattern rides in int32 param leaves
         return jax.value_and_grad(loss, allow_int=True)(params, x)
@@ -300,11 +300,10 @@ def bench(fast=True):
     cfg0 = _moe_cfg("jnp")
     moe_params = moe_mod.moe_init(jax.random.PRNGKey(0), cfg0)
     x = jax.random.normal(jax.random.PRNGKey(1), (1, T, d), jnp.float32)
-    _, G, C = moe_mod.moe_dispatch_dims(cfg0.moe, T)
-    M_e = G * C                                    # capacity rows per expert
+    ebm, M_e = moe_mod.expert_rows(T)              # buffer rows per expert
     kb = moe_params["idx_in"].shape[1]
-    ebm, ebn = bsm.choose_tiles(M_e, f // block, kb, block, d // block, 4,
-                                E=E, n_weight_operands=2)
+    _, ebn = bsm.choose_tiles(M_e, f // block, kb, block, d // block, 4,
+                              E=E, n_weight_operands=2)
     n = 3
     for engine in ("jnp", "pallas"):
         dt = _time_moe_fwd_bwd(moe_params, x, engine, n=n)
@@ -313,7 +312,7 @@ def bench(fast=True):
             "name": f"engine.moe.{engine}",
             "us_per_call": dt * 1e6,
             "derived": f"T={T} E={E} top{K} {d}->{f} d={density} bs={block} "
-                       f"C={C} tiles={ebm}x{ebn} mode={mode}",
+                       f"rows={M_e} tiles={ebm}x{ebn} mode={mode}",
         })
 
     # fused BP+UP vs two-pass train-update cycle (ISSUE 4 tentpole):
@@ -429,7 +428,7 @@ def _quant_rows(fast, on_tpu):
 
         @jax.jit
         def step(p, x, cfg=cfg):
-            y, aux = moe_mod.moe_apply(p, x, cfg)
+            y, _, _ = moe_mod.moe_apply(p, x, cfg)
             return y
 
         dt = _time_infer(step, (moe_q, xm))

@@ -16,15 +16,40 @@ from repro.core.sparsity import SparsityConfig
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int
+    """The expert layer (models/moe.py).  The router always scores all
+    ``num_experts`` and keeps ``top_k``; this device computes the experts
+    ``first_held .. first_held + held - 1`` (``held`` 0: all of them), the
+    share one chip of an expert-parallel deployment holds, and drops no
+    token."""
+    num_experts: int              # router width (experts in the layer)
     top_k: int
     d_expert: int                 # per-expert FFN hidden width
     num_shared: int = 0           # shared (always-on) experts
     d_shared: int = 0             # hidden width of the shared expert block
-    capacity_factor: float = 1.25
-    group_size: int = 2048        # GShard dispatch group
     aux_loss_weight: float = 1e-2
+    # "batch": Switch/GShard E * sum_e f_e * p_e over all tokens;
+    # "sequence": DeepSeek-V2's seq_aux, the same per sequence, averaged
+    aux_loss: str = "batch"
+    norm_topk_prob: bool = True   # renormalise the kept top-k weights
+    routed_scale: float = 1.0     # routed_scaling_factor
+    held: int = 0                 # experts computed here (0: all)
+    first_held: int = 0           # index of the first held expert
     first_dense_layers: int = 0   # deepseek-v2: layer 0 is a dense FFN
+
+    @property
+    def held_(self) -> int:
+        return self.held or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN rotary scaling (DeepSeek-V2's ``rope_scaling`` of type yarn)."""
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,6 +78,7 @@ class ArchConfig:
     qkv_bias: bool = False
     partial_rotary: float = 1.0   # fraction of head_dim rotated (stablelm 0.25)
     rope_theta: float = 1e6
+    rope_scaling: Optional[RopeScaling] = None   # YaRN (MLA only)
     mla: Optional[MLAConfig] = None
     # ssm
     ssm_kind: str = ""            # mamba1 | mamba2
@@ -161,7 +187,9 @@ class ArchConfig:
         if self.moe is not None:
             kw["moe"] = dataclasses.replace(
                 self.moe, num_experts=8, top_k=2, d_expert=64,
-                d_shared=64 if self.moe.num_shared else 0, group_size=64)
+                d_shared=64 if self.moe.num_shared else 0,
+                held=min(self.moe.held, 8),
+                first_held=min(self.moe.first_held, 8 - min(self.moe.held, 8)))
         if self.mla is not None:
             kw["mla"] = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=32,
                                   qk_rope_head_dim=16, v_head_dim=32)
@@ -190,7 +218,7 @@ class ArchConfig:
         gated = 3 if self.act == "silu" else 2
         if self.family == "moe":
             mo = self.moe
-            ffn = mo.num_experts * gated * d * mo.d_expert
+            ffn = mo.held_ * gated * d * mo.d_expert
             if mo.num_shared:
                 ffn += gated * d * mo.d_shared
             per_layer = per_attn + ffn
@@ -220,7 +248,7 @@ class ArchConfig:
             total += self.enc_layers * (4 * d * d + 2 * d * f)
             total += self.n_layers * 4 * d * d  # cross-attn per decoder layer
         if self.family == "moe" and self.moe.first_dense_layers:
-            total += self.moe.first_dense_layers * (gated * d * f - self.moe.num_experts * gated * d * self.moe.d_expert)
+            total += self.moe.first_dense_layers * (gated * d * f - self.moe.held_ * gated * d * self.moe.d_expert)
         return int(total)
 
     def active_param_count(self) -> int:
@@ -231,7 +259,7 @@ class ArchConfig:
         d, L = self.d_model, self.n_layers
         gated = 3
         full = self.param_count()
-        all_experts = L * mo.num_experts * gated * d * mo.d_expert
+        all_experts = L * mo.held_ * gated * d * mo.d_expert
         active = L * mo.top_k * gated * d * mo.d_expert
         return int(full - all_experts + active)
 

@@ -5,7 +5,7 @@ notes (e.g. deepseek-v2-lite expert count) are in DESIGN.md Sec. 4.
 """
 from __future__ import annotations
 
-from repro.configs.base import ArchConfig, MLAConfig, MoEConfig
+from repro.configs.base import ArchConfig, MLAConfig, MoEConfig, RopeScaling
 
 # --------------------------------------------------------------------------
 # [ssm] falcon-mamba-7b — 64L d4096, attn-free, vocab 65024, state 16 (mamba1)
@@ -70,8 +70,11 @@ LLAVA_NEXT_MISTRAL_7B = ArchConfig(
 
 # [moe] deepseek-v2-lite-16b — 27L d2048 16H MLA(kv_lora 512), 64 routed +
 # 2 shared experts top-6, expert ff 1408, first layer dense (ff 10944)
-# [arXiv:2405.04434; hf]  (assignment aside says "160 routed" — that is the
-# full V2; Lite is 64. See DESIGN.md.)
+# [hf:deepseek-ai/DeepSeek-V2-Lite config.json]: softmax router, greedy
+# top-6 without renormalisation (norm_topk_prob false), routed scale 1,
+# YaRN rope scaling (factor 40 over 4096 positions, mscale 0.707 on both),
+# RMSNorm eps 1e-6, sequence-wise balance loss (seq_aux; its alpha is not
+# in the config: 0.001, DeepSeek-V2's paper).
 DEEPSEEK_V2_LITE_16B = ArchConfig(
     name="deepseek-v2-lite-16b", family="moe", n_layers=27, d_model=2048,
     n_heads=16, kv_heads=16, head_dim=128, d_ff=10944, vocab=102400,
@@ -79,8 +82,13 @@ DEEPSEEK_V2_LITE_16B = ArchConfig(
     mla=MLAConfig(kv_lora_rank=512, qk_nope_head_dim=128,
                   qk_rope_head_dim=64, v_head_dim=128),
     moe=MoEConfig(num_experts=64, top_k=6, d_expert=1408, num_shared=2,
-                  d_shared=2816, first_dense_layers=1),
-    rope_theta=1e4,
+                  d_shared=2816, first_dense_layers=1,
+                  aux_loss_weight=1e-3, aux_loss="sequence",
+                  norm_topk_prob=False, routed_scale=1.0),
+    rope_theta=1e4, norm_eps=1e-6,
+    rope_scaling=RopeScaling(factor=40.0, original_max_position=4096,
+                             beta_fast=32.0, beta_slow=1.0, mscale=0.707,
+                             mscale_all_dim=0.707),
 )
 
 # [moe] qwen3-moe-30b-a3b — 48L d2048 32H kv4, 128 experts top-8, expert ff 768

@@ -212,6 +212,7 @@ Misses fall back to a VMEM-budget heuristic (``choose_tiles``).
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 
 import jax
@@ -378,10 +379,76 @@ def fwd_grid(M: int, nob: int, kb: int, bs: int, nib: int,
     return (_round_up(M, bm) // bm, nob // bn)
 
 
+# ------------------------------------------------------ live row counts
+# An expert layer that dispatches by index (models/moe.py) hands the
+# E-batched kernels a buffer [E, M, n] whose unit e holds ``counts[e]``
+# live rows at its top and nothing below.  With ``counts`` (int32 [E],
+# the last scalar-prefetch operand) a row tile at or past its unit's
+# count is neither fetched nor computed: the body is skipped under
+# ``pl.when`` and every index map sends the step to the unit's last
+# live block (so no DMA moves in or out).  Rows past a count are left
+# unwritten in the outputs; the caller never reads them.  The update
+# kernels skip the tile's gradient reduction only: the flush epilogue
+# runs for every (unit, output block), so a unit with no rows still
+# takes its optimizer step from its moments.  Counted calls carry the
+# name prefix ``expert_`` (``expert_junction_gated_fwd`` ...), so a
+# device trace tells the routed experts from the dense junctions.
+EXPERT_PREFIX = "expert_"
+
+
+def _kernel_name(name: str, counts) -> str:
+    return name if counts is None else EXPERT_PREFIX + name
+
+
+def _live(cnt_ref, e, m, bm):
+    """Whether row tile m of unit e holds a live row."""
+    return m * bm < cnt_ref[e]
+
+
+def _rows_outer(index_map, bm: int, n_inner: int):
+    """A counted grid (E, M/bm, n_inner)'s index map: steps past unit
+    e's live row tiles go to its last live step's block."""
+    def counted(e, m, j, *refs):
+        *pref, cnt = refs
+        live = _live(cnt, e, m, bm)
+        last = jnp.maximum((cnt[e] + bm - 1) // bm - 1, 0)
+        return index_map(e, jnp.where(live, m, last),
+                         jnp.where(live, j, n_inner - 1), *pref)
+    return counted
+
+
+def _rows_inner(index_map, bm: int):
+    """A counted grid (E, nob, M/bm)'s index map (M innermost): row
+    tiles past unit e's count re-point at its last live tile."""
+    def counted(e, o, m, *refs):
+        *pref, cnt = refs
+        last = jnp.maximum((cnt[e] + bm - 1) // bm - 1, 0)
+        return index_map(e, o, jnp.minimum(m, last), *pref)
+    return counted
+
+
+def _counted_specs(specs, counts, wrap):
+    if counts is None:
+        return specs
+    return [dataclasses.replace(s, index_map=wrap(s.index_map))
+            if s.index_map is not None else s for s in specs]
+
+
+def _run_live(compute, cnt_ref, bm: int, row_axis: int):
+    """Run a kernel body's work; with a counts ref, only for live row
+    tiles.  The program ids are read here, outside the conditional
+    (interpret mode lowers none inside one)."""
+    if cnt_ref is None:
+        compute()
+    else:
+        live = _live(cnt_ref, pl.program_id(0), pl.program_id(row_axis), bm)
+        pl.when(live)(compute)
+
+
 # ------------------------------------------------------------------ forward
 def fwd(x, w, idx, bias, *, act: str = "none", bm: int | None = None,
         bn: int | None = None, save_pre: bool = False,
-        interpret: bool = False):
+        interpret: bool = False, counts=None):
     """x [E, M, nib*bs], w [E, nob, kb, bs, bs], shared idx [nob, kb],
     bias [E, nob*bs] -> act(x_e @ W_e + b_e) [E, M, nob*bs] per junction
     unit (+ pre-activation if save_pre).
@@ -389,7 +456,8 @@ def fwd(x, w, idx, bias, *, act: str = "none", bm: int | None = None,
     Grid (E, M/bm, nob/bn): the expert dimension is the outermost grid
     axis; the pattern rides once in scalar prefetch and is reused by every
     unit.  One step computes bn output tiles — the kb fan-in slots reduce
-    in-body into fp32 VMEM scratch, epilogue fused, single output write."""
+    in-body into fp32 VMEM scratch, epilogue fused, single output write.
+    ``counts`` [E] int32: live rows per unit (see "live row counts")."""
     E, M, _ = x.shape
     _, nob, kb, bs, _ = w.shape
     nib = x.shape[2] // bs
@@ -400,22 +468,30 @@ def fwd(x, w, idx, bias, *, act: str = "none", bm: int | None = None,
         bn = 1
     assert M % bm == 0, f"M={M} must be a multiple of bm={bm} (pad in ops.py)"
 
-    def fwd_kernel(idx_ref, x_ref, w_ref, b_ref, *rest):
+    def fwd_kernel(idx_ref, *refs):
+        cnt_ref = None
+        if counts is not None:
+            cnt_ref, *refs = refs
+        x_ref, w_ref, b_ref, *rest = refs
         acc_ref = rest[-1]
         o_ref = rest[0]
         ob0 = pl.program_id(2) * bn
-        for j in range(bn):
-            acc = jnp.zeros((bm, bs), jnp.float32)
-            for k in range(kb):
-                ib = idx_ref[ob0 + j, k]
-                xk = x_ref[0, :, pl.ds(ib * bs, bs)]
-                acc = acc + jnp.dot(xk, w_ref[0, j, k],
-                                    preferred_element_type=jnp.float32)
-            acc_ref[:, j * bs:(j + 1) * bs] = acc
-        s = acc_ref[...] + b_ref[0].astype(jnp.float32)
-        if save_pre:
-            rest[1][0] = s.astype(rest[1].dtype)
-        o_ref[0] = act_fwd(s, act).astype(o_ref.dtype)
+
+        def _compute():
+            for j in range(bn):
+                acc = jnp.zeros((bm, bs), jnp.float32)
+                for k in range(kb):
+                    ib = idx_ref[ob0 + j, k]
+                    xk = x_ref[0, :, pl.ds(ib * bs, bs)]
+                    acc = acc + jnp.dot(xk, w_ref[0, j, k],
+                                        preferred_element_type=jnp.float32)
+                acc_ref[:, j * bs:(j + 1) * bs] = acc
+            s = acc_ref[...] + b_ref[0].astype(jnp.float32)
+            if save_pre:
+                rest[1][0] = s.astype(rest[1].dtype)
+            o_ref[0] = act_fwd(s, act).astype(o_ref.dtype)
+
+        _run_live(_compute, cnt_ref, bm, 1)
 
     out_shape = [jax.ShapeDtypeStruct((E, M, nob * bs), x.dtype)]
     out_specs = [pl.BlockSpec((1, bm, bn * bs), lambda e, m, o, idx: (e, m, o))]
@@ -424,36 +500,40 @@ def fwd(x, w, idx, bias, *, act: str = "none", bm: int | None = None,
         out_specs.append(pl.BlockSpec((1, bm, bn * bs),
                                       lambda e, m, o, idx: (e, m, o)))
 
+    in_specs = [
+        # full activation row block, resident across bundle steps
+        pl.BlockSpec((1, bm, nib * bs), lambda e, m, o, idx: (e, m, 0)),
+        pl.BlockSpec((1, bn, kb, bs, bs),
+                     lambda e, m, o, idx: (e, o, 0, 0, 0)),
+        pl.BlockSpec((1, 1, bn * bs), lambda e, m, o, idx: (e, 0, o)),
+    ]
+    prefetch = (idx,) if counts is None else (idx, counts)
+    rows = lambda im: _rows_outer(im, bm, nob // bn)
     outs = pl.pallas_call(
         fwd_kernel,
-        name="junction_fwd",
+        name=_kernel_name("junction_fwd", counts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=(E, M // bm, nob // bn),
-            in_specs=[
-                # full activation row block, resident across bundle steps
-                pl.BlockSpec((1, bm, nib * bs), lambda e, m, o, idx: (e, m, 0)),
-                pl.BlockSpec((1, bn, kb, bs, bs),
-                             lambda e, m, o, idx: (e, o, 0, 0, 0)),
-                pl.BlockSpec((1, 1, bn * bs), lambda e, m, o, idx: (e, 0, o)),
-            ],
-            out_specs=out_specs,
+            in_specs=_counted_specs(in_specs, counts, rows),
+            out_specs=_counted_specs(out_specs, counts, rows),
             scratch_shapes=[pltpu.VMEM((bm, bn * bs), jnp.float32)],
         ),
         out_shape=out_shape,
         interpret=interpret,
-    )(idx, x, w, bias.reshape(E, 1, -1))
+    )(*prefetch, x, w, bias.reshape(E, 1, -1))
     return (outs[0], outs[1]) if save_pre else (outs[0], None)
 
 
 def gated_fwd(x, wg, wi, idx, *, bm: int | None = None,
               bn: int | None = None, save_res: bool = False,
-              interpret: bool = False):
+              interpret: bool = False, counts=None):
     """Fused SiLU-gate FFN entry: silu(x_e @ Wg_e) * (x_e @ Wi_e) in one
     pass — both kb fan-in reductions accumulate side by side in VMEM
     scratch, the gate epilogue fuses before the single output write.
     Returns (h, g_pre, u) — the pre-activation g and the linear branch u
-    are emitted only when save_res (backward residuals)."""
+    are emitted only when save_res (backward residuals).  ``counts``: live
+    rows per unit, as in ``fwd``."""
     E, M, _ = x.shape
     _, nob, kb, bs, _ = wg.shape
     nib = x.shape[2] // bs
@@ -465,28 +545,36 @@ def gated_fwd(x, wg, wi, idx, *, bm: int | None = None,
         bn = 1
     assert M % bm == 0, f"M={M} must be a multiple of bm={bm} (pad in ops.py)"
 
-    def gated_fwd_kernel(idx_ref, x_ref, wg_ref, wi_ref, *rest):
+    def gated_fwd_kernel(idx_ref, *refs):
+        cnt_ref = None
+        if counts is not None:
+            cnt_ref, *refs = refs
+        x_ref, wg_ref, wi_ref, *rest = refs
         accg_ref, accu_ref = rest[-2], rest[-1]
         h_ref = rest[0]
         ob0 = pl.program_id(2) * bn
-        for j in range(bn):
-            ag = jnp.zeros((bm, bs), jnp.float32)
-            au = jnp.zeros((bm, bs), jnp.float32)
-            for k in range(kb):
-                ib = idx_ref[ob0 + j, k]
-                xk = x_ref[0, :, pl.ds(ib * bs, bs)]
-                ag = ag + jnp.dot(xk, wg_ref[0, j, k],
-                                  preferred_element_type=jnp.float32)
-                au = au + jnp.dot(xk, wi_ref[0, j, k],
-                                  preferred_element_type=jnp.float32)
-            accg_ref[:, j * bs:(j + 1) * bs] = ag
-            accu_ref[:, j * bs:(j + 1) * bs] = au
-        g = accg_ref[...]
-        u = accu_ref[...]
-        if save_res:
-            rest[1][0] = g.astype(rest[1].dtype)
-            rest[2][0] = u.astype(rest[2].dtype)
-        h_ref[0] = (act_fwd(g, "silu") * u).astype(h_ref.dtype)
+
+        def _compute():
+            for j in range(bn):
+                ag = jnp.zeros((bm, bs), jnp.float32)
+                au = jnp.zeros((bm, bs), jnp.float32)
+                for k in range(kb):
+                    ib = idx_ref[ob0 + j, k]
+                    xk = x_ref[0, :, pl.ds(ib * bs, bs)]
+                    ag = ag + jnp.dot(xk, wg_ref[0, j, k],
+                                      preferred_element_type=jnp.float32)
+                    au = au + jnp.dot(xk, wi_ref[0, j, k],
+                                      preferred_element_type=jnp.float32)
+                accg_ref[:, j * bs:(j + 1) * bs] = ag
+                accu_ref[:, j * bs:(j + 1) * bs] = au
+            g = accg_ref[...]
+            u = accu_ref[...]
+            if save_res:
+                rest[1][0] = g.astype(rest[1].dtype)
+                rest[2][0] = u.astype(rest[2].dtype)
+            h_ref[0] = (act_fwd(g, "silu") * u).astype(h_ref.dtype)
+
+        _run_live(_compute, cnt_ref, bm, 1)
 
     out_shape = [jax.ShapeDtypeStruct((E, M, nob * bs), x.dtype)]
     out_specs = [pl.BlockSpec((1, bm, bn * bs), lambda e, m, o, idx: (e, m, o))]
@@ -496,26 +584,29 @@ def gated_fwd(x, wg, wi, idx, *, bm: int | None = None,
             out_specs.append(pl.BlockSpec((1, bm, bn * bs),
                                           lambda e, m, o, idx: (e, m, o)))
 
+    in_specs = [
+        pl.BlockSpec((1, bm, nib * bs), lambda e, m, o, idx: (e, m, 0)),
+        pl.BlockSpec((1, bn, kb, bs, bs),
+                     lambda e, m, o, idx: (e, o, 0, 0, 0)),
+        pl.BlockSpec((1, bn, kb, bs, bs),
+                     lambda e, m, o, idx: (e, o, 0, 0, 0)),
+    ]
+    prefetch = (idx,) if counts is None else (idx, counts)
+    rows = lambda im: _rows_outer(im, bm, nob // bn)
     outs = pl.pallas_call(
         gated_fwd_kernel,
-        name="junction_gated_fwd",
+        name=_kernel_name("junction_gated_fwd", counts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=(E, M // bm, nob // bn),
-            in_specs=[
-                pl.BlockSpec((1, bm, nib * bs), lambda e, m, o, idx: (e, m, 0)),
-                pl.BlockSpec((1, bn, kb, bs, bs),
-                             lambda e, m, o, idx: (e, o, 0, 0, 0)),
-                pl.BlockSpec((1, bn, kb, bs, bs),
-                             lambda e, m, o, idx: (e, o, 0, 0, 0)),
-            ],
-            out_specs=out_specs,
+            in_specs=_counted_specs(in_specs, counts, rows),
+            out_specs=_counted_specs(out_specs, counts, rows),
             scratch_shapes=[pltpu.VMEM((bm, bn * bs), jnp.float32),
                             pltpu.VMEM((bm, bn * bs), jnp.float32)],
         ),
         out_shape=out_shape,
         interpret=interpret,
-    )(idx, x, wg, wi)
+    )(*prefetch, x, wg, wi)
     return (outs[0], outs[1], outs[2]) if save_res else (outs[0], None, None)
 
 
@@ -786,7 +877,7 @@ def _run_copies(copies, method: str):
 
 
 def dx(dy, w, rev_ob, rev_t, rev_cnt, res, *, act: str = "none",
-       bm: int | None = None, interpret: bool = False):
+       bm: int | None = None, interpret: bool = False, counts=None):
     """dy [E, M, nob*bs] -> dx [E, M, nib*bs] via the shared reverse
     (fan-out) pattern against the forward-layout weights w
     [E, nob, kb, bs, bs].
@@ -803,7 +894,8 @@ def dx(dy, w, rev_ob, rev_t, rev_cnt, res, *, act: str = "none",
     slots (f >= rev_cnt[i], (0,0) sentinels) prefetch an in-bounds bundle
     whose contribution is where-masked, so zero-fan-out input blocks
     yield exact-zero dx rows even for non-finite dy.  The activation
-    gradient is recomputed per dy block from the residual."""
+    gradient is recomputed per dy block from the residual.  ``counts``:
+    live rows per unit, as in ``fwd``."""
     E, M, _ = dy.shape
     _, nob, kb, bs, _ = w.shape
     nib, fb = rev_ob.shape
@@ -815,6 +907,9 @@ def dx(dy, w, rev_ob, rev_t, rev_cnt, res, *, act: str = "none",
     w_flat = w.reshape(E, nob * kb, bs, bs)
 
     def dx_kernel(rev_ob_ref, rev_t_ref, rev_cnt_ref, *refs):
+        cnt_ref = None
+        if counts is not None:
+            cnt_ref, *refs = refs
         if has_res:
             dy_ref, res_ref, w_hbm, o_ref, wbuf, sems = refs
         else:
@@ -832,26 +927,29 @@ def dx(dy, w, rev_ob, rev_t, rev_cnt, res, *, act: str = "none",
             s1 = slot(f0 + 1) if f0 + 1 < fb else None
             return _pair_copies(w_hbm, wbuf, sems, e, slot(f0), s1, buf)
 
-        _run_copies(copies(0, 0), "start")
-        acc = jnp.zeros((bm, bs), jnp.float32)
-        for p in range(npair):
-            if p + 1 < npair:
-                _run_copies(copies((p + 1) % 2, p + 1), "start")
-            _run_copies(copies(p % 2, p), "wait")
-            for j in range(min(2, fb - 2 * p)):
-                f = 2 * p + j
-                ob = rev_ob_ref[i, f]
-                dyb = dy_ref[0, :, pl.ds(ob * bs, bs)]
-                if has_res:
-                    gr = act_bwd(
-                        res_ref[0, :, pl.ds(ob * bs, bs)].astype(jnp.float32),
-                        act)
-                    dz = (dyb.astype(jnp.float32) * gr).astype(dyb.dtype)
-                else:
-                    dz = dyb
-                acc = acc + jnp.where(f < cnt,
-                                      _rev_dot(dz, wbuf[p % 2, j]), 0.0)
-        o_ref[0] = acc.astype(o_ref.dtype)
+        def _compute():
+            _run_copies(copies(0, 0), "start")
+            acc = jnp.zeros((bm, bs), jnp.float32)
+            for p in range(npair):
+                if p + 1 < npair:
+                    _run_copies(copies((p + 1) % 2, p + 1), "start")
+                _run_copies(copies(p % 2, p), "wait")
+                for j in range(min(2, fb - 2 * p)):
+                    f = 2 * p + j
+                    ob = rev_ob_ref[i, f]
+                    dyb = dy_ref[0, :, pl.ds(ob * bs, bs)]
+                    if has_res:
+                        gr = act_bwd(
+                            res_ref[0, :, pl.ds(ob * bs, bs)].astype(jnp.float32),
+                            act)
+                        dz = (dyb.astype(jnp.float32) * gr).astype(dyb.dtype)
+                    else:
+                        dz = dyb
+                    acc = acc + jnp.where(f < cnt,
+                                          _rev_dot(dz, wbuf[p % 2, j]), 0.0)
+            o_ref[0] = acc.astype(o_ref.dtype)
+
+        _run_live(_compute, cnt_ref, bm, 1)
 
     in_specs = [pl.BlockSpec((1, bm, nob * bs),
                              lambda e, m, i, *_: (e, m, 0))]
@@ -863,31 +961,36 @@ def dx(dy, w, rev_ob, rev_t, rev_cnt, res, *, act: str = "none",
     in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
     inputs.append(w_flat)
 
+    out_specs = [pl.BlockSpec((1, bm, bs), lambda e, m, i, *_: (e, m, i))]
+    prefetch = (rev_ob, rev_t, rev_cnt)
+    if counts is not None:
+        prefetch += (counts,)
+    rows = lambda im: _rows_outer(im, bm, nib)
     return pl.pallas_call(
         dx_kernel,
-        name="junction_dx",
+        name=_kernel_name("junction_dx", counts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(prefetch),
             grid=(E, M // bm, nib),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, bm, bs),
-                                   lambda e, m, i, *_: (e, m, i)),
+            in_specs=_counted_specs(in_specs, counts, rows),
+            out_specs=_counted_specs(out_specs, counts, rows)[0],
             scratch_shapes=[pltpu.VMEM((2, 2, bs, bs), w.dtype),
                             pltpu.SemaphoreType.DMA((2, 2))],
         ),
         out_shape=jax.ShapeDtypeStruct((E, M, nib * bs), dy.dtype),
         interpret=interpret,
-    )(rev_ob, rev_t, rev_cnt, *inputs)
+    )(*prefetch, *inputs)
 
 
 def gated_dx(dh, wg, wi, rev_ob, rev_t, rev_cnt, g, u, *,
-             bm: int | None = None, interpret: bool = False):
+             bm: int | None = None, interpret: bool = False, counts=None):
     """Fused two-branch dx for the gated FFN: both branch grads
     (dz_g = dh * u * silu'(g), dz_u = dh * silu(g)) are recomputed per dy
     block from the saved residuals and reduced against their reverse
     bundles in the same fb loop — one pass over dh/g/u per input block,
     with BOTH weight streams double-buffered HBM→VMEM in-kernel and the
-    same pairwise contiguous-run descriptor coalescing as ``dx``."""
+    same pairwise contiguous-run descriptor coalescing as ``dx``.
+    ``counts``: live rows per unit, as in ``fwd``."""
     E, M, _ = dh.shape
     _, nob, kb, bs, _ = wg.shape
     nib, fb = rev_ob.shape
@@ -898,8 +1001,12 @@ def gated_dx(dh, wg, wi, rev_ob, rev_t, rev_cnt, g, u, *,
     wg_flat = wg.reshape(E, nob * kb, bs, bs)
     wi_flat = wi.reshape(E, nob * kb, bs, bs)
 
-    def gated_dx_kernel(rev_ob_ref, rev_t_ref, rev_cnt_ref, dh_ref, g_ref,
-                        u_ref, wg_hbm, wi_hbm, o_ref, wgbuf, wibuf, sems):
+    def gated_dx_kernel(rev_ob_ref, rev_t_ref, rev_cnt_ref, *refs):
+        cnt_ref = None
+        if counts is not None:
+            cnt_ref, *refs = refs
+        (dh_ref, g_ref, u_ref, wg_hbm, wi_hbm, o_ref, wgbuf, wibuf,
+         sems) = refs
         e = pl.program_id(0)
         i = pl.program_id(2)
         cnt = rev_cnt_ref[i]
@@ -914,54 +1021,64 @@ def gated_dx(dh, wg, wi, rev_ob, rev_t, rev_cnt, g, u, *,
             return (_pair_copies(wg_hbm, wgbuf, sems.at[0], e, s0, s1, buf)
                     + _pair_copies(wi_hbm, wibuf, sems.at[1], e, s0, s1, buf))
 
-        _run_copies(copies(0, 0), "start")
-        acc = jnp.zeros((bm, bs), jnp.float32)
-        for p in range(npair):
-            if p + 1 < npair:
-                _run_copies(copies((p + 1) % 2, p + 1), "start")
-            _run_copies(copies(p % 2, p), "wait")
-            for j in range(min(2, fb - 2 * p)):
-                f = 2 * p + j
-                cols = pl.ds(rev_ob_ref[i, f] * bs, bs)
-                dhb = dh_ref[0, :, cols].astype(jnp.float32)
-                gb = g_ref[0, :, cols].astype(jnp.float32)
-                ub = u_ref[0, :, cols].astype(jnp.float32)
-                dzg = (dhb * ub * act_bwd(gb, "silu")).astype(dh_ref.dtype)
-                dzu = (dhb * act_fwd(gb, "silu")).astype(dh_ref.dtype)
-                part = (_rev_dot(dzg, wgbuf[p % 2, j])
-                        + _rev_dot(dzu, wibuf[p % 2, j]))
-                acc = acc + jnp.where(f < cnt, part, 0.0)
-        o_ref[0] = acc.astype(o_ref.dtype)
+        def _compute():
+            _run_copies(copies(0, 0), "start")
+            acc = jnp.zeros((bm, bs), jnp.float32)
+            for p in range(npair):
+                if p + 1 < npair:
+                    _run_copies(copies((p + 1) % 2, p + 1), "start")
+                _run_copies(copies(p % 2, p), "wait")
+                for j in range(min(2, fb - 2 * p)):
+                    f = 2 * p + j
+                    cols = pl.ds(rev_ob_ref[i, f] * bs, bs)
+                    dhb = dh_ref[0, :, cols].astype(jnp.float32)
+                    gb = g_ref[0, :, cols].astype(jnp.float32)
+                    ub = u_ref[0, :, cols].astype(jnp.float32)
+                    dzg = (dhb * ub * act_bwd(gb, "silu")
+                           ).astype(dh_ref.dtype)
+                    dzu = (dhb * act_fwd(gb, "silu")).astype(dh_ref.dtype)
+                    part = (_rev_dot(dzg, wgbuf[p % 2, j])
+                            + _rev_dot(dzu, wibuf[p % 2, j]))
+                    acc = acc + jnp.where(f < cnt, part, 0.0)
+            o_ref[0] = acc.astype(o_ref.dtype)
+
+        _run_live(_compute, cnt_ref, bm, 1)
 
     row = pl.BlockSpec((1, bm, nob * bs), lambda e, m, i, *_: (e, m, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
+    out_specs = [pl.BlockSpec((1, bm, bs), lambda e, m, i, *_: (e, m, i))]
+    prefetch = (rev_ob, rev_t, rev_cnt)
+    if counts is not None:
+        prefetch += (counts,)
+    rows = lambda im: _rows_outer(im, bm, nib)
     return pl.pallas_call(
         gated_dx_kernel,
-        name="junction_gated_dx",
+        name=_kernel_name("junction_gated_dx", counts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(prefetch),
             grid=(E, M // bm, nib),
-            in_specs=[row, row, row, hbm, hbm],
-            out_specs=pl.BlockSpec((1, bm, bs),
-                                   lambda e, m, i, *_: (e, m, i)),
+            in_specs=_counted_specs([row, row, row, hbm, hbm], counts, rows),
+            out_specs=_counted_specs(out_specs, counts, rows)[0],
             scratch_shapes=[pltpu.VMEM((2, 2, bs, bs), wg.dtype),
                             pltpu.VMEM((2, 2, bs, bs), wi.dtype),
                             pltpu.SemaphoreType.DMA((2, 2, 2))],
         ),
         out_shape=jax.ShapeDtypeStruct((E, M, nib * bs), dh.dtype),
         interpret=interpret,
-    )(rev_ob, rev_t, rev_cnt, dh, g, u, wg_flat, wi_flat)
+    )(*prefetch, dh, g, u, wg_flat, wi_flat)
 
 
 # ------------------------------------------------------------------ dw (+db)
 def dw(x, dy, idx, res, *, act: str = "none", with_bias: bool = True,
-       bm: int | None = None, interpret: bool = False):
+       bm: int | None = None, interpret: bool = False, counts=None):
     """(dw [E, nob, kb, bs, bs] fp32, db [E, nob*bs] fp32 or None) — grid
     (E, nob, M/bm) with the M reduction innermost into fp32 VMEM scratch,
     flushed once per (unit, output block).  The kb gathered input blocks
     arrive through scalar-prefetch BlockSpec index_maps — the interleaver
     as a DMA descriptor — and, for biased layers, db accumulates from the
-    same fused dz prologue (with_bias=False skips it entirely)."""
+    same fused dz prologue (with_bias=False skips it entirely).
+    ``counts``: live rows per unit, as in ``fwd`` (dead row tiles add
+    nothing)."""
     E, M, _ = x.shape
     nob, kb = idx.shape
     bs = dy.shape[2] // nob
@@ -972,6 +1089,9 @@ def dw(x, dy, idx, res, *, act: str = "none", with_bias: bool = True,
     nm = M // bm
 
     def dw_kernel(idx_ref, *refs):
+        cnt_ref = None
+        if counts is not None:
+            cnt_ref, *refs = refs
         n_in = (2 if has_res else 1) + kb
         dy_ref = refs[0]
         res_ref = refs[1] if has_res else None
@@ -988,19 +1108,23 @@ def dw(x, dy, idx, res, *, act: str = "none", with_bias: bool = True,
             if with_bias:
                 accb_ref[...] = jnp.zeros((1, bs), jnp.float32)
 
-        if has_res:
-            grad = act_bwd(res_ref[0].astype(jnp.float32), act)
-            dzf = dy_ref[0].astype(jnp.float32) * grad
-            dz = dzf.astype(dy_ref.dtype)
-        else:
-            dzf = None
-            dz = dy_ref[0]
-        for k in range(kb):
-            accw_ref[k] = accw_ref[k] + jnp.dot(
-                x_refs[k][0].T, dz, preferred_element_type=jnp.float32)
-        if with_bias:
-            s = dzf if dzf is not None else dy_ref[0].astype(jnp.float32)
-            accb_ref[...] = accb_ref[...] + jnp.sum(s, axis=0, keepdims=True)
+        def _accumulate():
+            if has_res:
+                grad = act_bwd(res_ref[0].astype(jnp.float32), act)
+                dzf = dy_ref[0].astype(jnp.float32) * grad
+                dz = dzf.astype(dy_ref.dtype)
+            else:
+                dzf = None
+                dz = dy_ref[0]
+            for k in range(kb):
+                accw_ref[k] = accw_ref[k] + jnp.dot(
+                    x_refs[k][0].T, dz, preferred_element_type=jnp.float32)
+            if with_bias:
+                s = dzf if dzf is not None else dy_ref[0].astype(jnp.float32)
+                accb_ref[...] = accb_ref[...] + jnp.sum(s, axis=0,
+                                                        keepdims=True)
+
+        _run_live(_accumulate, cnt_ref, bm, 2)
 
         @pl.when(m == nm - 1)
         def _flush():
@@ -1028,30 +1152,51 @@ def dw(x, dy, idx, res, *, act: str = "none", with_bias: bool = True,
         out_shape.append(jax.ShapeDtypeStruct((E, 1, nob * bs), jnp.float32))
         scratch.append(pltpu.VMEM((1, bs), jnp.float32))
 
+    prefetch = (idx,) if counts is None else (idx, counts)
+    rows = lambda im: _rows_inner(im, bm)
     outs = pl.pallas_call(
         dw_kernel,
-        name="junction_dw",
+        name=_kernel_name("junction_dw", counts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=(E, nob, nm),
-            in_specs=in_specs,
-            out_specs=out_specs,
+            in_specs=_counted_specs(in_specs, counts, rows),
+            out_specs=_counted_specs(out_specs, counts, rows),
             scratch_shapes=scratch,
         ),
         out_shape=out_shape,
         interpret=interpret,
-    )(idx, *inputs)
+    )(*prefetch, *inputs)
     if with_bias:
         return outs[0], outs[1].reshape(E, -1)
     return outs[0], None
 
 
+def _gated_accumulate(dh_ref, g_ref, u_ref, x_refs, accg_ref, accu_ref,
+                      kb: int):
+    """One row tile of the gated junction's two weight-gradient
+    reductions: both branch grads recomputed from the (g, u) residuals,
+    then every fan-in slot's ``x^T dz`` added into its VMEM scratch."""
+    dhb = dh_ref[0].astype(jnp.float32)
+    gb = g_ref[0].astype(jnp.float32)
+    ub = u_ref[0].astype(jnp.float32)
+    dzg = (dhb * ub * act_bwd(gb, "silu")).astype(dh_ref.dtype)
+    dzu = (dhb * act_fwd(gb, "silu")).astype(dh_ref.dtype)
+    for k in range(kb):
+        xT = x_refs[k][0].T
+        accg_ref[k] = accg_ref[k] + jnp.dot(
+            xT, dzg, preferred_element_type=jnp.float32)
+        accu_ref[k] = accu_ref[k] + jnp.dot(
+            xT, dzu, preferred_element_type=jnp.float32)
+
+
 def gated_dw(x, dh, idx, g, u, *, bm: int | None = None,
-             interpret: bool = False):
+             interpret: bool = False, counts=None):
     """(dwg, dwi) [E, nob, kb, bs, bs] fp32 for the fused gated FFN — the
     two branch grads are recomputed in the prologue from the (g, u)
     residuals and both M reductions accumulate innermost into separate
-    VMEM scratch buffers, flushed once per (unit, output block)."""
+    VMEM scratch buffers, flushed once per (unit, output block).
+    ``counts``: live rows per unit, as in ``dw``."""
     E, M, _ = x.shape
     nob, kb = idx.shape
     bs = dh.shape[2] // nob
@@ -1060,9 +1205,13 @@ def gated_dw(x, dh, idx, g, u, *, bm: int | None = None,
     assert M % bm == 0
     nm = M // bm
 
-    def gated_dw_kernel(idx_ref, dh_ref, g_ref, u_ref, *refs):
-        x_refs = refs[:kb]
-        dwg_ref, dwi_ref, accg_ref, accu_ref = refs[kb:]
+    def gated_dw_kernel(idx_ref, *refs):
+        cnt_ref = None
+        if counts is not None:
+            cnt_ref, *refs = refs
+        dh_ref, g_ref, u_ref = refs[:3]
+        x_refs = refs[3:3 + kb]
+        dwg_ref, dwi_ref, accg_ref, accu_ref = refs[3 + kb:]
         m = pl.program_id(2)
 
         @pl.when(m == 0)
@@ -1070,17 +1219,11 @@ def gated_dw(x, dh, idx, g, u, *, bm: int | None = None,
             accg_ref[...] = jnp.zeros((kb, bs, bs), jnp.float32)
             accu_ref[...] = jnp.zeros((kb, bs, bs), jnp.float32)
 
-        dhb = dh_ref[0].astype(jnp.float32)
-        gb = g_ref[0].astype(jnp.float32)
-        ub = u_ref[0].astype(jnp.float32)
-        dzg = (dhb * ub * act_bwd(gb, "silu")).astype(dh_ref.dtype)
-        dzu = (dhb * act_fwd(gb, "silu")).astype(dh_ref.dtype)
-        for k in range(kb):
-            xT = x_refs[k][0].T
-            accg_ref[k] = accg_ref[k] + jnp.dot(
-                xT, dzg, preferred_element_type=jnp.float32)
-            accu_ref[k] = accu_ref[k] + jnp.dot(
-                xT, dzu, preferred_element_type=jnp.float32)
+        def _accumulate():
+            _gated_accumulate(dh_ref, g_ref, u_ref, x_refs, accg_ref,
+                              accu_ref, kb)
+
+        _run_live(_accumulate, cnt_ref, bm, 2)
 
         @pl.when(m == nm - 1)
         def _flush():
@@ -1096,21 +1239,23 @@ def gated_dw(x, dh, idx, g, u, *, bm: int | None = None,
         inputs.append(x)
 
     wout = pl.BlockSpec((1, 1, kb, bs, bs), lambda e, o, m, idx: (e, o, 0, 0, 0))
+    prefetch = (idx,) if counts is None else (idx, counts)
+    rows = lambda im: _rows_inner(im, bm)
     outs = pl.pallas_call(
         gated_dw_kernel,
-        name="junction_gated_dw",
+        name=_kernel_name("junction_gated_dw", counts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=len(prefetch),
             grid=(E, nob, nm),
-            in_specs=in_specs,
-            out_specs=[wout, wout],
+            in_specs=_counted_specs(in_specs, counts, rows),
+            out_specs=_counted_specs([wout, wout], counts, rows),
             scratch_shapes=[pltpu.VMEM((kb, bs, bs), jnp.float32),
                             pltpu.VMEM((kb, bs, bs), jnp.float32)],
         ),
         out_shape=[jax.ShapeDtypeStruct((E, nob, kb, bs, bs), jnp.float32),
                    jax.ShapeDtypeStruct((E, nob, kb, bs, bs), jnp.float32)],
         interpret=interpret,
-    )(idx, *inputs)
+    )(*prefetch, *inputs)
     return outs[0], outs[1]
 
 
@@ -1209,7 +1354,7 @@ def _epilogue_step(h, acc, w32, mom, vel, with_health):
 def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
               vel_b=None, act: str = "none", with_bias: bool = True,
               bm: int | None = None, with_health: bool = False,
-              interpret: bool = False):
+              interpret: bool = False, counts=None):
     """The fused UP stage: the ``dw`` gradient reduction with the
     optimizer update applied in the flush epilogue — returns
     ``(new_w, new_b, new_mom, new_mom_b, new_vel, new_vel_b, health)``
@@ -1235,7 +1380,10 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
     count into unit e's slot — the in-kernel divergence detector (one
     VMEM compare per tile; the gradient still never materializes in
     HBM).  health[e] > 0 means unit e wrote at least one non-finite
-    parameter tile this step."""
+    parameter tile this step.
+
+    ``counts``: live rows per unit, as in ``dw``; every (unit, output
+    block) is still updated, a unit with no live row from its moments."""
     E, M, _ = x.shape
     nob, kb = idx.shape
     bs = dy.shape[2] // nob
@@ -1251,6 +1399,9 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
     nm = M // bm
 
     def fused_update_dw(idx_ref, hyp_ref, *refs):
+        cnt_ref = None
+        if counts is not None:
+            cnt_ref, *refs = refs
         n_lead = 2 if has_res else 1
         dy_ref = refs[0]
         res_ref = refs[1] if has_res else None
@@ -1296,19 +1447,23 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
             def _zero_health():
                 health_ref[...] = jnp.zeros(health_ref.shape, jnp.int32)
 
-        if has_res:
-            grad = act_bwd(res_ref[0].astype(jnp.float32), act)
-            dzf = dy_ref[0].astype(jnp.float32) * grad
-            dz = dzf.astype(dy_ref.dtype)
-        else:
-            dzf = None
-            dz = dy_ref[0]
-        for k in range(kb):
-            accw_ref[k] = accw_ref[k] + jnp.dot(
-                x_refs[k][0].T, dz, preferred_element_type=jnp.float32)
-        if with_bias:
-            s = dzf if dzf is not None else dy_ref[0].astype(jnp.float32)
-            accb_ref[...] = accb_ref[...] + jnp.sum(s, axis=0, keepdims=True)
+        def _accumulate():
+            if has_res:
+                grad = act_bwd(res_ref[0].astype(jnp.float32), act)
+                dzf = dy_ref[0].astype(jnp.float32) * grad
+                dz = dzf.astype(dy_ref.dtype)
+            else:
+                dzf = None
+                dz = dy_ref[0]
+            for k in range(kb):
+                accw_ref[k] = accw_ref[k] + jnp.dot(
+                    x_refs[k][0].T, dz, preferred_element_type=jnp.float32)
+            if with_bias:
+                s = dzf if dzf is not None else dy_ref[0].astype(jnp.float32)
+                accb_ref[...] = accb_ref[...] + jnp.sum(s, axis=0,
+                                                        keepdims=True)
+
+        _run_live(_accumulate, cnt_ref, bm, 2)
 
         @pl.when(m == nm - 1)
         def _apply():
@@ -1357,10 +1512,12 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
     aliases: dict[int, int] = {}
     out_specs, out_shape = [], []
 
+    n_prefetch = N_SCALAR_PREFETCH_UPDATE + (counts is not None)
+
     def alias_io(arr, spec):
         """Parameter operand riding in AND out through the same BlockSpec —
         the in-place update contract."""
-        aliases[N_SCALAR_PREFETCH_UPDATE + len(inputs)] = len(out_shape)
+        aliases[n_prefetch + len(inputs)] = len(out_shape)
         in_specs.append(spec)
         inputs.append(arr)
         out_specs.append(spec)
@@ -1385,20 +1542,22 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
     if with_bias:
         scratch.append(pltpu.VMEM((1, bs), jnp.float32))
 
+    prefetch = (idx, hyp) if counts is None else (idx, hyp, counts)
+    rows = lambda im: _rows_inner(im, bm)
     outs = pl.pallas_call(
         fused_update_dw,
-        name="junction_update_dw",
+        name=_kernel_name("junction_update_dw", counts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=N_SCALAR_PREFETCH_UPDATE,
+            num_scalar_prefetch=n_prefetch,
             grid=(E, nob, nm),
-            in_specs=in_specs,
-            out_specs=out_specs,
+            in_specs=_counted_specs(in_specs, counts, rows),
+            out_specs=_counted_specs(out_specs, counts, rows),
             scratch_shapes=scratch,
         ),
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
-    )(idx, hyp, *inputs)
+    )(*prefetch, *inputs)
     outs = list(outs)
     new_w = outs.pop(0)
     new_mom = outs.pop(0) if has_mom else None
@@ -1413,7 +1572,8 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
 
 def update_gated_dw(x, dh, idx, g, u, wg, wi, mg, mi, hyp, *, vg=None,
                     vi=None, bm: int | None = None,
-                    with_health: bool = False, interpret: bool = False):
+                    with_health: bool = False, interpret: bool = False,
+                    counts=None):
     """Fused BP+UP for the gated junction: both branch gradients reduce
     into VMEM scratch exactly as in ``gated_dw`` and the flush epilogue
     applies the optimizer update to BOTH weight streams in place —
@@ -1424,7 +1584,8 @@ def update_gated_dw(x, dh, idx, g, u, wg, wi, mg, mi, hyp, *, vg=None,
     optimizer statically — mg/mi alone → SGD(+momentum), plus vg/vi →
     Adam.  ``with_health=True`` appends the non-aliased ``[E]``
     int32 divergence detector (see ``update_dw``): the epilogue checks
-    BOTH branch update tiles for non-finites."""
+    BOTH branch update tiles for non-finites.  ``counts``: live rows per
+    unit, as in ``update_dw``."""
     E, M, _ = x.shape
     nob, kb = idx.shape
     bs = dh.shape[2] // nob
@@ -1438,9 +1599,13 @@ def update_gated_dw(x, dh, idx, g, u, wg, wi, mg, mi, hyp, *, vg=None,
     assert M % bm == 0
     nm = M // bm
 
-    def fused_update_gated_dw(idx_ref, hyp_ref, dh_ref, g_ref, u_ref, *refs):
-        x_refs = refs[:kb]
-        pos = kb
+    def fused_update_gated_dw(idx_ref, hyp_ref, *refs):
+        cnt_ref = None
+        if counts is not None:
+            cnt_ref, *refs = refs
+        dh_ref, g_ref, u_ref = refs[:3]
+        x_refs = refs[3:3 + kb]
+        pos = 3 + kb
         wg_ref, wi_ref = refs[pos], refs[pos + 1]
         pos += 2
         if has_mom:
@@ -1474,17 +1639,11 @@ def update_gated_dw(x, dh, idx, g, u, wg, wi, mg, mi, hyp, *, vg=None,
             def _zero_health():
                 health_ref[...] = jnp.zeros(health_ref.shape, jnp.int32)
 
-        dhb = dh_ref[0].astype(jnp.float32)
-        gb = g_ref[0].astype(jnp.float32)
-        ub = u_ref[0].astype(jnp.float32)
-        dzg = (dhb * ub * act_bwd(gb, "silu")).astype(dh_ref.dtype)
-        dzu = (dhb * act_fwd(gb, "silu")).astype(dh_ref.dtype)
-        for k in range(kb):
-            xT = x_refs[k][0].T
-            accg_ref[k] = accg_ref[k] + jnp.dot(
-                xT, dzg, preferred_element_type=jnp.float32)
-            accu_ref[k] = accu_ref[k] + jnp.dot(
-                xT, dzu, preferred_element_type=jnp.float32)
+        def _accumulate():
+            _gated_accumulate(dh_ref, g_ref, u_ref, x_refs, accg_ref,
+                              accu_ref, kb)
+
+        _run_live(_accumulate, cnt_ref, bm, 2)
 
         @pl.when(m == nm - 1)
         def _apply():
@@ -1521,9 +1680,10 @@ def update_gated_dw(x, dh, idx, g, u, wg, wi, mg, mi, hyp, *, vg=None,
     wspec = pl.BlockSpec((1, 1, kb, bs, bs), lambda e, o, m, *_: (e, o, 0, 0, 0))
     aliases: dict[int, int] = {}
     out_specs, out_shape = [], []
+    n_prefetch = N_SCALAR_PREFETCH_UPDATE + (counts is not None)
 
     def alias_io(arr):
-        aliases[N_SCALAR_PREFETCH_UPDATE + len(inputs)] = len(out_shape)
+        aliases[n_prefetch + len(inputs)] = len(out_shape)
         in_specs.append(wspec)
         inputs.append(arr)
         out_specs.append(wspec)
@@ -1541,21 +1701,23 @@ def update_gated_dw(x, dh, idx, g, u, wg, wi, mg, mi, hyp, *, vg=None,
         out_specs.append(HEALTH_SPEC)
         out_shape.append(_health_shape(E))
 
+    prefetch = (idx, hyp) if counts is None else (idx, hyp, counts)
+    rows = lambda im: _rows_inner(im, bm)
     outs = pl.pallas_call(
         fused_update_gated_dw,
-        name="junction_update_gated_dw",
+        name=_kernel_name("junction_update_gated_dw", counts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=N_SCALAR_PREFETCH_UPDATE,
+            num_scalar_prefetch=n_prefetch,
             grid=(E, nob, nm),
-            in_specs=in_specs,
-            out_specs=out_specs,
+            in_specs=_counted_specs(in_specs, counts, rows),
+            out_specs=_counted_specs(out_specs, counts, rows),
             scratch_shapes=[pltpu.VMEM((kb, bs, bs), jnp.float32),
                             pltpu.VMEM((kb, bs, bs), jnp.float32)],
         ),
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
-    )(idx, hyp, *inputs)
+    )(*prefetch, *inputs)
     outs = list(outs)
     new_wg = outs.pop(0)
     new_wi = outs.pop(0)

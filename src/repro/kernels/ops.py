@@ -103,61 +103,79 @@ def _kernel_scope(name: str, spec: KernelSpec):
     return jax.named_scope(tag)
 
 
-def _fwd_call(spec, x, ws, b, idx, save: bool):
+def _counted(counts) -> dict:
+    """Keyword arguments of a kernel call for the live row counts: none
+    at all without them, so an uncounted call is the same program."""
+    return {} if counts is None else {"counts": counts}
+
+
+def _bwd_tile(spec, counts) -> dict:
+    """Counted calls run every kernel on the forward's row tile, so the
+    live tiles (and the rows they cover) are the same in each."""
+    return {} if counts is None else {"bm": spec.bm}
+
+
+def _fwd_call(spec, x, ws, b, idx, save: bool, counts=None):
     """(y, res) through the forward kernels; res is the backward residual
     ((g, u) for gated, pre-activation or y for plain activations, None
     otherwise) — emitted only when ``save``."""
     if spec.gated:
         h, g, u = bsm.gated_fwd(x, ws[0], ws[1], idx, bm=spec.bm, bn=spec.bn,
-                                save_res=save, interpret=spec.interpret)
+                                save_res=save, interpret=spec.interpret,
+                                **_counted(counts))
         return h, ((g, u) if save else None)
     needs_pre = spec.act in bsm.ACT_NEEDS_PRE
     y, pre = bsm.fwd(x, ws[0], idx, b, act=spec.act, bm=spec.bm, bn=spec.bn,
-                     save_pre=save and needs_pre, interpret=spec.interpret)
+                     save_pre=save and needs_pre, interpret=spec.interpret,
+                     **_counted(counts))
     if not save:
         return y, None
     return y, (pre if needs_pre else (y if spec.act != "none" else None))
 
 
-def _dx_call(spec, ws, res, dy, rev_ob, rev_t, rev_cnt):
+def _dx_call(spec, ws, res, dy, rev_ob, rev_t, rev_cnt, counts=None):
     """BP through the reverse pattern — the reverse weight bundles are
     DMA'd HBM→VMEM inside the kernel from the forward-layout weights (no
     XLA w[rev_ob, rev_t] pre-gather)."""
+    kw = dict(_counted(counts), **_bwd_tile(spec, counts))
     if spec.gated:
         g, u = res
         return bsm.gated_dx(dy, ws[0], ws[1], rev_ob, rev_t, rev_cnt, g, u,
-                            interpret=spec.interpret)
+                            interpret=spec.interpret, **kw)
     return bsm.dx(dy, ws[0], rev_ob, rev_t, rev_cnt, res, act=spec.act,
-                  interpret=spec.interpret)
+                  interpret=spec.interpret, **kw)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
-def _junction_core(spec, x, ws, b, idx, rev_ob, rev_t, rev_cnt):
+def _junction_core(spec, x, ws, b, idx, rev_ob, rev_t, rev_cnt, counts):
     """x [E, M, nib*bs], ws tuple of 1 (plain) or 2 (gated) weight tensors
-    [E, nob, kb, bs, bs], b [E, nob*bs] -> y [E, M, nob*bs]."""
-    y, _ = _fwd_call(spec, x, ws, b, idx, save=False)
+    [E, nob, kb, bs, bs], b [E, nob*bs] -> y [E, M, nob*bs]; counts: live
+    rows per unit [E] int32, or None (every row)."""
+    y, _ = _fwd_call(spec, x, ws, b, idx, save=False, counts=counts)
     return y
 
 
-def _junction_fwd(spec, x, ws, b, idx, rev_ob, rev_t, rev_cnt):
-    y, res = _fwd_call(spec, x, ws, b, idx, save=True)
-    return y, (x, ws, res, idx, rev_ob, rev_t, rev_cnt)
+def _junction_fwd(spec, x, ws, b, idx, rev_ob, rev_t, rev_cnt, counts):
+    y, res = _fwd_call(spec, x, ws, b, idx, save=True, counts=counts)
+    return y, (x, ws, res, idx, rev_ob, rev_t, rev_cnt, counts)
 
 
 def _junction_bwd(spec, saved, dy):
-    x, ws, res, idx, rev_ob, rev_t, rev_cnt = saved
-    dxv = _dx_call(spec, ws, res, dy, rev_ob, rev_t, rev_cnt)
+    x, ws, res, idx, rev_ob, rev_t, rev_cnt, counts = saved
+    dxv = _dx_call(spec, ws, res, dy, rev_ob, rev_t, rev_cnt, counts)
+    kw = dict(_counted(counts), **_bwd_tile(spec, counts))
     if spec.gated:
         g, u = res
-        dwg, dwi = bsm.gated_dw(x, dy, idx, g, u, interpret=spec.interpret)
+        dwg, dwi = bsm.gated_dw(x, dy, idx, g, u, interpret=spec.interpret,
+                                **kw)
         dws = (dwg.astype(ws[0].dtype), dwi.astype(ws[1].dtype))
         db = jnp.zeros((dy.shape[0], dy.shape[2]), jnp.float32)
-        return dxv, dws, db, None, None, None, None
+        return dxv, dws, db, None, None, None, None, None
     dwv, dbv = bsm.dw(x, dy, idx, res, act=spec.act,
-                      with_bias=spec.has_bias, interpret=spec.interpret)
+                      with_bias=spec.has_bias, interpret=spec.interpret, **kw)
     if dbv is None:  # bias-free layer: the zero-bias operand gets zeros
         dbv = jnp.zeros((dy.shape[0], dy.shape[2]), jnp.float32)
-    return dxv, (dwv.astype(ws[0].dtype),), dbv, None, None, None, None
+    return dxv, (dwv.astype(ws[0].dtype),), dbv, None, None, None, None, None
 
 
 _junction_core.defvjp(_junction_fwd, _junction_bwd)
@@ -166,7 +184,7 @@ _junction_core.defvjp(_junction_fwd, _junction_bwd)
 # ------------------------------------------------- fused BP+UP custom_vjp
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _junction_update_core(spec, x, ws, b, moms, mom_b, vels, vel_b, hyp,
-                          health, idx, rev_ob, rev_t, rev_cnt):
+                          health, idx, rev_ob, rev_t, rev_cnt, counts):
     """Forward identical to _junction_core; the vjp's cotangents for the
     parameter operands are the optimizer-UPDATED values computed by the
     fused update_dw kernels (kernels/block_sparse_matmul.py) — the
@@ -184,22 +202,26 @@ def _junction_update_core(spec, x, ws, b, moms, mom_b, vels, vel_b, hyp,
     [E] int32 divergence flags come back as its cotangent (count of
     non-finite update tiles per unit), so the in-kernel detector
     surfaces through an ordinary jax.grad without materializing any
-    gradient — the forward ignores the operand entirely."""
-    y, _ = _fwd_call(spec, x, ws, b, idx, save=False)
+    gradient — the forward ignores the operand entirely.
+
+    ``counts`` (live rows per unit, or None) skips dead row tiles in
+    every kernel; the update epilogue still runs for every unit."""
+    y, _ = _fwd_call(spec, x, ws, b, idx, save=False, counts=counts)
     return y
 
 
 def _junction_update_fwd(spec, x, ws, b, moms, mom_b, vels, vel_b, hyp,
-                         health, idx, rev_ob, rev_t, rev_cnt):
-    y, res = _fwd_call(spec, x, ws, b, idx, save=True)
+                         health, idx, rev_ob, rev_t, rev_cnt, counts):
+    y, res = _fwd_call(spec, x, ws, b, idx, save=True, counts=counts)
     return y, (x, ws, b, res, moms, mom_b, vels, vel_b, hyp, idx, rev_ob,
-               rev_t, rev_cnt)
+               rev_t, rev_cnt, counts)
 
 
 def _junction_update_bwd(spec, saved, dy):
     (x, ws, b, res, moms, mom_b, vels, vel_b, hyp, idx, rev_ob, rev_t,
-     rev_cnt) = saved
-    dxv = _dx_call(spec, ws, res, dy, rev_ob, rev_t, rev_cnt)
+     rev_cnt, counts) = saved
+    dxv = _dx_call(spec, ws, res, dy, rev_ob, rev_t, rev_cnt, counts)
+    kw = dict(_counted(counts), **_bwd_tile(spec, counts))
     if spec.gated:
         g, u = res
         nwg, nwi, nmg, nmi, nvg, nvi, flags = bsm.update_gated_dw(
@@ -207,7 +229,7 @@ def _junction_update_bwd(spec, saved, dy):
             moms[0] if moms else None, moms[1] if moms else None,
             hyp, vg=vels[0] if vels else None,
             vi=vels[1] if vels else None,
-            with_health=spec.with_health, interpret=spec.interpret)
+            with_health=spec.with_health, interpret=spec.interpret, **kw)
         new_ws = (nwg, nwi)
         new_moms = (nmg, nmi) if moms else ()
         new_vels = (nvg, nvi) if vels else ()
@@ -222,7 +244,7 @@ def _junction_update_bwd(spec, saved, dy):
             hyp, vel=vels[0] if vels else None,
             vel_b=vel_b[0] if vel_b else None,
             act=spec.act, with_bias=spec.has_bias,
-            with_health=spec.with_health, interpret=spec.interpret)
+            with_health=spec.with_health, interpret=spec.interpret, **kw)
         new_ws = (nw,)
         new_moms = (nm,) if moms else ()
         new_vels = (nv,) if vels else ()
@@ -232,7 +254,7 @@ def _junction_update_bwd(spec, saved, dy):
     d_health = (flags.astype(jnp.float32)
                 if spec.with_health else jnp.zeros((spec.E,), jnp.float32))
     return (dxv, new_ws, new_b, new_moms, new_mom_b, new_vels, new_vel_b,
-            jnp.zeros_like(hyp), d_health, None, None, None, None)
+            jnp.zeros_like(hyp), d_health, None, None, None, None, None)
 
 
 _junction_update_core.defvjp(_junction_update_fwd, _junction_update_bwd)
@@ -242,7 +264,7 @@ def junction_matmul(x, w, idx, rev_ob, rev_t, rev_cnt, *, wi=None, bias=None,
                     act: str = "none", interpret: bool | None = None,
                     bm: int | None = None, bn: int | None = None,
                     w_scale=None, wi_scale=None, x_scale=None,
-                    qfmt=None, qlut=None):
+                    qfmt=None, qlut=None, counts=None):
     """The unified junction: y = act(x @ W_sparse + bias) through the
     pre-defined block pattern, every configuration through ONE custom_vjp.
 
@@ -261,6 +283,11 @@ def junction_matmul(x, w, idx, rev_ob, rev_t, rev_cnt, *, wi=None, bias=None,
       ``qfmt`` + ``qlut`` select full fixed-point (plain junctions
       only, LUT replaces ``act``).  These specs are FORWARD-ONLY — no
       custom_vjp; differentiate the fp junction instead.
+    * ``counts`` (int32 ``[E]``, 5-D weights only): unit e holds
+      ``counts[e]`` live rows at the top of its ``M``; row tiles past it
+      are skipped in every kernel, forward and backward, and rows past
+      it come back unwritten.  The kernels then carry the
+      ``expert_junction_`` names.
     """
     interpret = _auto_interpret() if interpret is None else interpret
     gated = wi is not None
@@ -278,6 +305,7 @@ def junction_matmul(x, w, idx, rev_ob, rev_t, rev_cnt, *, wi=None, bias=None,
             "cast codes to floats silently")
     single, lead, x3, w5, wi5, b2, E, M, nob, bs, bm, bn = _prep_junction(
         x, w, wi, bias, bm, bn, gated)
+    _check_counts(counts, single, E)
     b = (jnp.zeros((E, nob * bs), x.dtype) if b2 is None
          else b2.astype(x.dtype))
     ws = ((w5.astype(x.dtype), wi5.astype(x.dtype)) if gated
@@ -285,7 +313,8 @@ def junction_matmul(x, w, idx, rev_ob, rev_t, rev_cnt, *, wi=None, bias=None,
     spec = KernelSpec(E=E, gated=gated, act=act, bm=bm, bn=bn,
                       has_bias=bias is not None, interpret=interpret)
     with _kernel_scope("junction_matmul", spec):
-        y = _junction_core(spec, x3, ws, b, idx, rev_ob, rev_t, rev_cnt)
+        y = _junction_core(spec, x3, ws, b, idx, rev_ob, rev_t, rev_cnt,
+                           counts)
     y = y[:, :M]
     return y.reshape(*lead, nob * bs) if single else y
 
@@ -363,6 +392,14 @@ def _prep_junction(x, w, wi, bias, bm, bn, gated):
     return single, lead, x3, w5, wi5, b2, E, M, nob, bs, bm, bn
 
 
+def _check_counts(counts, single: bool, E: int) -> None:
+    if counts is None:
+        return
+    if single or counts.shape != (E,) or counts.dtype != jnp.int32:
+        raise ValueError(f"counts must be int32 [E={E}] beside 5-D expert "
+                         f"weights, got {counts.shape} {counts.dtype}")
+
+
 def _pad_junction_rows(x, bm):
     M = x.shape[1]
     pad = (-M) % bm
@@ -376,7 +413,8 @@ def junction_train_update(x, w, idx, rev_ob, rev_t, rev_cnt, *, hyp,
                           mom=None, mom_wi=None, mom_b=None, vel=None,
                           vel_wi=None, vel_b=None, health=None,
                           interpret: bool | None = None,
-                          bm: int | None = None, bn: int | None = None):
+                          bm: int | None = None, bn: int | None = None,
+                          counts=None):
     """The fused BP+UP junction — forward y = act(x @ W_sparse + bias)
     exactly like ``junction_matmul``, but the custom_vjp's cotangents for
     the parameter operands (w / wi / bias and their accumulator slots)
@@ -415,6 +453,9 @@ def junction_train_update(x, w, idx, rev_ob, rev_t, rev_cnt, *, hyp,
     VMEM.  Requires ``w.dtype == x.dtype``:
     the fused path must not cast weights (a cast would re-materialize
     them and its vjp would corrupt the updated-params contract).
+
+    counts: live rows per unit, as in ``junction_matmul``; a unit with no
+    live row still takes its optimizer step (Adam from its moments).
     """
     interpret = _auto_interpret() if interpret is None else interpret
     gated = wi is not None
@@ -448,6 +489,7 @@ def junction_train_update(x, w, idx, rev_ob, rev_t, rev_cnt, *, hyp,
                              "full-precision even for bf16 params")
     single, lead, x3, w5, wi5, b2, E, M, nob, bs, bm, bn = _prep_junction(
         x, w, wi, bias, bm, bn, gated)
+    _check_counts(counts, single, E)
     hyp = bsm.normalize_hyp(hyp, E)
     b = jnp.zeros((E, nob * bs), x.dtype) if b2 is None else b2
     ws = (w5, wi5) if gated else (w5,)
@@ -480,7 +522,7 @@ def junction_train_update(x, w, idx, rev_ob, rev_t, rev_cnt, *, hyp,
     with _kernel_scope("junction_train_update", spec):
         y = _junction_update_core(spec, x3, ws, b, moms, mom_b_t, vels,
                                   vel_b_t, hyp, health, idx, rev_ob, rev_t,
-                                  rev_cnt)
+                                  rev_cnt, counts)
     y = y[:, :M]
     return y.reshape(*lead, nob * bs) if single else y
 

@@ -34,6 +34,11 @@ def _parse():
     ap.add_argument("--sparse", action="store_true",
                     help="enable the paper's pre-defined sparsity on FFNs")
     ap.add_argument("--density", type=float, default=0.25)
+    ap.add_argument("--experts-held", default="",
+                    metavar="N[@FIRST]",
+                    help="MoE: compute only N of the router's experts "
+                         "(from index FIRST, default 0), one chip's share "
+                         "of an expert-parallel layer; nothing is dropped")
     ap.add_argument("--ckpt", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--devices", type=int, default=0)
@@ -118,6 +123,12 @@ def main():
         block = 32 if args.reduce else 128
         cfg = cfg.with_sparsity(SparsityConfig(density=args.density,
                                                block=block, where="ffn"))
+    if args.experts_held:
+        if cfg.moe is None:
+            raise SystemExit(f"--experts-held: {cfg.name} has no experts")
+        n, _, first = args.experts_held.partition("@")
+        cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
+            cfg.moe, held=int(n), first_held=int(first or 0)))
 
     sched = cosine_schedule(args.lr, warmup=20, total=args.steps)
     if args.optim == "sgd":
@@ -171,6 +182,12 @@ def main():
     if result["history"]:
         print(f"[train] first loss {result['history'][0]['loss']:.4f} "
               f"-> last {result['history'][-1]['loss']:.4f}")
+    if result["moe"]:
+        m = result["moe"]
+        pad = m["moe_computed_rows"] - m["moe_routed_rows"]
+        print(f"[train] moe: routed {m['moe_routed_rows']} rows, computed "
+              f"{m['moe_computed_rows']} ({pad} padding), largest expert "
+              f"{m['moe_max_expert_rows']}, dropped {m['moe_dropped_rows']}")
     return result
 
 

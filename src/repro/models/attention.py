@@ -10,6 +10,7 @@ all-reduces — flash-decoding for free (DESIGN.md Sec. 5).
 """
 from __future__ import annotations
 
+import functools
 from typing import Any
 
 import jax
@@ -18,7 +19,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.core import sparse_linear as sl
-from repro.models.layers import norm_apply, norm_init, rope
+from repro.models.layers import mla_rope, norm_apply, norm_init, rope
 
 NEG_INF = -1e30
 Params = dict[str, Any]
@@ -60,8 +61,10 @@ def _split_heads(x, n_heads, hd):
 
 
 def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
-                      chunk: int = 1024, q_pos=None, kv_pos=None):
-    """Online-softmax attention.  q [B,Sq,H,D]; k,v [B,Sk,Hkv,D].
+                      chunk: int = 1024, q_pos=None, kv_pos=None,
+                      scale: float | None = None):
+    """Online-softmax attention.  q [B,Sq,H,D]; k,v [B,Sk,Hkv,D]; scores
+    scaled by ``scale`` (default 1/sqrt(D)).
 
     Scans KV chunks carrying (running max, normalizer, weighted acc) in fp32
     — numerically identical to monolithic softmax, O(Sq*chunk) live memory.
@@ -69,7 +72,7 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
     B, Sq, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     rep = H // Hkv
-    scale = 1.0 / np.sqrt(D)
+    scale = 1.0 / np.sqrt(D) if scale is None else scale
     chunk = min(chunk, Sk)
     if Sk % chunk:  # pad KV to a chunk multiple; padding masked below
         pad = chunk - Sk % chunk
@@ -119,6 +122,28 @@ def chunked_attention(q, k, v, *, causal: bool, window: int = 0,
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H, D)
     return out.astype(q.dtype)
+
+
+def causal_block_attention(q, k, v, *, chunk: int, positions, scale=None):
+    """Causal self-attention of a sequence over its own keys (q and k at
+    the same ``positions``): the queries in blocks of ``chunk``, each
+    against the keys up to its block's end, so the blocks past the
+    diagonal are neither computed nor stored; each block is
+    checkpointed, so a backward keeps one block's softmax state live
+    instead of every kv chunk's accumulator over the whole sequence."""
+    S = q.shape[1]
+    if S <= chunk or S % chunk:
+        return chunked_attention(q, k, v, causal=True, chunk=chunk,
+                                 q_pos=positions, kv_pos=positions,
+                                 scale=scale)
+    block = jax.checkpoint(functools.partial(
+        chunked_attention, causal=True, chunk=chunk, scale=scale))
+    outs = []
+    for i in range(S // chunk):
+        lo, hi = i * chunk, (i + 1) * chunk
+        outs.append(block(q[:, lo:hi], k[:, :hi], v[:, :hi],
+                          q_pos=positions[lo:hi], kv_pos=positions[:hi]))
+    return jnp.concatenate(outs, axis=1)
 
 
 def decode_attention(q, k_cache, v_cache, pos, *, window: int = 0):
@@ -310,19 +335,23 @@ def gqa_prefill_paged(p: Params, x, cfg: ArchConfig, cache: dict, positions,
 # =============================================================== MLA paths
 def mla_forward(p: Params, x, cfg: ArchConfig, *, positions):
     """DeepSeek-V2 multi-head latent attention, expanded form (train/prefill).
+    Rotary frequencies and the score scale come from ``mla_rope`` (YaRN
+    when the config scales its rope).
 
     Returns (out, (latent, k_rope)) for the compressed cache."""
     B, S, _ = x.shape
     m, H = cfg.mla, cfg.n_heads
     nope, rd, vd, lora = (m.qk_nope_head_dim, m.qk_rope_head_dim,
                           m.v_head_dim, m.kv_lora_rank)
+    inv_freq, scale = mla_rope(cfg)
     q = _split_heads(sl.apply(p["wq"], x, engine=cfg.engine), H, nope + rd)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    q_rope = rope(q_rope, positions, cfg.rope_theta, inv_freq=inv_freq)
 
     a = sl.apply_dense(p["wkv_a"], x)                       # [B,S,lora+rd]
     latent = norm_apply(p["kv_norm"], a[..., :lora], "rmsnorm", cfg.norm_eps)
-    k_rope = rope(a[..., lora:][:, :, None, :], positions, cfg.rope_theta)  # [B,S,1,rd]
+    k_rope = rope(a[..., lora:][:, :, None, :], positions, cfg.rope_theta,
+                  inv_freq=inv_freq)                        # [B,S,1,rd]
 
     kvb = sl.apply_dense(p["wkv_b"], latent)                # [B,S,H*(nope+vd)]
     kvb = kvb.reshape(B, S, H, nope + vd)
@@ -331,8 +360,8 @@ def mla_forward(p: Params, x, cfg: ArchConfig, *, positions):
     qf = jnp.concatenate([q_nope, q_rope], -1)
     # pad v to qk dim for the shared chunked kernel, slice after
     v_pad = jnp.pad(v, ((0, 0), (0, 0), (0, 0), (0, nope + rd - vd)))
-    out = chunked_attention(qf, k, v_pad, causal=True, chunk=cfg.attn_chunk,
-                            q_pos=positions, kv_pos=positions)[..., :vd]
+    out = causal_block_attention(qf, k, v_pad, chunk=cfg.attn_chunk,
+                                 positions=positions, scale=scale)[..., :vd]
     out = sl.apply(p["wo"], out.reshape(B, S, H * vd), engine=cfg.engine)
     return out, (latent, k_rope[:, :, 0, :])
 
@@ -344,14 +373,16 @@ def mla_decode(p: Params, x, cfg: ArchConfig, cache: dict, pos):
     m, H = cfg.mla, cfg.n_heads
     nope, rd, vd, lora = (m.qk_nope_head_dim, m.qk_rope_head_dim,
                           m.v_head_dim, m.kv_lora_rank)
+    inv_freq, scale = mla_rope(cfg)
     q = _split_heads(sl.apply(p["wq"], x, engine=cfg.engine), H, nope + rd)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
     pos_arr = jnp.full((1,), pos)
-    q_rope = rope(q_rope, pos_arr, cfg.rope_theta)
+    q_rope = rope(q_rope, pos_arr, cfg.rope_theta, inv_freq=inv_freq)
 
     a = sl.apply_dense(p["wkv_a"], x)
     lat_new = norm_apply(p["kv_norm"], a[..., :lora], "rmsnorm", cfg.norm_eps)
-    kr_new = rope(a[..., lora:][:, :, None, :], pos_arr, cfg.rope_theta)[:, :, 0, :]
+    kr_new = rope(a[..., lora:][:, :, None, :], pos_arr, cfg.rope_theta,
+                  inv_freq=inv_freq)[:, :, 0, :]
     lat = jax.lax.dynamic_update_slice_in_dim(
         cache["latent"], lat_new.astype(cache["latent"].dtype), pos, 1)
     kr = jax.lax.dynamic_update_slice_in_dim(
@@ -363,7 +394,7 @@ def mla_decode(p: Params, x, cfg: ArchConfig, cache: dict, pos):
     q_abs = jnp.einsum("bqhn,lhn->bqhl", q_nope, w_uk)
     s = (jnp.einsum("bqhl,bsl->bhqs", q_abs, lat, preferred_element_type=jnp.float32)
          + jnp.einsum("bqhr,bsr->bhqs", q_rope, kr, preferred_element_type=jnp.float32))
-    s = s / np.sqrt(nope + rd)
+    s = s * scale
     valid = jnp.arange(lat.shape[1]) <= pos
     s = jnp.where(valid[None, None, None, :], s, NEG_INF)
     pr = jax.nn.softmax(s, axis=-1).astype(x.dtype)

@@ -6,6 +6,8 @@ Compute runs in ``cfg.dtype`` (bf16), parameters live in ``param_dtype``
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -46,9 +48,10 @@ def norm_apply(p, x, kind: str, eps: float):
 
 # ------------------------------------------------------------------ rotary
 def rope(x: jax.Array, positions: jax.Array, theta: float,
-         partial: float = 1.0) -> jax.Array:
+         partial: float = 1.0, inv_freq=None) -> jax.Array:
     """x [..., S, H, D]; positions [..., S] (broadcastable).  Rotates the
-    first ``partial * D`` dims (stablelm-style partial rotary)."""
+    first ``partial * D`` dims (stablelm-style partial rotary), pair
+    (i, i + rot/2) at frequency ``inv_freq[i]`` (default theta^(-2i/rot))."""
     d = x.shape[-1]
     rot = int(d * partial)
     rot -= rot % 2
@@ -56,13 +59,59 @@ def rope(x: jax.Array, positions: jax.Array, theta: float,
         return x
     xr, xp = x[..., :rot], x[..., rot:]
     half = rot // 2
-    freqs = jnp.exp(-np.log(theta) * jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = jnp.exp(-np.log(theta) * jnp.arange(half, dtype=jnp.float32)
+                        / half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     ang = positions[..., None].astype(jnp.float32) * freqs     # [..., S, half]
     cos = jnp.cos(ang)[..., None, :].astype(x.dtype)           # [..., S, 1, half]
     sin = jnp.sin(ang)[..., None, :].astype(x.dtype)
     x1, x2 = xr[..., :half], xr[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return jnp.concatenate([out, xp], axis=-1) if rot < d else out
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, sc) -> np.ndarray:
+    """YaRN rotary frequencies (HF ``DeepseekV2YarnRotaryEmbedding``):
+    theta^(-2i/dim) below the correction range, divided by ``factor``
+    above it, a linear ramp between.  The range runs from
+    floor(d(beta_fast)) to ceil(d(beta_slow)), where d(r) =
+    dim ln(L / (2 pi r)) / (2 ln theta) and L the original context."""
+    def corr(r):
+        return (dim * math.log(sc.original_max_position / (r * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(corr(sc.beta_fast)), 0)
+    hi = min(math.ceil(corr(sc.beta_slow)), dim - 1)
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3), 0, 1)
+    keep = 1.0 - ramp
+    return (extra / sc.factor * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def mla_rope(cfg: ArchConfig) -> tuple:
+    """(inv_freq or None, softmax scale) of latent attention: plain RoPE
+    and 1/sqrt(qk dim), or YaRN's frequencies and the score scale
+    mscale(factor, mscale_all_dim)^2 / sqrt(qk dim).  The cos/sin factor
+    mscale(mscale) / mscale(mscale_all_dim) must be 1 (DeepSeek-V2 sets
+    both to the same value), so it is not applied."""
+    m = cfg.mla
+    scale = 1.0 / math.sqrt(m.qk_nope_head_dim + m.qk_rope_head_dim)
+    sc = cfg.rope_scaling
+    if sc is None:
+        return None, scale
+    if yarn_mscale(sc.factor, sc.mscale) != yarn_mscale(sc.factor,
+                                                        sc.mscale_all_dim):
+        raise ValueError("YaRN with mscale != mscale_all_dim scales cos/sin;"
+                         " not supported")
+    inv = yarn_inv_freq(m.qk_rope_head_dim, cfg.rope_theta, sc)
+    if sc.mscale_all_dim:
+        scale *= yarn_mscale(sc.factor, sc.mscale_all_dim) ** 2
+    return inv, scale
 
 
 def sinusoidal_pos(seq: int, d: int, dtype) -> jax.Array:

@@ -125,11 +125,18 @@ def _attn_mlp_block(lp, x, cfg: ArchConfig, positions, cache=None, pos=None,
             new_cache = {"k": kv[0], "v": kv[1]}
     x = x + a
     h = norm_apply(lp["norm2"], x, cfg.norm, cfg.norm_eps)
-    if "moe" in lp:
-        m, aux = moe_mod.moe_apply(lp["moe"], h, cfg)
-    else:
-        m, aux = mlp_apply(lp["mlp"], h, cfg), 0.0
+    m, aux = _ffn(lp, h, cfg)
     return x + m, new_cache, aux
+
+
+def _ffn(lp, h, cfg: ArchConfig):
+    """The block's FFN: (out, aux).  An expert layer's aux is its balance
+    loss with its counters (``moe.zero_stats``'s keys); a dense FFN's is
+    0.0."""
+    if "moe" in lp:
+        m, aux, stats = moe_mod.moe_apply(lp["moe"], h, cfg)
+        return m, dict(stats, aux=aux)
+    return mlp_apply(lp["mlp"], h, cfg), 0.0
 
 
 def _enc_block(lp, x, cfg: ArchConfig):
@@ -181,15 +188,21 @@ def _maybe_ckpt(fn, cfg):
 
 
 def _scan_layers(fn, x, layer_params, cfg, with_cache=None):
-    """scan fn over stacked layers; fn(x, lp, cache_i) -> (x, new_cache_i, aux)."""
+    """scan fn over stacked layers; fn(x, lp, cache_i) -> (x, new_cache_i, aux).
+    aux sums over layers: a scalar, or an MoE model's balance loss and
+    counters (``moe.add_stats``)."""
+    moe = cfg.family == "moe"
+    aux0 = moe_mod.zero_stats() if moe else 0.0
+
     def body(carry, inp):
         x, aux_sum = carry
         lp, cache_i = inp
         x, new_cache, aux = fn(x, lp, cache_i)
         x = hints.constrain_tokens3d(x, cfg)   # store carry seq-sharded
-        return (x, aux_sum + aux), new_cache
+        aux_sum = moe_mod.add_stats(aux_sum, aux) if moe else aux_sum + aux
+        return (x, aux_sum), new_cache
     body = _maybe_ckpt(body, cfg)
-    (x, aux), caches = jax.lax.scan(body, (x, 0.0), (layer_params, with_cache))
+    (x, aux), caches = jax.lax.scan(body, (x, aux0), (layer_params, with_cache))
     return x, caches, aux
 
 
@@ -285,10 +298,14 @@ def forward(cfg: ArchConfig, params: Params, batch, *, return_cache=False,
             x, cache, aux = _attn_mlp_block(lp, x, cfg, positions)
             return x, (cache if return_cache else 0), aux
         if fam == "moe" and cfg.moe.first_dense_layers:
+            # rematerialised like the scanned layers, so a leading dense
+            # layer keeps no activations of its own for the backward
+            dense = _maybe_ckpt(lambda lp, x: _attn_mlp_block(
+                lp, x, cfg, positions)[:2], cfg)
             dcaches = []
             for i in range(cfg.moe.first_dense_layers):
                 lp = jax.tree.map(lambda t: t[i], params["dense_layers"])
-                x, dc, _ = _attn_mlp_block(lp, x, cfg, positions)
+                x, dc = dense(lp, x)
                 dcaches.append(dc)
         x, caches, aux = _scan_layers(fn, x, params["layers"], cfg)
         if fam == "moe" and cfg.moe.first_dense_layers and return_cache:
@@ -484,10 +501,7 @@ def _attn_block_paged(lp, x, cfg: ArchConfig, cache_i, positions, page_table,
                                               positions, page_table)
     x = x + a
     h = norm_apply(lp["norm2"], x, cfg.norm, cfg.norm_eps)
-    if "moe" in lp:
-        m, aux = moe_mod.moe_apply(lp["moe"], h, cfg)
-    else:
-        m, aux = mlp_apply(lp["mlp"], h, cfg), 0.0
+    m, aux = _ffn(lp, h, cfg)
     return x + m, new_cache, aux
 
 
@@ -625,7 +639,17 @@ def softmax_xent(logits, labels):
     return lse - ll
 
 
+def _aux_terms(aux):
+    """(balance loss, counters) of forward's aux: an MoE model's carries
+    the expert layers' counters (``moe.STATS``) beside the loss."""
+    if isinstance(aux, dict):
+        return aux["aux"], {k: aux[k] for k in moe_mod.STATS}
+    return aux, {}
+
+
 def loss_fn(cfg: ArchConfig, params: Params, batch):
+    """(loss, metrics): mean next-token cross-entropy plus the balance
+    loss; metrics holds both, and an MoE model's counters."""
     tokens = batch["tokens"]
     labels = tokens[:, 1:]
     T = labels.shape[1]
@@ -637,14 +661,16 @@ def loss_fn(cfg: ArchConfig, params: Params, batch):
         chunk = c if c > 1 else 0
     if not chunk:
         logits, _, (aux, off) = forward(cfg, params, batch)
+        aux, counters = _aux_terms(aux)
         lg = logits[:, off:off + T] if off else logits[:, :-1]
         ce = jnp.mean(softmax_xent(lg, labels))
-        return ce + aux, {"ce": ce, "aux": aux}
+        return ce + aux, {"ce": ce, "aux": aux, **counters}
 
     # chunked CE: run the trunk once, unembed + CE per sequence chunk under
     # checkpoint so [tokens, vocab] logits never fully materialize (§Perf C2)
     hidden, _, (aux, off) = forward(cfg, params, batch, last_only=False,
                                     return_hidden=True)
+    aux, counters = _aux_terms(aux)
     hs = hidden[:, off:off + T] if off else hidden[:, :-1]
     c = chunk
     nc = T // c
@@ -660,4 +686,4 @@ def loss_fn(cfg: ArchConfig, params: Params, batch):
 
     total, _ = jax.lax.scan(chunk_ce, jnp.zeros((), jnp.float32), (hs, lb))
     ce = total / (B * T)
-    return ce + aux, {"ce": ce, "aux": aux}
+    return ce + aux, {"ce": ce, "aux": aux, **counters}

@@ -1,23 +1,34 @@
-"""Mixture-of-Experts with GShard-style capacity dispatch.
+"""Mixture-of-Experts: a dropless expert layer that knows its share.
 
-Tokens are processed in groups of ``group_size``; dispatch/combine tensors
-are [G, g, E, C] einsums, so with experts sharded over the "model" axis
-(EP) and groups over "data" the per-device footprint stays bounded and the
-expert matmuls are dense MXU work.  Dropped tokens (over capacity) fall
-through on the residual path — standard GShard semantics.
+The router scores every token over all ``num_experts`` (in float32, as
+DeepSeek-V2 publishes it) and keeps ``top_k``.  This device computes the
+experts it holds (``MoEConfig.held`` from ``first_held``; all of them by
+default), the share of one chip in an expert-parallel deployment; what
+the other experts add is left to the chips that hold them.  No token is
+dropped: every token-slot routed to a held expert is placed, by index, in
+a per-expert buffer ``[held, T_buf, d]`` (its position is its rank among
+that expert's slots, token-major), the experts run on the buffer, and the
+results are gathered back by the same index and weighted.  An expert can
+receive at most one slot per token, so ``T_buf`` (the token count, rounded
+up to the row tile) is a bound routing cannot exceed; ``counts[e]`` says
+how many rows of expert e's buffer are live.
 
 When the paper's pre-defined sparsity applies to the expert FFNs, one
 block pattern (same junction shape) is shared by all experts with
-per-expert weights — and the expert matmuls run through the unified
-edge-bundle engine entry point ``kernels/ops.junction_matmul`` (the same
-custom_vjp the dense-model junctions use, here with 5-D weights
-[E, nob, kb, bs, bs] and grid (E, M/bm, nob/bn); ``wi=`` fuses the
-SwiGLU gate into one pass) when ``ArchConfig.engine`` resolves to
-"pallas".  The vmapped gather+einsum loop (``_expert_apply``) remains
-the reference path and the path the dry-run FLOP accounting sees
-(launch/dryrun.py pins engine="jnp").
+per-expert weights, and the expert matmuls run through the unified
+junction entry point ``kernels/ops.junction_matmul`` (weights [E, nob,
+kb, bs, bs], ``wi=`` fusing the SwiGLU gate) with the live counts: row
+tiles past an expert's count are neither fetched nor computed
+(``expert_junction_*`` kernels).  The vmapped gather+einsum loop
+(``_expert_apply``) is the jnp engine's path; it computes every row of
+the buffer.
 
-Aux load-balance loss follows Switch/GShard: E * sum_e f_e * p_e.
+The balance loss is Switch/GShard's E * sum_e f_e * p_e over the batch,
+or DeepSeek-V2's sequence-wise form (``aux_loss="sequence"``, seq_aux):
+the same per sequence, averaged over sequences.  ``moe_apply`` also
+returns the layer's counters (``STATS``): token-slots routed to held
+experts, rows the computed tiles cover, the largest expert's rows, and
+slots dropped (0 by construction).
 """
 from __future__ import annotations
 
@@ -33,16 +44,30 @@ from repro.models.layers import mlp_apply, mlp_init
 
 Params = dict[str, Any]
 
+# the expert kernels' row tile: a multiple of 16 sublanes whose gated
+# backward row blocks (dh, g, u at the expert width) fit VMEM
+ROW_TILE = 256
+STATS = ("moe_routed_rows", "moe_computed_rows", "moe_max_expert_rows",
+         "moe_dropped_rows")
 
-def moe_dispatch_dims(mo, T: int) -> tuple[int, int, int]:
-    """(g, G, C) for T tokens: dispatch group size, group count, and the
-    per-expert capacity (rounded up to a multiple of 4).  Single source of
-    the capacity formula — benchmarks derive their metadata from it."""
-    g = min(mo.group_size, T)
-    G = T // g
-    C = int(np.ceil(g * mo.top_k * mo.capacity_factor / mo.num_experts))
-    C = max(4, -(-C // 4) * 4)
-    return g, G, C
+
+def expert_rows(T: int) -> tuple[int, int]:
+    """(row tile, buffer rows per expert) for T tokens."""
+    bm = min(ROW_TILE, -(-T // 16) * 16)
+    return bm, -(-T // bm) * bm
+
+
+def zero_stats() -> dict:
+    """The layer-scan carry of an MoE model: the balance loss and the
+    counters, summed over layers (the largest expert's rows: the max)."""
+    out = {"aux": jnp.zeros((), jnp.float32)}
+    out.update({k: jnp.zeros((), jnp.int32) for k in STATS})
+    return out
+
+
+def add_stats(a: dict, b: dict) -> dict:
+    return {k: (jnp.maximum(a[k], b[k]) if k == "moe_max_expert_rows"
+                else a[k] + b[k]) for k in a}
 
 
 def _expert_sparse_ok(cfg: ArchConfig) -> bool:
@@ -55,6 +80,7 @@ def _expert_sparse_ok(cfg: ArchConfig) -> bool:
 def moe_init(key, cfg: ArchConfig, dtype=jnp.float32, seed: int = 0) -> Params:
     mo, d = cfg.moe, cfg.d_model
     E, F = mo.num_experts, mo.d_expert
+    Eh = mo.held_          # the router scores all E; Eh experts live here
     ks = jax.random.split(key, 7)
     scale_in = float(1.0 / np.sqrt(d))
     scale_out = float(1.0 / np.sqrt(F))
@@ -68,8 +94,8 @@ def moe_init(key, cfg: ArchConfig, dtype=jnp.float32, seed: int = 0) -> Params:
         pat_out = make_block_pattern(F, d, sp.density, sp.block, seed=sp.seed + 1)
         s_in = float(np.sqrt(2.0 / ((pat_in.fan_in_blocks + pat_in.fan_out_blocks) * sp.block)))
         s_out = float(np.sqrt(2.0 / ((pat_out.fan_in_blocks + pat_out.fan_out_blocks) * sp.block)))
-        shp_in = (E, pat_in.n_out_blocks, pat_in.fan_in_blocks, sp.block, sp.block)
-        shp_out = (E, pat_out.n_out_blocks, pat_out.fan_in_blocks, sp.block, sp.block)
+        shp_in = (Eh, pat_in.n_out_blocks, pat_in.fan_in_blocks, sp.block, sp.block)
+        shp_out = (Eh, pat_out.n_out_blocks, pat_out.fan_in_blocks, sp.block, sp.block)
         p.update({
             "wi": jax.random.normal(ks[1], shp_in, dtype) * s_in,
             "wg": jax.random.normal(ks[2], shp_in, dtype) * s_in,
@@ -87,9 +113,9 @@ def moe_init(key, cfg: ArchConfig, dtype=jnp.float32, seed: int = 0) -> Params:
         })
     else:
         p.update({
-            "wi": jax.random.normal(ks[1], (E, d, F), dtype) * scale_in,
-            "wg": jax.random.normal(ks[2], (E, d, F), dtype) * scale_in,
-            "wo": jax.random.normal(ks[3], (E, F, d), dtype) * scale_out,
+            "wi": jax.random.normal(ks[1], (Eh, d, F), dtype) * scale_in,
+            "wg": jax.random.normal(ks[2], (Eh, d, F), dtype) * scale_in,
+            "wo": jax.random.normal(ks[3], (Eh, F, d), dtype) * scale_out,
         })
     if mo.num_shared:
         # d_shared is the *combined* hidden width of the always-on experts
@@ -99,38 +125,36 @@ def moe_init(key, cfg: ArchConfig, dtype=jnp.float32, seed: int = 0) -> Params:
 
 def _expert_apply(w, idx, x):
     """Batched block-sparse expert matmul (jnp reference path):
-    x [G,E,C,din] -> [G,E,C,dout].  Accumulates over fan-in slots to avoid
+    x [E,M,din] -> [E,M,dout].  Accumulates over fan-in slots to avoid
     the kb-times gather blow-up.  This is also the path the dry-run FLOP
     accounting sees (density-scaled einsums)."""
     E, nob, kb, bs, _ = w.shape
-    G, _, C, din = x.shape
-    xb = x.reshape(G, E, C, din // bs, bs)
+    _, M, din = x.shape
+    xb = x.reshape(E, M, din // bs, bs)
     wc = w.astype(x.dtype)
     y = None
     for k in range(kb):
-        xk = jnp.take(xb, idx[:, k], axis=3)          # [G,E,C,nob,bs]
-        # slot k of every output block: wc[:, :, k] [E, nob, bs, bs] — the
-        # seed sliced axis 1 (the *output-block* axis), which only shaped
-        # up when nob == kb and silently transposed the weight layout
-        part = jnp.einsum("GECob,Eobc->GECoc", xk, wc[:, :, k])
+        xk = jnp.take(xb, idx[:, k], axis=2)          # [E,M,nob,bs]
+        # slot k of every output block: wc[:, :, k] [E, nob, bs, bs]
+        part = jnp.einsum("EMob,Eobc->EMoc", xk, wc[:, :, k])
         y = part if y is None else y + part
-    return y.reshape(G, E, C, nob * bs)
+    return y.reshape(E, M, nob * bs)
 
 
-def _expert_ffn_pallas(p: Params, xd, E: int):
+def _expert_ffn_pallas(p: Params, xe, counts, bm: int):
     """Expert FFN stack through the unified junction engine:
-    xd [G,E,C,d] -> [G,E,C,d].  Both junctions go through the same
-    ``junction_matmul`` custom_vjp the dense-model layers use — the gate
-    (silu(x@wg) * (x@wi)) as ONE fused pass via ``wi=``, wo as the plain
-    E-batched configuration.  When the fused-update context rides in the
-    params dict (train/steps.py injection), both junctions run through
-    ``junction_train_update`` instead: the per-expert weight gradients
-    are consumed by the in-kernel optimizer epilogue (SGD+momentum, or
-    Adam when the vel_* slots ride along) and the updated wg/wi/wo come
-    back as their cotangents."""
+    xe [E,M,d] -> [E,M,d], unit e live in its first ``counts[e]`` rows.
+    Both junctions go through the same ``junction_matmul`` custom_vjp the
+    dense-model layers use — the gate (silu(x@wg) * (x@wi)) as ONE fused
+    pass via ``wi=``, wo as the plain E-batched configuration — with the
+    counts, so dead row tiles cost nothing.  When the fused-update
+    context rides in the params dict (train/steps.py injection), both
+    junctions run through ``junction_train_update`` instead: the
+    per-expert weight gradients are consumed by the in-kernel optimizer
+    epilogue (SGD+momentum, or Adam when the vel_* slots ride along) and
+    the updated wg/wi/wo come back as their cotangents; an expert with
+    no rows still takes its step."""
     from repro.kernels import ops  # local import: kernels optional at runtime
-    G, _, C, D = xd.shape
-    xe = jnp.moveaxis(xd, 1, 0).reshape(E, G * C, D)
     if "wgq" in p:   # quantized experts (core/quantize.py): inference-only
         if sl.UPDATE_HYP_LEAF in p:
             raise ValueError("quantized expert FFN inside a fused train "
@@ -140,11 +164,10 @@ def _expert_ffn_pallas(p: Params, xd, E: int):
             p["rev_in_ob"], p["rev_in_t"], p["rev_in_cnt"], wi=p["wiq"],
             w_scale=p["wg_scale"], wi_scale=p["wi_scale"],
             x_scale=p.get("x_scale_in"))
-        ye = ops.junction_matmul(
+        return ops.junction_matmul(
             h, p["woq"], p["idx_out"],
             p["rev_out_ob"], p["rev_out_t"], p["rev_out_cnt"],
             w_scale=p["wo_scale"], x_scale=p.get("x_scale_out"))
-        return jnp.moveaxis(ye.reshape(E, G, C, -1), 0, 1)
     if sl.UPDATE_HYP_LEAF in p:
         hyp = p[sl.UPDATE_HYP_LEAF]
         h = ops.junction_train_update(
@@ -152,80 +175,139 @@ def _expert_ffn_pallas(p: Params, xd, E: int):
             p["rev_in_ob"], p["rev_in_t"], p["rev_in_cnt"], wi=p["wi"],
             hyp=hyp, mom=p.get("mom_wg"), mom_wi=p.get("mom_wi"),
             vel=p.get("vel_wg"), vel_wi=p.get("vel_wi"),
-            health=p.get("upd_health_in"))
-        ye = ops.junction_train_update(
+            health=p.get("upd_health_in"), bm=bm, counts=counts)
+        return ops.junction_train_update(
             h, p["wo"], p["idx_out"],
             p["rev_out_ob"], p["rev_out_t"], p["rev_out_cnt"],
             hyp=hyp, mom=p.get("mom_wo"), vel=p.get("vel_wo"),
-            health=p.get("upd_health_out"))
-        return jnp.moveaxis(ye.reshape(E, G, C, -1), 0, 1)
+            health=p.get("upd_health_out"), bm=bm, counts=counts)
     h = ops.junction_matmul(
         xe, p["wg"], p["idx_in"],
-        p["rev_in_ob"], p["rev_in_t"], p["rev_in_cnt"], wi=p["wi"])
-    ye = ops.junction_matmul(
+        p["rev_in_ob"], p["rev_in_t"], p["rev_in_cnt"], wi=p["wi"], bm=bm,
+        counts=counts)
+    return ops.junction_matmul(
         h, p["wo"], p["idx_out"],
-        p["rev_out_ob"], p["rev_out_t"], p["rev_out_cnt"])
-    return jnp.moveaxis(ye.reshape(E, G, C, -1), 0, 1)
+        p["rev_out_ob"], p["rev_out_t"], p["rev_out_cnt"], bm=bm,
+        counts=counts)
+
+
+def route(p: Params, x, cfg: ArchConfig):
+    """Router of every expert in float32: x [T,d] -> (probs [T,E],
+    weights [T,K], experts [T,K]) — greedy top-k of the softmax,
+    renormalised over the k when ``norm_topk_prob``, times the routed
+    scale."""
+    mo = cfg.moe
+    logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, mo.top_k)
+    if mo.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return probs, top_p * mo.routed_scale, top_e
+
+
+def balance_loss(probs, top_e, cfg: ArchConfig, batch: int):
+    """Switch/GShard's E * sum_e f_e * p_e, f_e the share of routed
+    slots and p_e the mean probability of expert e, over the batch or
+    (``aux_loss="sequence"``) per sequence and averaged, times the
+    weight."""
+    mo = cfg.moe
+    E, K = mo.num_experts, mo.top_k
+    groups = batch if mo.aux_loss == "sequence" else 1
+    pr = probs.reshape(groups, -1, E)
+    hits = jax.nn.one_hot(top_e, E, dtype=jnp.float32).sum(axis=1)
+    f = jnp.mean(hits.reshape(groups, -1, E), axis=1) / K
+    per = E * jnp.sum(f * jnp.mean(pr, axis=1), axis=-1)
+    return jnp.mean(per) * mo.aux_loss_weight
+
+
+def dispatch_index(top_e, cfg: ArchConfig, T_buf: int):
+    """Where each token-slot goes.  top_e [T,K] -> (dest [T*K]: its row
+    in the flat buffer [held*T_buf], or held*T_buf for a slot routed to
+    an expert held elsewhere; counts [held] int32).  A slot's row within
+    its expert is its rank among that expert's slots, token-major."""
+    mo = cfg.moe
+    Eh = mo.held_
+    local = top_e.reshape(-1) - mo.first_held
+    here = (local >= 0) & (local < Eh)
+    bucket = jnp.where(here, local, Eh).astype(jnp.int32)
+    order = jnp.argsort(bucket, stable=True)
+    sizes = jnp.bincount(bucket, length=Eh + 1)
+    starts = jnp.cumsum(sizes) - sizes
+    rank = jnp.zeros_like(bucket).at[order].set(
+        jnp.arange(bucket.shape[0], dtype=jnp.int32) - starts[bucket[order]])
+    dest = jnp.where(here, bucket * T_buf + rank, Eh * T_buf)
+    return dest, sizes[:Eh].astype(jnp.int32)
 
 
 def moe_apply(p: Params, x, cfg: ArchConfig):
-    """x [B,S,D] -> (y, aux_loss).  The expert matmuls run through the
+    """x [B,S,D] -> (y, aux, stats).  The expert matmuls run through the
     engine ``ArchConfig.engine`` resolves to: "pallas" selects the
-    expert-batched fused kernels, "jnp" the reference gather+einsum loop."""
+    expert-batched fused kernels with live counts, "jnp" the reference
+    gather+einsum loop over the whole buffer."""
     mo = cfg.moe
     B, S, D = x.shape
-    E, K = mo.num_experts, mo.top_k
+    K, Eh = mo.top_k, mo.held_
     T = B * S
-    g, G, C = moe_dispatch_dims(mo, T)
-    assert T % g == 0, f"tokens {T} not divisible by moe group {g}"
+    bm, T_buf = expert_rows(T)
+    xt = x.reshape(T, D)
+    probs, top_w, top_e = route(p, xt, cfg)
+    aux = balance_loss(probs, top_e, cfg, B)
 
-    xt = x.reshape(G, g, D)
-    logits = jnp.einsum("Ggd,de->Gge", xt, p["router"].astype(x.dtype))
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)      # [G,g,E]
-    top_p, top_e = jax.lax.top_k(probs, K)                           # [G,g,K]
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)           # renorm
+    dest, counts = dispatch_index(top_e, cfg, T_buf)
+    tok = jnp.repeat(jnp.arange(T, dtype=jnp.int32), K)
+    src = jnp.full((Eh * T_buf,), T, jnp.int32).at[dest].set(tok, mode="drop")
+    # row T of the padded tokens is zeros: the buffer's dead rows read it
+    xpad = jnp.concatenate([xt, jnp.zeros((1, D), xt.dtype)])
+    xd = xpad.at[src].get(mode="promise_in_bounds")
+    xd = xd.reshape(Eh, T_buf, D)
 
-    # position-in-expert via cumsum over tokens (slot k-major then token)
-    mask = jax.nn.one_hot(top_e, E, dtype=jnp.float32)               # [G,g,K,E]
-    mask_flat = mask.transpose(0, 2, 1, 3).reshape(G, K * g, E)      # k-major
-    pos = jnp.cumsum(mask_flat, axis=1) - 1.0                        # [G,Kg,E]
-    keep = (pos < C) * mask_flat
-    pos = pos.reshape(G, K, g, E).transpose(0, 2, 1, 3)              # [G,g,K,E]
-    keep = keep.reshape(G, K, g, E).transpose(0, 2, 1, 3)
-
-    # aux load-balance loss (fraction routed vs mean prob), Switch-style
-    f_e = jnp.mean(mask[..., 0, :] if K == 1 else jnp.sum(mask, axis=2), axis=(0, 1)) / K
-    p_e = jnp.mean(probs, axis=(0, 1))
-    aux = E * jnp.sum(f_e * p_e) * mo.aux_loss_weight
-
-    pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), C, dtype=jnp.float32)  # [G,g,K,E,C]
-    dispatch = jnp.einsum("GgKE,GgKEC->GgEC", keep, pos_oh)
-    combine = jnp.einsum("GgK,GgKE,GgKEC->GgEC", top_p, keep, pos_oh)
-
-    xd = jnp.einsum("GgEC,Ggd->GECd", dispatch.astype(x.dtype), xt)
+    pallas = sl.resolve_engine(cfg.engine) == "pallas"
     if "idx_in" in p:   # pre-defined-sparse experts (the paper's technique)
-        if sl.resolve_engine(cfg.engine) == "pallas":
-            ye = _expert_ffn_pallas(p, xd, E)
+        if pallas:
+            ye = _expert_ffn_pallas(p, xd, counts, bm)
         elif "wgq" in p:   # quantized experts, jnp twin of the int8 kernels
             from repro.core import quantize as qz
             gq = qz.expert_apply_int8(p["wgq"], p["wg_scale"], p["idx_in"],
-                                      xd, p.get("x_scale_in"))
+                                      xd[None], p.get("x_scale_in"))
             uq = qz.expert_apply_int8(p["wiq"], p["wi_scale"], p["idx_in"],
-                                      xd, p.get("x_scale_in"))
+                                      xd[None], p.get("x_scale_in"))
             h = (jax.nn.silu(gq) * uq).astype(x.dtype)
             ye = qz.expert_apply_int8(p["woq"], p["wo_scale"], p["idx_out"],
-                                      h, p.get("x_scale_out")).astype(x.dtype)
+                                      h, p.get("x_scale_out"))
+            ye = ye[0].astype(x.dtype)
         else:
             h = (jax.nn.silu(_expert_apply(p["wg"], p["idx_in"], xd))
                  * _expert_apply(p["wi"], p["idx_in"], xd))
             ye = _expert_apply(p["wo"], p["idx_out"], h)
     else:
-        h = (jax.nn.silu(jnp.einsum("GECd,Edf->GECf", xd, p["wg"].astype(x.dtype)))
-             * jnp.einsum("GECd,Edf->GECf", xd, p["wi"].astype(x.dtype)))
-        ye = jnp.einsum("GECf,Efd->GECd", h, p["wo"].astype(x.dtype))
-    y = jnp.einsum("GgEC,GECd->Ggd", combine.astype(x.dtype), ye)
+        h = (jax.nn.silu(jnp.einsum("EMd,Edf->EMf", xd, p["wg"].astype(x.dtype)))
+             * jnp.einsum("EMd,Edf->EMf", xd, p["wi"].astype(x.dtype)))
+        ye = jnp.einsum("EMf,Efd->EMd", h, p["wo"].astype(x.dtype))
+    # one slot rank at a time, so no [T, K, d] tensor (or its cotangent)
+    # is ever live; rows past an expert's count are never read, and a
+    # slot held elsewhere points past the buffer and gathers zeros
+    ye = ye.reshape(Eh * T_buf, D)
+    dest_k = dest.reshape(T, K)
+    y = None
+    for k in range(K):
+        got = jnp.take(ye, dest_k[:, k], axis=0, mode="fill", fill_value=0)
+        part = got.astype(jnp.float32) * top_w[:, k:k + 1]
+        y = part if y is None else y + part
+    y = y.astype(x.dtype)
     y = y.reshape(B, S, D)
-
     if "shared" in p:
         y = y + mlp_apply(p["shared"], x, cfg)
-    return y, aux
+
+    routed = jnp.sum(counts)
+    if pallas and "idx_in" in p and "wgq" not in p:
+        computed = jnp.sum(-(-counts // bm) * bm)
+    else:
+        computed = jnp.int32(Eh * T_buf)
+    stats = {"moe_routed_rows": routed,
+             "moe_computed_rows": computed.astype(jnp.int32),
+             "moe_max_expert_rows": jnp.max(counts),
+             "moe_dropped_rows": (jnp.sum((dest < Eh * T_buf)
+                                          .astype(jnp.int32))
+                                  - jnp.sum((src < T).astype(jnp.int32)))}
+    return y, aux, stats

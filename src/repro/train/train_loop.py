@@ -47,6 +47,7 @@ from typing import Any, Callable, Optional
 import jax
 import numpy as np
 
+from repro.models import moe as moe_mod
 from repro.obs import telemetry as obs
 from repro.train import checkpoint as ckpt_mod
 
@@ -112,6 +113,18 @@ def _batch_tokens(batch) -> int:
     return int(np.asarray(leaves[0]).shape[0]) if leaves else 0
 
 
+def _moe_counters(metrics) -> dict:
+    """An MoE step's expert-layer counters (``models/moe.STATS``: rows
+    routed to held experts, rows the computed tiles cover, the largest
+    expert's rows, slots dropped) as host ints, fetched after the loss
+    has already synced the step."""
+    names = [k for k in moe_mod.STATS if k in metrics]
+    if not names:
+        return {}
+    vals = jax.device_get([metrics[k] for k in names])
+    return {k: int(v) for k, v in zip(names, vals)}
+
+
 def _restore_into(cfg, step, state_like, pipeline):
     tree, extra = ckpt_mod.restore(cfg.ckpt_dir, step, state_like)
     pipeline.step = extra["data_state"]["step"]
@@ -122,7 +135,8 @@ def _restore_into(cfg, step, state_like, pipeline):
 def run(cfg: TrainLoopConfig, train_step, params, opt_state, pipeline,
         log: Callable[[str], None] = print,
         recorder: "obs.Recorder | None" = None) -> dict:
-    """Returns {params, opt_state, step, history, straggler_count, guardian}.
+    """Returns {params, opt_state, step, history, straggler_count, guardian,
+    moe}.
 
     ``train_step(params, opt_state, batch, step[, lr_scale]) ->
     (params, opt_state, metrics)`` must be jit-compiled by the caller
@@ -137,7 +151,10 @@ def run(cfg: TrainLoopConfig, train_step, params, opt_state, pipeline,
     sync: every recorded value is one the loop already fetched for its
     own logic — ``loss`` is synced for honest step timing regardless,
     ``nonfinite`` only on the guardian path (``obs.NOT_SAMPLED`` when
-    the guardian is off rather than forcing a transfer).
+    the guardian is off rather than forcing a transfer).  An MoE step's
+    counters (``moe_*``) are fetched after the loss's sync and go to the
+    recorder as counters (the largest expert's rows as a gauge); the
+    returned ``moe`` sums them over the adopted steps (that one: the max).
     """
     g = cfg.guardian
     saver = ckpt_mod.AsyncSaver()
@@ -177,6 +194,7 @@ def run(cfg: TrainLoopConfig, train_step, params, opt_state, pipeline,
                            on_straggler=lambda s, dt, med: log(
                                f"[straggler] step {s}: {dt*1e3:.1f}ms vs median {med*1e3:.1f}ms"))
     history = []
+    moe_totals: dict = {}
     rec = recorder
     dt_ema: float | None = None
     awaiting_recovery = False
@@ -262,6 +280,15 @@ def run(cfg: TrainLoopConfig, train_step, params, opt_state, pipeline,
 
             params, opt_state = new_params, new_opt
             mon.observe(step, dt)
+            for name, n in _moe_counters(metrics).items():
+                big = name == "moe_max_expert_rows"
+                moe_totals[name] = (max(moe_totals.get(name, 0), n) if big
+                                    else moe_totals.get(name, 0) + n)
+                if rec is not None:
+                    if big:
+                        rec.gauge(f"train.{name}", n)
+                    else:
+                        rec.count(f"train.{name}", n)
             if rec is not None:
                 if awaiting_recovery:
                     # first step adopted after a rollback: the run is live
@@ -338,4 +365,5 @@ def run(cfg: TrainLoopConfig, train_step, params, opt_state, pipeline,
                      "skipped_data_steps": sorted(bad_data_steps)}
     return {"params": params, "opt_state": opt_state, "step": step,
             "history": history, "straggler_count": mon.count,
-            "guardian": guardian_info if g is not None else None}
+            "guardian": guardian_info if g is not None else None,
+            "moe": moe_totals}

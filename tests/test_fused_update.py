@@ -51,7 +51,7 @@ def _moe_cfg(**kw):
         n_heads=4, kv_heads=4, head_dim=32, d_ff=256, vocab=128,
         act="silu", max_seq=64, attn_chunk=32, dtype="float32",
         param_dtype="float32",
-        moe=MoEConfig(num_experts=4, top_k=2, d_expert=64, group_size=32),
+        moe=MoEConfig(num_experts=4, top_k=2, d_expert=64),
         sparsity=SparsityConfig(density=0.5, block=32, where="ffn"),
         engine="pallas", fused_update=True)
     base.update(kw)

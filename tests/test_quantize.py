@@ -209,8 +209,7 @@ def _moe_cfg(engine="jnp"):
         name="quant-moe-test", family="moe", n_layers=1, d_model=128,
         n_heads=4, kv_heads=4, head_dim=32, d_ff=256, vocab=128,
         act="silu", max_seq=64, attn_chunk=32, dtype="float32",
-        moe=MoEConfig(num_experts=4, top_k=2, d_expert=64, group_size=32,
-                      capacity_factor=1.25),
+        moe=MoEConfig(num_experts=4, top_k=2, d_expert=64),
         sparsity=SparsityConfig(density=0.5, block=32, where="ffn"),
         engine=engine)
 
@@ -231,15 +230,15 @@ def test_moe_expert_int8_parity_and_tolerance():
     assert jnp.array_equal(pq["router"], params["router"])  # dense stays fp
 
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 32, cfg.d_model))
-    y_fp, aux_fp = moe_mod.moe_apply(params, x, cfg)
-    y_q, aux_q = moe_mod.moe_apply(pq, x, cfg)
+    y_fp, aux_fp, _ = moe_mod.moe_apply(params, x, cfg)
+    y_q, aux_q, _ = moe_mod.moe_apply(pq, x, cfg)
     assert float(aux_q) == float(aux_fp)         # routing untouched
     rel = (np.linalg.norm(np.asarray(y_q) - np.asarray(y_fp))
            / np.linalg.norm(np.asarray(y_fp)))
     assert 0.0 < rel < 0.05
 
-    y_pal, _ = moe_mod.moe_apply(pq, x, dataclasses.replace(cfg,
-                                                            engine="pallas"))
+    y_pal, _, _ = moe_mod.moe_apply(pq, x, dataclasses.replace(
+        cfg, engine="pallas"))
     np.testing.assert_allclose(np.asarray(y_pal), np.asarray(y_q),
                                atol=1e-4, rtol=1e-4)
 

@@ -220,3 +220,113 @@ def test_paged_serve_step_writes_pool_in_place(chip, monkeypatch, step):
               if op in ("copy", "copy-start", "copy-done", "dynamic-slice",
                         "dynamic-update-slice")]
     assert not copies, "\n".join(copies)
+
+
+# ------------------------------------------- expert kernels with live counts
+# The MoE training cell's expert junctions: E=8 held experts, a buffer of
+# 32,768 rows each (4 x 8192 tokens), 2048 -> 1408 at kb 4 (gated) and
+# 1408 -> 2048 at kb 3, bf16, the fused Adam epilogue.
+EXP, EXP_ROWS, EXP_D, EXP_F = 8, 32768, 2048, 1408
+PIN = make_block_pattern(EXP_D, EXP_F, 0.25, BLOCK)
+POUT = make_block_pattern(EXP_F, EXP_D, 0.25, BLOCK)
+
+# location-free Mosaic of each kernel called WITHOUT counts at these
+# shapes, as the kernels stood before counts were added (sha256, 16 hex)
+UNCOUNTED_MOSAIC = {
+    "gated_fwd": "489878efb97e32d9", "fwd": "e8b273c02d569e50",
+    "gated_dx": "536f74fea442d8a6", "dx": "460cd4438b64dd4b",
+    "gated_dw": "6b3a96f44b955e1d", "dw": "552b8c0391e553ba",
+    "update_gated_dw": "57e9da81c3c6b445", "update_dw": "389467c8f110dbb8",
+}
+
+
+def _expert_calls(chip, counted: bool):
+    """name -> (fn, operands) of every E-batched expert kernel."""
+    i32, f32 = jnp.int32, jnp.float32
+    x, h, y = (chip((EXP, EXP_ROWS, EXP_D)), chip((EXP, EXP_ROWS, EXP_F)),
+               chip((EXP, EXP_ROWS, EXP_D)))
+    wg = chip((EXP,) + PIN.idx.shape + (BLOCK, BLOCK))
+    wo = chip((EXP,) + POUT.idx.shape + (BLOCK, BLOCK))
+    slot = lambda w: chip(w.shape, f32)
+    idx_i, idx_o = chip(PIN.idx.shape, i32), chip(POUT.idx.shape, i32)
+    rv_i = [chip(a.shape, i32) for a in (PIN.rev_ob, PIN.rev_t, PIN.rev_cnt)]
+    rv_o = [chip(a.shape, i32)
+            for a in (POUT.rev_ob, POUT.rev_t, POUT.rev_cnt)]
+    hyp, b0 = chip((EXP, bsm.HYP_K), f32), chip((EXP, EXP_D))
+    tail = (chip((EXP,), i32),) if counted else ()
+
+    def c(fn):   # the counts ride as the last operand when counted
+        def call(*a):
+            return fn(*a[:len(a) - len(tail)], counts=a[-1] if tail else None)
+        return call
+
+    return {
+        "gated_fwd": (c(lambda x, a, b, i, counts: bsm.gated_fwd(
+            x, a, b, i, save_res=True, counts=counts)),
+            (x, wg, wg, idx_i) + tail),
+        "fwd": (c(lambda h, w, i, b, counts: bsm.fwd(
+            h, w, i, b, counts=counts)[0]), (h, wo, idx_o, b0) + tail),
+        "gated_dx": (c(lambda dh, a, b, r0, r1, r2, g, u, counts: bsm.gated_dx(
+            dh, a, b, r0, r1, r2, g, u, counts=counts)),
+            (h, wg, wg, *rv_i, h, h) + tail),
+        "dx": (c(lambda dy, w, r0, r1, r2, counts: bsm.dx(
+            dy, w, r0, r1, r2, None, counts=counts)), (y, wo, *rv_o) + tail),
+        "gated_dw": (c(lambda x, dh, i, g, u, counts: bsm.gated_dw(
+            x, dh, i, g, u, counts=counts)), (x, h, idx_i, h, h) + tail),
+        "dw": (c(lambda h, dy, i, counts: bsm.dw(
+            h, dy, i, None, with_bias=False, counts=counts)[0]),
+            (h, y, idx_o) + tail),
+        "update_gated_dw": (c(
+            lambda x, dh, i, g, u, a, b, ma, mb, va, vb, hy, counts:
+            bsm.update_gated_dw(x, dh, i, g, u, a, b, ma, mb, hy, vg=va,
+                                vi=vb, with_health=True,
+                                counts=counts)[:7:6]),
+            (x, h, idx_i, h, h, wg, wg, slot(wg), slot(wg), slot(wg),
+             slot(wg), hyp) + tail),
+        "update_dw": (c(lambda h, dy, i, w, m, v, hy, counts: bsm.update_dw(
+            h, dy, i, None, w, None, m, None, hy, vel=v, with_bias=False,
+            with_health=True, counts=counts)[::6]),
+            (h, y, idx_o, wo, slot(wo), slot(wo), hyp) + tail),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(UNCOUNTED_MOSAIC))
+def test_counted_expert_kernel_compiles(chip, name):
+    """Each expert kernel with live row counts compiles for the chip at the
+    MoE cell's shapes, under its ``expert_junction_`` name."""
+    fn, args = _expert_calls(chip, counted=True)[name]
+    _compiled(fn, *args, kernel=f"expert_junction_{name}")
+
+
+def _mosaic_fingerprints(txt: str) -> list[str]:
+    """sha256 (16 hex) of each Mosaic kernel in a lowered module, with
+    its source locations stripped (they move with every edit)."""
+    import base64
+    import hashlib
+    import json
+    from jax._src.lib import tpu as tpu_dialect
+    from jax._src.lib.mlir import ir
+    out = []
+    for cfg in re.findall(r'backend_config = "([^"]*)"', txt):
+        cfg = json.loads(re.sub(r"\\([0-9A-Fa-f]{2})",
+                                lambda g: chr(int(g.group(1), 16)), cfg))
+        body = base64.b64decode(cfg["custom_call_config"]["body"])
+        with ir.Context() as ctx:
+            tpu_dialect.register_dialect(ctx)
+            ctx.allow_unregistered_dialects = True
+            asm = ir.Module.parse(body).operation.get_asm(
+                enable_debug_info=False)
+        out.append(hashlib.sha256(asm.encode()).hexdigest()[:16])
+    return out
+
+
+def test_uncounted_expert_kernels_lower_as_before(chip):
+    """Called without counts, every expert kernel lowers to the Mosaic it
+    lowered to before counts existed: the sweep's and the dense cells'
+    kernels are unchanged."""
+    got = {}
+    for name, (fn, args) in _expert_calls(chip, counted=False).items():
+        with jax.default_matmul_precision("default"):
+            got[name] = _mosaic_fingerprints(
+                jax.jit(fn).lower(*args).as_text())
+    assert got == {k: [v] for k, v in UNCOUNTED_MOSAIC.items()}
