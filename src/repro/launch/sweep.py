@@ -133,7 +133,8 @@ def main(argv=None):
     led = result.ledger
     led.save(args.out)
     print(f"[sweep] traces: step {led.meta['step_traces']}, eval "
-          f"{led.meta['eval_traces']} ({n_cohorts} cohort(s))")
+          f"{led.meta['eval_traces']}, programs reused "
+          f"{led.meta['programs_reused']} ({n_cohorts} cohort(s))")
 
     for m in sorted(led.members, key=lambda m: (m.pruned_at is None,
                                                 m.rounds_survived)):

@@ -83,10 +83,11 @@ only inside its outer one):
 
 * ``repro.sweep.setup`` — ``search/scheduler.run_sweep`` from entry to
   the first cohort step: the data's host→device copies and each
-  cohort's weights, slots, hyp table, step and eval builders and padded
-  targets;
-* ``repro.sweep.first_step`` — a cohort's first step of the call (its
-  trace, lowering and compile-cache fetch), ``repro.sweep.step`` —
+  cohort's weights, slots, hyp table, step and eval (from the program
+  cache, built on first use) and padded targets;
+* ``repro.sweep.first_step`` — a cohort's first step of the call (on
+  a cold call also its trace, lowering and compile-cache fetch),
+  ``repro.sweep.step`` —
   each later cohort step (hyp stamp, dispatch, bookkeeping); both hold
   ``repro.sweep.fetch``, the fetch of the step's losses and health;
 * ``repro.sweep.eval`` — one cohort's eval in a round, its fetch

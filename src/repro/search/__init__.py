@@ -40,7 +40,8 @@ re-addresses it as a *population* (E models, one structure):
   applied to training.
 
 Modules: ``population`` (stacking, per-member hyp, E-batched steps),
-``cohorts`` (structure bucketing), ``scheduler`` (successive halving),
+``cohorts`` (structure bucketing), ``scheduler`` (successive halving;
+each structure's step and eval built once per process),
 ``ledger`` (JSON lineage artifact).  ``launch/sweep.py`` is the CLI;
 ``configs.base.SweepConfig`` the knob set.
 """
@@ -51,10 +52,10 @@ from repro.search.population import (CandidateSpec, hyp_table,
                                      make_population_eval,
                                      make_population_step, member_slice,
                                      structure_key)
-from repro.search.scheduler import SweepResult, run_sweep
+from repro.search.scheduler import SweepResult, clear_program_cache, run_sweep
 
 __all__ = ["CandidateSpec", "Cohort", "Ledger", "MemberRecord",
            "QuantCohort", "SweepResult", "bucket", "bucket_quant",
-           "hyp_table", "init_population", "init_slots",
-           "make_population_eval", "make_population_step",
+           "clear_program_cache", "hyp_table", "init_population",
+           "init_slots", "make_population_eval", "make_population_step",
            "member_slice", "run_sweep", "structure_key"]

@@ -21,10 +21,23 @@ to 0 (its loss drops out of the objective, so its gradients are exact
 zeros) and its hyp row goes to all zeros (the kernels' guarded epilogue
 makes an all-zero registry row an exact freeze for SGD and Adam alike:
 w' = w, slots' = 0).  The arrays
-the jitted step sees never change shape, so a sweep compiles each cohort
-step exactly once — the serve engine's finished-slot masking applied to
-training, and the paper's "greater exploration ... on-chip" claim as a
-subsystem: exploration cost scales with rounds, not candidates.
+the jitted step sees never change shape — the serve engine's
+finished-slot masking applied to training, and the paper's "greater
+exploration ... on-chip" claim as a subsystem: exploration cost scales
+with rounds, not candidates.
+
+The jitted step and eval are built once per structure per process
+(``_programs``): weights, pattern leaves, slots, the hyp table, the mask
+and the data are all traced operands, so one program serves every cohort
+of the same activation, engine and update path, on every call, and JAX
+keeps one executable per shape.  A cohort's step is traced by the first
+call that meets its shapes and never again: a later call with the same
+structures and shapes traces nothing.  Only a process that calls
+``run_sweep`` more than once gains; a single call (``launch/sweep.py``
+makes one) traces each cohort once, as before.  The programs, and an
+executable for every shape the process has run, are kept until
+``clear_program_cache`` drops them: a long-lived caller that sweeps many
+widths or batch sizes holds them all.
 
 The same mechanism doubles as FAULT ISOLATION (``SweepConfig.quarantine``,
 on by default): exploring lr×density means routinely training members at
@@ -47,6 +60,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import threading
 from typing import Sequence
 
 import jax
@@ -54,6 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import SweepConfig
+from repro.core.sparse_linear import resolve_engine
 from repro.obs import telemetry as obs
 from repro.search import cohorts as ch
 from repro.search import population as pop
@@ -147,10 +162,62 @@ def _quarantine(st: CohortState, rec: MemberRecord, rnd: int,
             detail={"step": global_step}))
 
 
+class _ThreadTraces(threading.local):
+    """Traces of the cached programs made by the calling thread, by kind
+    (the ``traces`` dict protocol of the factories).  JAX traces a jitted
+    function in the thread that calls it, so the delta a ``run_sweep``
+    call records holds its own traces only, whatever other threads sweep
+    meanwhile."""
+
+    def __init__(self):
+        self.counts = {"step": 0, "eval": 0}
+
+    def get(self, kind, default=0):
+        return self.counts.get(kind, default)
+
+    def __setitem__(self, kind, n):
+        self.counts[kind] = n
+
+
+# (step factory, eval factory, act, engine, fused update, with_health) ->
+# (jitted step, jitted eval).  Every cached program counts its traces in
+# _TRACES; run_sweep records a call's delta.
+_PROGRAMS: dict[tuple, tuple] = {}
+_TRACES = _ThreadTraces()
+
+
+def clear_program_cache() -> None:
+    """Drop the cached cohort programs: the next ``run_sweep`` builds,
+    and JAX traces, each cohort's step and eval anew."""
+    _PROGRAMS.clear()
+
+
+def _programs(act: str, cfg: SweepConfig):
+    """((step, evaluate), reused) for a cohort of activation ``act``:
+    the pair is built on first use and kept for the process.  The key is
+    everything the traced functions close over, the two factories
+    included (read from ``population`` at lookup, so a replaced factory
+    gets programs of its own)."""
+    make_step, make_eval = pop.make_population_step, pop.make_population_eval
+    engine = resolve_engine(cfg.engine)
+    # the factory fuses the update on the pallas engine only
+    fused = cfg.fused and engine == "pallas"
+    key = (make_step, make_eval, act, engine, fused, cfg.quarantine)
+    progs = _PROGRAMS.get(key)
+    if progs is not None:
+        return progs, True
+    progs = _PROGRAMS[key] = (
+        make_step(act, engine=engine, fused=fused,
+                  with_health=cfg.quarantine, traces=_TRACES),
+        make_eval(act, engine=engine, traces=_TRACES))
+    return progs, False
+
+
 def _setup(specs, x_train, t_train, x_eval, t_eval, cfg: SweepConfig,
-           tag: str, traces: dict):
+           tag: str):
     """The ledger, each cohort's state (weights, slots, hyp table, step
-    and eval, padded targets) and the data on the device."""
+    and eval from the program cache, padded targets) and the data on the
+    device."""
     x_train = np.asarray(x_train, np.float32)
     t_train = np.asarray(t_train, np.float32)
     x_eval = np.asarray(x_eval, np.float32)[:cfg.eval_samples]
@@ -162,6 +229,7 @@ def _setup(specs, x_train, t_train, x_eval, t_eval, cfg: SweepConfig,
                               n_candidates=len(specs)))
     key = jax.random.PRNGKey(cfg.seed)
     states: list[CohortState] = []
+    reused = 0
     for ci, cohort in enumerate(ch.bucket(specs)):
         spec0 = cohort.specs[0]
         if x_train.shape[1] != spec0.layers[0]:
@@ -174,23 +242,20 @@ def _setup(specs, x_train, t_train, x_eval, t_eval, cfg: SweepConfig,
             member=mid, config=s.to_dict(), cohort=ci, slot=slot))
             for slot, (mid, s) in enumerate(zip(cohort.member_ids,
                                                 cohort.specs))]
+        (step, evaluate), hit = _programs(spec0.act, cfg)
+        reused += hit
         states.append(CohortState(
             cohort=cohort, params=params,
             mom=pop.init_slots(params, cohort.specs),
             hyp=pop.hyp_table(cohort.specs),
             mask=jnp.ones((cohort.size,), jnp.float32),
             records=records,
-            step=pop.make_population_step(spec0.act, engine=cfg.engine,
-                                          fused=cfg.fused,
-                                          with_health=cfg.quarantine,
-                                          traces=traces),
-            evaluate=pop.make_population_eval(spec0.act,
-                                              engine=cfg.engine,
-                                              traces=traces),
+            step=step, evaluate=evaluate,
             # targets are constant per cohort: pad + upload once, slice
             # per minibatch on device
             t_train_pad=jnp.asarray(_pad_targets(t_train, spec0.layers[-1])),
             t_eval_pad=jnp.asarray(_pad_targets(t_eval, spec0.layers[-1]))))
+    ledger.meta["programs_reused"] = reused
     return ledger, states, jnp.asarray(x_train), jnp.asarray(x_eval)
 
 
@@ -209,12 +274,21 @@ def run_sweep(specs: Sequence[pop.CandidateSpec], x_train, t_train,
     ``detail``), prune and quarantine (one per affected member, its
     cohort/slot attached), winner — so a sweep's ledger and its
     telemetry share one timeline.  All values are host floats the
-    scheduler already fetched for ranking."""
+    scheduler already fetched for ranking.
+
+    ``ledger.meta`` counts the call's traces of the cohorts' step and
+    eval (``step_traces``, ``eval_traces``: one per cohort of distinct
+    shapes on a cold call, 0 on a warm one) and the cohorts whose step
+    and eval came from the program cache (``programs_reused``, also
+    counted as ``sweep.programs_reused`` on the recorder)."""
     specs = list(specs)
-    traces = {"step": 0, "eval": 0}
+    traces0 = dict(_TRACES.counts)
     with obs.span("sweep.setup", recorder):
         ledger, states, x_train_d, x_eval_d = _setup(
-            specs, x_train, t_train, x_eval, t_eval, cfg, tag, traces)
+            specs, x_train, t_train, x_eval, t_eval, cfg, tag)
+    if recorder is not None:
+        recorder.count("sweep.programs_reused",
+                       ledger.meta["programs_reused"])
 
     n_train = x_train_d.shape[0]
     global_step = 0
@@ -225,7 +299,8 @@ def run_sweep(specs: Sequence[pop.CandidateSpec], x_train, t_train,
             bi = jnp.asarray(_batch_indices(
                 n_train, min(cfg.batch_size, n_train), global_step))
             xb = jnp.take(x_train_d, bi, axis=0)
-            # the first cohort step traces, lowers and fetches the step
+            # a cohort's first step of the call: on a cold call it also
+            # traces, lowers and fetches the step
             step_span = "sweep.first_step" if global_step == 0 else \
                 "sweep.step"
             for st in states:
@@ -297,8 +372,8 @@ def run_sweep(specs: Sequence[pop.CandidateSpec], x_train, t_train,
     ledger.meta["live_at_end"] = n_live
     ledger.meta["quarantined"] = sum(
         1 for m in ledger.members if m.quarantined_at is not None)
-    ledger.meta["step_traces"] = traces["step"]
-    ledger.meta["eval_traces"] = traces["eval"]
+    ledger.meta["step_traces"] = _TRACES.counts["step"] - traces0["step"]
+    ledger.meta["eval_traces"] = _TRACES.counts["eval"] - traces0["eval"]
     return SweepResult(ledger=ledger, states=states)
 
 

@@ -6,6 +6,7 @@ from pathlib import Path
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_default_matmul_precision", "highest")
 
@@ -31,3 +32,13 @@ def optional_hypothesis():
                 return lambda *a, **k: None
 
         return given, settings, _StrategyStub()
+
+
+@pytest.fixture
+def cold_sweep_programs():
+    """The sweep scheduler's program cache emptied first: a ``run_sweep``
+    in the test builds, and traces, its cohorts' step and eval anew,
+    whatever sweeps the process ran before (trace counts do not depend
+    on test order)."""
+    from repro.search import clear_program_cache
+    clear_program_cache()

@@ -514,10 +514,10 @@ def _sweep_data(n_in=128, n_out=64):
     return x, t, x[:32], t[:32]
 
 
-def test_sweep_spans_in_profiler_trace(tmp_path):
+def test_sweep_spans_in_profiler_trace(tmp_path, cold_sweep_programs):
     """Every sweep span, nested as documented, with the counts the
     schedule implies (2 cohorts, 2 rounds of 3 steps, nobody pruned),
-    and one trace of each cohort's step and eval per call."""
+    and one trace of each cohort's step and eval on a cold call."""
     cfg = SweepConfig(rounds=2, steps_per_round=3, batch_size=32,
                       eval_samples=32, keep_fraction=1.0, engine="jnp",
                       fused=False)
@@ -550,15 +550,19 @@ def test_sweep_spans_in_profiler_trace(tmp_path):
     assert rec.hists["span.sweep.fetch_s"].count == cohorts * steps
 
 
-def test_sweep_trace_counts_are_per_call():
-    """run_sweep builds its cohorts' step and eval anew on every call:
-    each call's ledger counts its own traces, one per cohort and kind."""
+def test_sweep_trace_counts_are_per_call(cold_sweep_programs):
+    """Each call's ledger counts its own traces: one per cohort and kind
+    on a cold call, none on a second call of the same structures and
+    shapes, whose cohorts all take their step and eval from the program
+    cache (the cold call's second cohort already reuses the first's)."""
     cfg = SweepConfig(rounds=1, steps_per_round=2, batch_size=32,
                       eval_samples=32, engine="jnp", fused=False)
-    metas = [run_sweep(_sweep_specs(), *_sweep_data(), cfg).ledger.meta
-             for _ in range(2)]
-    assert [(m["step_traces"], m["eval_traces"]) for m in metas] == \
-        [(2, 2), (2, 2)]
+    rec = Recorder()
+    metas = [run_sweep(_sweep_specs(), *_sweep_data(), cfg,
+                       recorder=rec).ledger.meta for _ in range(2)]
+    assert [(m["step_traces"], m["eval_traces"], m["programs_reused"])
+            for m in metas] == [(2, 2, 1), (0, 0, 2)]
+    assert rec.counters["sweep.programs_reused"] == 3
 
 
 def test_population_step_jaxpr_unchanged_by_spans_and_trace_counter():

@@ -13,7 +13,9 @@ explicit-table equivalence at the ops level, opt as a structural cohort
 axis, cohort bucketing rules, in-place prune freezing, and ledger JSON
 round-tripping.
 """
+import dataclasses
 import json
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -25,9 +27,11 @@ from repro.core import sparse_linear as sl
 from repro.core.sparsity import make_block_pattern
 from repro.data.mnist import paper_dataset
 from repro.kernels import ops
-from repro.search import (CandidateSpec, Ledger, bucket, hyp_table,
-                          init_population, make_population_step,
-                          member_slice, run_sweep, structure_key)
+from repro.obs import Recorder
+from repro.search import (CandidateSpec, Ledger, bucket,
+                          clear_program_cache, hyp_table, init_population,
+                          make_population_step, member_slice, run_sweep,
+                          structure_key)
 from repro.search import population as pop
 
 
@@ -531,3 +535,153 @@ def test_sweep_single_candidate_wins():
     result = run_sweep(specs, x[:64], t[:64], x[64:], t[64:], cfg)
     w = result.ledger.winner()
     assert w is not None and w.member == 0 and w.rounds_survived == 2
+
+
+# ------------------------------------------------ program cache (scheduler)
+def _cache_specs(act="sigmoid", diverging=False):
+    """Two cohorts (densities 0.5 and 0.25) of two members each; with
+    ``diverging`` the first cohort holds a third member at lr=inf."""
+    specs = [CandidateSpec(lr=lr, density=d, layers=(128, 64), block=32,
+                           act=act, init_seed=i)
+             for i, (d, lr) in enumerate((d, lr) for d in (0.5, 0.25)
+                                         for lr in (0.05, 0.2))]
+    if diverging:
+        specs.insert(2, dataclasses.replace(specs[0], lr=float("inf"),
+                                            init_seed=9))
+    return specs
+
+
+def _cache_data():
+    rng = np.random.default_rng(3)
+    x = rng.standard_normal((96, 128)).astype(np.float32)
+    t = np.eye(64, dtype=np.float32)[rng.integers(0, 64, 96)]
+    return x[:64], t[:64], x[64:], t[64:]
+
+
+def _sweep_outputs(result):
+    """Everything a sweep returns but the artifact stamp: the members'
+    records (loss curves, eval losses, pruning, quarantine, winner) and
+    every cohort's final params and slots."""
+    members = json.dumps([m.to_dict() for m in result.ledger.members])
+    leaves = [np.asarray(a) for st in result.states
+              for a in jax.tree_util.tree_leaves((st.params, st.mom))]
+    return members, leaves
+
+
+@pytest.mark.parametrize("engine,fused,quarantine",
+                         [("pallas", True, True), ("jnp", False, False)])
+def test_cached_programs_match_a_cold_build(engine, fused, quarantine):
+    """Two run_sweep calls with different seeds, the second on the first
+    one's cached programs, give the same ledgers, eval losses and final
+    weights, bit for bit, as the same two calls each built cold; the
+    warm call traces nothing and takes every cohort's programs from the
+    cache."""
+    specs = _cache_specs(diverging=quarantine)
+    data = _cache_data()
+    cfg = SweepConfig(rounds=2, steps_per_round=2, batch_size=16,
+                      eval_samples=32, engine=engine, fused=fused,
+                      quarantine=quarantine)
+    cfgs = [dataclasses.replace(cfg, seed=s) for s in (11, 2**31 - 5)]
+    clear_program_cache()
+    warm = [run_sweep(specs, *data, c) for c in cfgs]
+    cold = []
+    for c in cfgs:
+        clear_program_cache()
+        cold.append(run_sweep(specs, *data, c))
+    cohorts = len(bucket(specs))
+    assert [(r.ledger.meta["step_traces"], r.ledger.meta["eval_traces"],
+             r.ledger.meta["programs_reused"]) for r in warm] == \
+        [(cohorts, cohorts, cohorts - 1), (0, 0, cohorts)]
+    assert all(r.ledger.meta["step_traces"] == cohorts for r in cold)
+    if quarantine:
+        assert warm[1].ledger.meta["quarantined"] == 1
+    for w, c in zip(warm, cold):
+        (wm, wl), (cm, cl) = _sweep_outputs(w), _sweep_outputs(c)
+        assert wm == cm
+        assert len(wl) == len(cl)
+        for a, b in zip(wl, cl):
+            np.testing.assert_array_equal(a, b)
+    # the two seeds trained different weights: the comparison has teeth
+    assert _sweep_outputs(warm[0])[0] != _sweep_outputs(warm[1])[0]
+
+
+@pytest.mark.parametrize("change", ["act", "fused", "quarantine", "factory"])
+def test_program_cache_key_separates_structure(change, monkeypatch):
+    """A different activation, update path, quarantine setting or step
+    factory gets programs of its own, never a cached one built for
+    another; the first structure's programs stay cached beside it.  The
+    update path is fused on the pallas engine only, so that case runs
+    there."""
+    data = _cache_data()
+    cfg = SweepConfig(rounds=1, steps_per_round=1, batch_size=16,
+                      eval_samples=32,
+                      engine="pallas" if change == "fused" else "jnp",
+                      fused=False, quarantine=False)
+    specs = _cache_specs()
+    cohorts = len(bucket(specs))
+    clear_program_cache()
+    run_sweep(specs, *data, cfg)
+    built = []
+    if change == "act":
+        specs2, cfg2 = _cache_specs(act="relu"), cfg
+    elif change == "factory":
+        real = pop.make_population_step
+
+        def factory(*a, **kw):
+            built.append(a)
+            return real(*a, **kw)
+        monkeypatch.setattr(pop, "make_population_step", factory)
+        specs2, cfg2 = specs, cfg
+    else:
+        specs2, cfg2 = specs, dataclasses.replace(cfg, **{change: True})
+    meta = run_sweep(specs2, *data, cfg2).ledger.meta
+    assert (meta["step_traces"], meta["programs_reused"]) == \
+        (cohorts, cohorts - 1)
+    assert built == ([("sigmoid",)] if change == "factory" else [])
+    monkeypatch.undo()
+    meta = run_sweep(specs, *data, cfg).ledger.meta
+    assert (meta["step_traces"], meta["programs_reused"]) == (0, cohorts)
+
+
+def test_program_cache_keys_the_update_path_not_the_flag():
+    """On the jnp engine ``fused`` changes nothing the step traces (the
+    update is two-pass either way), so both settings share one entry."""
+    data = _cache_data()
+    cfg = SweepConfig(rounds=1, steps_per_round=1, batch_size=16,
+                      eval_samples=32, engine="jnp", fused=False,
+                      quarantine=False)
+    specs = _cache_specs()
+    cohorts = len(bucket(specs))
+    clear_program_cache()
+    run_sweep(specs, *data, cfg)
+    meta = run_sweep(specs, *data,
+                     dataclasses.replace(cfg, fused=True)).ledger.meta
+    assert (meta["step_traces"], meta["programs_reused"]) == (0, cohorts)
+
+
+def test_sweep_trace_counts_ignore_other_threads():
+    """A call's trace counts are its own: a cold sweep run by another
+    thread while the call is under way (here between its set-up and its
+    first step, where its own traces happen) adds nothing to them."""
+    data = _cache_data()
+    cfg = SweepConfig(rounds=1, steps_per_round=1, batch_size=16,
+                      eval_samples=32, engine="jnp", fused=False,
+                      quarantine=False)
+    specs = _cache_specs()
+    cohorts = len(bucket(specs))
+    other = {}
+
+    class SideSweep(Recorder):
+        def count(self, name, n=1):
+            super().count(name, n)
+            if name == "sweep.programs_reused":
+                th = threading.Thread(target=lambda: other.update(
+                    run_sweep(_cache_specs(act="relu"), *data,
+                              cfg).ledger.meta))
+                th.start()
+                th.join()
+
+    clear_program_cache()
+    meta = run_sweep(specs, *data, cfg, recorder=SideSweep()).ledger.meta
+    assert other["step_traces"] == other["eval_traces"] == cohorts
+    assert (meta["step_traces"], meta["eval_traces"]) == (cohorts, cohorts)
