@@ -330,3 +330,233 @@ def test_uncounted_expert_kernels_lower_as_before(chip):
             got[name] = _mosaic_fingerprints(
                 jax.jit(fn).lower(*args).as_text())
     assert got == {k: [v] for k, v in UNCOUNTED_MOSAIC.items()}
+
+
+# ------------------------------------------------- the cells' kernels, pinned
+# Every junction kernel configuration the benchmark's five cells launch
+# (found by lowering each cell's step for a described v5e and listing its
+# kernels), and a few that only tests and the int8 serve path reach, with
+# the location-free Mosaic each lowers to.  A key reads
+# "kernel ExM n_in>n_out kb=.. [fb=..] dtype [options]": fb is the reverse
+# pattern's width, opt the update's slots (sgd: none, mom: momentum, adam:
+# m and v); the update kernels carry the health flags unless nohealth.
+CELL_MOSAIC = {
+    # stablelm-3b's FFN, 8 x 1024 rows: both train cells (the fused cell
+    # alone runs update_dw)
+    "fwd 1x8192 2560>6912 kb=5 bf16 bm=512 bn=2":
+        "ae87a1926a6c2f4e",
+    "fwd 1x8192 6912>2560 kb=14 bf16 bm=256 bn=4":
+        "938a68234f622aaf",
+    "fwd 1x8192 2560>6912 kb=5 bf16 act=silu bm=512 bn=2 save":
+        "42b80f8289c6414e",
+    "dx 1x8192 6912>2560 kb=14 fb=6 bf16":
+        "dad8cdf1a5357b3a",
+    "dw 1x8192 6912>2560 kb=14 bf16":
+        "a5bf5c19606d9593",
+    "dx 1x8192 2560>6912 kb=5 fb=14 bf16":
+        "3c3f45fd7b989ea7",
+    "dw 1x8192 2560>6912 kb=5 bf16":
+        "34679a693c030e02",
+    "dx 1x8192 2560>6912 kb=5 fb=14 bf16 act=silu":
+        "7d49c8694da672fe",
+    "dw 1x8192 2560>6912 kb=5 bf16 act=silu":
+        "85635399feaaff54",
+    "update_dw 1x8192 6912>2560 kb=14 bf16 opt=adam":
+        "106ef5628112ff6b",
+    "update_dw 1x8192 2560>6912 kb=5 bf16 opt=adam":
+        "d95a01784f26d9d6",
+    "update_dw 1x8192 2560>6912 kb=5 bf16 act=silu opt=adam":
+        "e33cf3e0d5cc4731",
+    # DeepSeek-V2-Lite, 4 x 8192 rows: the dense layer and the shared
+    # experts (E=1), the routed experts (E=8, counted)
+    "fwd 8x32768 1408>2048 kb=3 bf16 bm=256 bn=8 counted":
+        "76533283c6fdf7d6",
+    "fwd 1x32768 2048>2816 kb=4 bf16 bm=512 bn=2":
+        "0b32605c325209ff",
+    "fwd 1x32768 2816>2048 kb=6 bf16 bm=512 bn=8":
+        "f5c5dc63749258c6",
+    "gated_fwd 8x32768 2048>1408 kb=4 bf16 bm=256 bn=1 save counted":
+        "a5d2511bd0586e1f",
+    "fwd 1x32768 2048>2816 kb=4 bf16 act=silu bm=512 bn=2 save":
+        "3f2895834ecda3d2",
+    "dx 1x32768 2816>2048 kb=6 fb=5 bf16":
+        "2835b367798ceb92",
+    "dw 1x32768 2816>2048 kb=6 bf16":
+        "6095b0b4172ee2c1",
+    "dx 1x32768 2048>2816 kb=4 fb=6 bf16":
+        "2d03aeaa73263d87",
+    "dw 1x32768 2048>2816 kb=4 bf16":
+        "f982b55ca3e160f3",
+    "dx 1x32768 2048>2816 kb=4 fb=6 bf16 act=silu":
+        "c1e02305e3751b3a",
+    "dw 1x32768 2048>2816 kb=4 bf16 act=silu":
+        "7a29790f7de1d17e",
+    "dx 8x32768 1408>2048 kb=3 fb=5 bf16 bm=256 counted":
+        "e19d49554842889c",
+    "dw 8x32768 1408>2048 kb=3 bf16 bm=256 counted":
+        "e0018eb929b50afb",
+    "gated_dx 8x32768 2048>1408 kb=4 fb=3 bf16 bm=256 counted":
+        "ee6364ae23896925",
+    "gated_dw 8x32768 2048>1408 kb=4 bf16 bm=256 counted":
+        "12d4a20a9142d710",
+    "update_dw 1x32768 2816>2048 kb=6 bf16 opt=adam":
+        "3ad3c34ae5cc2b35",
+    "update_dw 1x32768 2048>2816 kb=4 bf16 opt=adam":
+        "32b9df997ae84452",
+    "update_dw 1x32768 2048>2816 kb=4 bf16 act=silu opt=adam":
+        "fed8d384a72aa56c",
+    "update_dw 8x32768 1408>2048 kb=3 bf16 bm=256 opt=adam counted":
+        "15b6c33f63529317",
+    "update_gated_dw 8x32768 2048>1408 kb=4 bf16 bm=256 opt=adam counted":
+        "c670197c0ef99fb7",
+    # the population sweep: E=8 float32 members, 256-row steps and
+    # 512-row evals, densities 0.125, 0.25 and 0.5
+    "fwd 8x256 2560>6912 kb=2 f32 act=sigmoid bm=256 bn=2":
+        "cb4c031e40dbf2ff",
+    "fwd 8x256 6912>2560 kb=7 f32 act=sigmoid bm=128 bn=4":
+        "b98662b648befdec",
+    "dx 8x256 6912>2560 kb=7 fb=3 f32 act=sigmoid":
+        "ab43f38cebc7b448",
+    "update_dw 8x256 6912>2560 kb=7 f32 act=sigmoid bias opt=sgd":
+        "f33203c5f2d4d00b",
+    "update_dw 8x256 2560>6912 kb=2 f32 act=sigmoid bias opt=sgd":
+        "70eb6b5f14759130",
+    "fwd 8x512 2560>6912 kb=2 f32 act=sigmoid bm=256 bn=2":
+        "cbced894f5cc0fa3",
+    "fwd 8x512 6912>2560 kb=7 f32 act=sigmoid bm=128 bn=4":
+        "050472cb1bb07dd8",
+    "fwd 8x256 2560>6912 kb=5 f32 act=sigmoid bm=256 bn=2":
+        "07f022f283342365",
+    "fwd 8x256 6912>2560 kb=14 f32 act=sigmoid bm=128 bn=2":
+        "a3ad730b8d4316c1",
+    "dx 8x256 6912>2560 kb=14 fb=6 f32 act=sigmoid":
+        "c8ce2a04cef6526c",
+    "update_dw 8x256 6912>2560 kb=14 f32 act=sigmoid bias opt=sgd":
+        "fb6dc7583b4dba8f",
+    "update_dw 8x256 2560>6912 kb=5 f32 act=sigmoid bias opt=sgd":
+        "f1e426b09464bcba",
+    "fwd 8x512 2560>6912 kb=5 f32 act=sigmoid bm=256 bn=2":
+        "6ae86d6a4539d8ea",
+    "fwd 8x512 6912>2560 kb=14 f32 act=sigmoid bm=128 bn=2":
+        "e1ccb8b8a5cd6026",
+    "fwd 8x256 2560>6912 kb=10 f32 act=sigmoid bm=256 bn=2":
+        "b56b4e229bd3bb4e",
+    "fwd 8x256 6912>2560 kb=27 f32 act=sigmoid bm=128 bn=1":
+        "87b4fee87f3518f1",
+    "dx 8x256 6912>2560 kb=27 fb=10 f32 act=sigmoid":
+        "850f478f84373595",
+    "update_dw 8x256 6912>2560 kb=27 f32 act=sigmoid bias opt=sgd":
+        "cc06555fd01edeeb",
+    "update_dw 8x256 2560>6912 kb=10 f32 act=sigmoid bias opt=sgd":
+        "f439320df656f5eb",
+    "fwd 8x512 2560>6912 kb=10 f32 act=sigmoid bm=256 bn=2":
+        "6f0b6900652e677e",
+    "fwd 8x512 6912>2560 kb=27 f32 act=sigmoid bm=128 bn=1":
+        "e3aeb3ed9974f61a",
+    # the serve tick (32 slots) and prefill chunk (256 rows)
+    "fwd 1x32 2560>6912 kb=5 bf16 act=silu bm=32 bn=2":
+        "f2a4ed85086beaab",
+    "fwd 1x32 2560>6912 kb=5 bf16 bm=32 bn=2":
+        "cbc3575355b420a0",
+    "fwd 1x32 6912>2560 kb=14 bf16 bm=32 bn=4":
+        "65090c9eaf6e429f",
+    "fwd 1x256 2560>6912 kb=5 bf16 act=silu bm=256 bn=2":
+        "349580e9e7ec0d03",
+    "fwd 1x256 2560>6912 kb=5 bf16 bm=256 bn=2":
+        "746af83780a2bacc",
+    "fwd 1x256 6912>2560 kb=14 bf16 bm=256 bn=4":
+        "80059d576ae6a619",
+
+    # beyond the cells: the quantized forwards (tests and
+    # ServeConfig.quantize) and the update slot layouts no cell runs
+    "fwd_int8 1x32 2560>6912 kb=5 bf16 act=silu bm=32 bn=2":
+        "b77d0407dd21f73e",
+    "fwd_int8 1x32 2560>6912 kb=5 bf16 bm=32 bn=2 xscale":
+        "67ce79aa5562f4db",
+    "gated_fwd_int8 8x256 2048>1408 kb=4 bf16 bm=256 bn=1":
+        "4239cc9b58d67881",
+    "gated_fwd_int8 8x256 2048>1408 kb=4 bf16 bm=256 bn=1 xscale":
+        "daa983b0f028d285",
+    "gated_fwd 8x256 2048>1408 kb=4 bf16 bm=256 bn=1":
+        "b69a8e893f7243f2",
+    "dw 4x1024 2560>6912 kb=5 bf16 act=sigmoid bias":
+        "10b4f52d9261a099",
+    "update_dw 4x1024 2560>6912 kb=5 bf16 act=sigmoid bias opt=adam":
+        "0ff91aff0ac7eef0",
+    "update_dw 1x1024 2560>6912 kb=5 f32 act=gelu bias opt=mom nohealth":
+        "9a98b7e5f806d12c",
+    "update_gated_dw 4x1024 2560>6912 kb=5 bf16 opt=mom nohealth":
+        "8197f5440c6a0816",
+}
+
+
+def _cell_call(chip, case: str):
+    """(fn, operands) of one CELL_MOSAIC configuration."""
+    kernel, em, io, *rest = case.split()
+    E, M = (int(v) for v in em.split("x"))
+    n_in, n_out = (int(v) for v in io.split(">"))
+    opt = {"act": "none", "fb": 0, "opt": "sgd"}
+    for tok in rest:
+        k, eq, v = tok.partition("=")
+        opt[k] = (int(v) if v.isdigit() else v) if eq else True
+    i32, f32 = jnp.int32, jnp.float32
+    dt = jnp.bfloat16 if opt.get("bf16") else f32
+    quant = kernel.endswith("int8")
+    nob, nib, kb = n_out // BLOCK, n_in // BLOCK, opt["kb"]
+    x, y = chip((E, M, n_in), dt), chip((E, M, n_out), dt)
+    w = chip((E, nob, kb, BLOCK, BLOCK), jnp.int8 if quant else dt)
+    idx = chip((nob, kb), i32)
+    rev = {"rev_ob": chip((nib, opt["fb"]), i32),
+           "rev_t": chip((nib, opt["fb"]), i32), "rev_cnt": chip((nib,), i32)}
+    bias = chip((E, n_out), f32 if quant else dt)
+    act, save, has_b = opt["act"], opt.get("save", False), "bias" in opt
+    res = y if act != "none" else None
+    scale = chip((E, nob, kb), f32)
+    xs = chip((E,), f32) if "xscale" in opt else None
+    mom, vel = opt["opt"] in ("mom", "adam"), opt["opt"] == "adam"
+    slot = lambda on, a: chip(a.shape, f32) if on else None
+    upd = {"hyp": chip((E, bsm.HYP_K), f32),
+           "with_health": "nohealth" not in opt}
+    args = {
+        "fwd": dict(x=x, w=w, idx=idx, bias=bias, act=act, save_pre=save),
+        "gated_fwd": dict(x=x, wg=w, wi=w, idx=idx, save_res=save),
+        "fwd_int8": dict(x=x, wq=w, idx=idx, w_scale=scale, bias=bias,
+                         act=act, x_scale=xs),
+        "gated_fwd_int8": dict(x=x, wgq=w, wiq=w, idx=idx, wg_scale=scale,
+                               wi_scale=scale, x_scale=xs),
+        "dx": dict(dy=y, w=w, **rev, res=res, act=act),
+        "gated_dx": dict(dh=y, wg=w, wi=w, **rev, g=y, u=y),
+        "dw": dict(x=x, dy=y, idx=idx, res=res, act=act, with_bias=has_b),
+        "gated_dw": dict(x=x, dh=y, idx=idx, g=y, u=y),
+        "update_dw": dict(
+            x=x, dy=y, idx=idx, res=res, w=w, b=bias if has_b else None,
+            mom=slot(mom, w), mom_b=slot(mom and has_b, bias),
+            vel=slot(vel, w), vel_b=slot(vel and has_b, bias), act=act,
+            with_bias=has_b, **upd),
+        "update_gated_dw": dict(
+            x=x, dh=y, idx=idx, g=y, u=y, wg=w, wi=w, mg=slot(mom, w),
+            mi=slot(mom, w), vg=slot(vel, w), vi=slot(vel, w), **upd),
+    }[kernel]
+    args.update({k: opt[k] for k in ("bm", "bn") if k in opt})
+    if "counted" in opt:
+        args["counts"] = chip((E,), i32)
+    names = [k for k, v in args.items() if isinstance(v, jax.ShapeDtypeStruct)]
+    static = {k: v for k, v in args.items() if k not in names}
+
+    def call(*a):
+        return getattr(bsm, kernel)(**dict(zip(names, a)), **static)
+    return call, [args[k] for k in names]
+
+
+@pytest.mark.parametrize("case", sorted(CELL_MOSAIC))
+def test_cell_kernels_lower_as_before(chip, case):
+    """Each kernel configuration the cells launch lowers, under its own
+    name, to the Mosaic recorded for it: a change to the kernels' Python
+    that leaves these alone leaves the device's programs alone."""
+    fn, args = _cell_call(chip, case)
+    with jax.default_matmul_precision("default"):
+        txt = jax.jit(fn).lower(*args).as_text()
+    name = ("expert_" if "counted" in case.split() else "") + (
+        "junction_" + case.split()[0])
+    assert re.findall(r'kernel_name = "([^"]+)"', txt) == [name]
+    assert _mosaic_fingerprints(txt) == [CELL_MOSAIC[case]]
