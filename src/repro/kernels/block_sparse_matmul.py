@@ -48,49 +48,60 @@ ONE block pattern that rides once in scalar prefetch — the paper's
   input blocks arrive through scalar-prefetch-driven BlockSpec
   index_maps (the interleaver as DMA descriptor), and the bias gradient
   accumulates in the same pass.
-* **update_dw / update_gated_dw** — the fused **BP+UP** variants (the
-  paper's concurrent backprop + update pipeline): same grid and the same
-  M-innermost VMEM-scratch gradient reduction as ``dw``/``gated_dw``,
-  but instead of flushing the weight gradient to HBM the flush epilogue
-  applies the optimizer update **in-kernel** on the last M step.  The
-  optimizer is a STATIC switch keyed on which fp32 accumulator slots
-  ride along (``_epilogue_step``): momentum-only runs SGD(+momentum),
-  a second (m, v) slot pair runs Adam with per-step bias correction and
-  decoupled weight decay — the hyperparameters come from the per-unit
-  ``[E, HYP_K]`` hyp table in scalar prefetch (registry below).
-  Every parameter and accumulator operand comes in as a per-(e, ob)
-  resident tile and leaves as an output declared with
-  ``input_output_aliases``, so XLA rewrites the buffers in place —
-  neither ``dw`` nor a second copy of ``w`` ever touches HBM.  The
-  aliasing contract: every parameter operand maps to the output at
-  the same relative position, the input/output BlockSpecs are identical,
-  and each (e, ob) tile is read and written exactly once (the M loop is
-  innermost), so no grid step can observe a partially-updated tile.
-  Accumulator slots are fp32 even for bf16 params.
+* **update** — the fused **BP+UP** stage (the paper's concurrent
+  backprop + update pipeline): same grid and the same M-innermost
+  VMEM-scratch gradient reduction as ``dw``, but instead of flushing the
+  weight gradient to HBM the flush epilogue applies the optimizer update
+  **in-kernel** on the last M step.  The optimizer is a STATIC switch
+  keyed on which fp32 accumulator slots ride along (``_epilogue_step``):
+  momentum-only runs SGD(+momentum), a second (m, v) slot pair runs Adam
+  with per-step bias correction and decoupled weight decay — the
+  hyperparameters come from the per-unit ``[E, HYP_K]`` hyp table in
+  scalar prefetch (registry below).  Every parameter and accumulator
+  operand comes in as a per-(e, ob) resident tile and leaves as an
+  output declared with ``input_output_aliases``, so XLA rewrites the
+  buffers in place — neither ``dw`` nor a second copy of ``w`` ever
+  touches HBM.  The aliasing contract: every parameter operand maps to
+  the output at the same relative position, the input/output BlockSpecs
+  are identical, and each (e, ob) tile is read and written exactly once
+  (the M loop is innermost), so no grid step can observe a
+  partially-updated tile.  Accumulator slots are fp32 even for bf16
+  params.
 
   With ``with_health=True`` the update kernels additionally emit a tiny
   **non-aliased** ``[E, 1, 128]`` int32 health output — the in-kernel
   divergence detector.  Because the in-place update means a non-finite
   ``dw`` silently destroys the parameter state (there is no HBM gradient
   to inspect downstream), the flush epilogue OR-reduces ``isfinite``
-  over each post-momentum update tile (both branches for the gated
-  kernel, plus the bias update for biased layers) and accumulates a
-  per-unit count of bad (e, ob) tiles: ``health[e] > 0`` ⇔ unit e wrote
-  at least one non-finite parameter tile this step.  The slot is a
-  single revisited ``(1, 1, 128)`` lane row per unit (zeroed at the
-  first (ob, m) step, written only at flushes; a vector block because the
-  TPU cannot store a scalar to VMEM) — one VMEM compare per tile,
-  no gradient materialization, and the parameter outputs' aliasing
-  contract is untouched.  ``ops.junction_train_update`` surfaces it as
-  the cotangent of a dummy ``[E]`` health operand; ``train/steps.py``
-  aggregates it into ``metrics["nonfinite"]``.
-* **gated_{fwd,dx,dw}** — the GShard/SwiGLU gate
-  ``silu(x @ Wg) * (x @ Wi)`` fused into single passes: both fan-in
-  reductions accumulate side by side in VMEM scratch in the forward, and
-  the backward kernels recompute both branch gradients
-  (``dz_g = dh * u * silu'(g)``, ``dz_u = dh * silu(g)``) from the saved
-  ``(g, u)`` residuals, ``gated_dx`` double-buffering BOTH reverse
-  weight streams.
+  over each post-momentum update tile (every weight operand, plus the
+  bias update for biased layers) and accumulates a per-unit count of bad
+  (e, ob) tiles: ``health[e] > 0`` ⇔ unit e wrote at least one
+  non-finite parameter tile this step.  The slot is a single revisited
+  ``(1, 1, 128)`` lane row per unit (zeroed at the first (ob, m) step,
+  written only at flushes; a vector block because the TPU cannot store a
+  scalar to VMEM) — one VMEM compare per tile, no gradient
+  materialization, and the parameter outputs' aliasing contract is
+  untouched.  ``ops.junction_train_update`` surfaces it as the cotangent
+  of a dummy ``[E]`` health operand; ``train/steps.py`` aggregates it
+  into ``metrics["nonfinite"]``.
+
+Each product has one builder — ``junction_fwd``, ``junction_fwd_int8``,
+``junction_dx``, ``junction_dw``, ``junction_update`` — over a tuple of
+weight operands.  One operand is the plain junction; two, (Wg, Wi), are
+the GShard/SwiGLU gate ``silu(x @ Wg) * (x @ Wi)`` fused into single
+passes: both fan-in reductions accumulate side by side in VMEM scratch
+in the forward, and the backward recomputes both branch gradients
+(``dz_g = dh * u * silu'(g)``, ``dz_u = dh * silu(g)``) from the saved
+``(g, u)`` residuals, ``dx`` double-buffering BOTH reverse weight
+streams.  The live-count form (below) is the same builders given
+``counts``.  ``fwd``/``gated_fwd``, ``fwd_int8``/``gated_fwd_int8``,
+``dx``/``gated_dx``, ``dw``/``gated_dw`` and ``update_dw``/
+``update_gated_dw`` are the per-form entry points over them; ``ops``
+calls the update kernels through those two names, so a test can replace
+either.  Where the one- and two-operand kernels have always traced the
+same work in a different order, the builder keeps each order (the
+``order:`` comments): another order lowers to another Mosaic program,
+which ``tests/test_tpu_compile.py`` pins.
 
 Hyp-column registry and accumulator-slot layout
 -----------------------------------------------
@@ -149,8 +160,8 @@ the math matches ``optim.adam``'s two-pass update to fp32 round-off.
 Quantized inference variants (PR 8)
 -----------------------------------
 
-``fwd_int8`` / ``gated_fwd_int8`` / ``fwd_fxp`` are forward-only twins
-of ``fwd``/``gated_fwd`` for post-training-quantized weights
+``junction_fwd_int8`` and ``fwd_fxp`` are forward-only counterparts of
+``junction_fwd`` for post-training-quantized weights
 (``core/quantize.py`` builds the operands at checkpoint-load time; no
 custom_vjp — ``junction_train_update`` refuses integer codes):
 
@@ -176,14 +187,14 @@ custom_vjp — ``junction_train_update`` refuses integer codes):
   the saturate bound is static from the LUT length.  The runtime
   ``act`` is ignored — the LUT (baked at quantize time) IS the
   activation.
-* **gated_fwd_int8** — both expert branches dotted in int8 with
+* **two int8 operands** (the gate) — both branches dotted in int8 with
   per-branch scale prefetch leaves, shared in-body activation codes,
   two fp32 scratch accumulators, ``silu(g) * u`` epilogue unchanged.
 
 Tile tuning — one table for every configuration
 -----------------------------------------------
 
-``TUNE_TABLE`` maps a canonical 6-key
+``TUNE_TABLE`` maps a 6-key
 
     (E, M, nob, kb, bs, n_weight_operands) -> (bm, bn)
 
@@ -201,18 +212,13 @@ plain ``dw`` kernels so the fp32 gradient accumulation order matches
 the two-pass reference (updated params agree to fp32 round-off; only
 XLA's fma fusion of the epilogue differs between the two programs).
 
-To add a measured entry: run ``benchmarks/run.py --json`` on real
-hardware, pick the winning tiles for an ``engine.*`` row, and add the
-key to ``_SEED_ENTRIES`` below.  Legacy key schemas keep working —
-``canonical_tune_key`` migrates PR 1's 4-key ``(M, nob, kb, bs)`` and
-the transitional 5-key ``(E, M, nob, kb, bs)`` by pinning the missing
-dims to ``E=1`` / ``n_weight_operands=1`` — so entries derived from old
-``BENCH_*.json`` artifacts can be pasted in their original form.
+Table entries come from chip runs recorded in ``PERF.md``.
 Misses fall back to a VMEM-budget heuristic (``choose_tiles``).
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -220,8 +226,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-DEFAULT_BM = 128
 
 # Hyp-column registry: the [E, HYP_K] table's cross-layer ABI.  Append
 # only — see the module docstring's registry section before changing.
@@ -282,40 +286,12 @@ MAX_BN = 8
 WEIGHT_BUNDLE_BUDGET = 2 * 1024 * 1024  # per-step streamed-weight bound
 
 
-def canonical_tune_key(key) -> tuple[int, int, int, int, int, int]:
-    """Normalize a tune-table key to the canonical 6-tuple
-    ``(E, M, nob, kb, bs, n_weight_operands)``.
-
-    Migration shim for pre-unification schemas: PR 1 keyed single-junction
-    entries ``(M, nob, kb, bs)`` (implicitly E=1, one weight operand) and
-    PR 2 keyed expert entries ``(E, M, nob, kb, bs, n_weight_operands)``;
-    a transitional 5-key ``(E, M, nob, kb, bs)`` pins one weight operand.
-    """
-    key = tuple(int(v) for v in key)
-    if len(key) == 4:        # PR 1: (M, nob, kb, bs)
-        return (1, *key, 1)
-    if len(key) == 5:        # transitional: (E, M, nob, kb, bs)
-        return (*key, 1)
-    if len(key) == 6:        # canonical (PR 2 expert schema)
-        return key
-    raise ValueError(f"tune key {key!r}: expected 4, 5 or 6 ints")
-
-
-# Measured entries (BENCH_*.json artifacts are the data source).  Keys may
-# be written in any historical schema — canonical_tune_key migrates them.
-_SEED_ENTRIES: dict[tuple, tuple[int, int]] = {
-    # PR 1, paper MNIST junction (12544-sample epoch, 1024->512 @ kb=2)
-    (12544, 4, 2, 128): (512, 4),
-    # PR 1, transformer FFN up-projection bench shape (1024->4096 @ kb=2)
-    (4096, 32, 2, 128): (256, 8),
-    # PR 2, engine.moe bench gated entry kernel: E=4 experts, top-2 routed
-    # 2048 tokens (capacity rows M=1280), 1024->512 @ kb=2, two weight
-    # operands (wg + wi streamed per step)
-    (4, 1280, 4, 2, 128, 2): (256, 4),
-}
-
+# Measured entries: (E, M, nob, kb, bs, n_weight_operands) -> (bm, bn).
 TUNE_TABLE: dict[tuple[int, int, int, int, int, int], tuple[int, int]] = {
-    canonical_tune_key(k): v for k, v in _SEED_ENTRIES.items()
+    # paper MNIST junction (12544-sample epoch, 1024->512 @ kb=2)
+    (1, 12544, 4, 2, 128, 1): (512, 4),
+    # transformer FFN up-projection bench shape (1024->4096 @ kb=2)
+    (1, 4096, 32, 2, 128, 1): (256, 8),
 }
 
 
@@ -348,13 +324,12 @@ def choose_tiles(M: int, nob: int, kb: int, bs: int, nib: int,
                  itemsize: int = 4, *, E: int = 1,
                  n_weight_operands: int = 1) -> tuple[int, int]:
     """(bm, bn) for the fused forward of ANY junction configuration:
-    TUNE_TABLE first (canonical 6-key, legacy keys migrated), then the
+    TUNE_TABLE first, then the
     VMEM heuristic — bm bounded by the resident x row block (one expert's
     row block is resident per grid step, so the bound is E-independent),
     bn the largest power-of-two divisor of nob whose weight bundle fits
     the per-step budget split across the streamed weight tensors."""
-    hit = TUNE_TABLE.get(canonical_tune_key((E, M, nob, kb, bs,
-                                             n_weight_operands)))
+    hit = TUNE_TABLE.get((E, M, nob, kb, bs, n_weight_operands))
     if hit is not None:
         bm, bn = hit
         return max(16, min(bm, _round_up(M, 16))), bn
@@ -446,168 +421,147 @@ def _run_live(compute, cnt_ref, bm: int, row_axis: int):
 
 
 # ------------------------------------------------------------------ forward
-def fwd(x, w, idx, bias, *, act: str = "none", bm: int | None = None,
-        bn: int | None = None, save_pre: bool = False,
-        interpret: bool = False, counts=None):
-    """x [E, M, nib*bs], w [E, nob, kb, bs, bs], shared idx [nob, kb],
-    bias [E, nob*bs] -> act(x_e @ W_e + b_e) [E, M, nob*bs] per junction
-    unit (+ pre-activation if save_pre).
+def _gate(ws) -> str:
+    """The kernel-name infix of a junction with weight operands ``ws``."""
+    return "gated_" if len(ws) == 2 else ""
+
+
+def _fwd_tiles(M, nob, kb, bs, nib, itemsize, E, n_w, bm, bn):
+    """(bm, bn) of a forward call: the caller's, else ``choose_tiles``."""
+    cbm, cbn = choose_tiles(M, nob, kb, bs, nib, itemsize, E=E,
+                            n_weight_operands=n_w)
+    bm = cbm if bm is None else bm
+    bn = cbn if bn is None else bn
+    if nob % bn:
+        bn = 1
+    assert M % bm == 0, f"M={M} must be a multiple of bm={bm} (pad in ops.py)"
+    return bm, bn
+
+
+def _fwd_in_specs(bm, bn, nib, kb, bs, n_w, with_bias):
+    # the full activation row block, resident across bundle steps; then
+    # each weight operand's bundle, and the bias's tiles
+    specs = [pl.BlockSpec((1, bm, nib * bs), lambda e, m, o, *_: (e, m, 0))]
+    specs += [pl.BlockSpec((1, bn, kb, bs, bs),
+                           lambda e, m, o, *_: (e, o, 0, 0, 0))] * n_w
+    if with_bias:
+        specs.append(pl.BlockSpec((1, 1, bn * bs),
+                                  lambda e, m, o, *_: (e, 0, o)))
+    return specs
+
+
+def _fwd_out(acc_refs, b_ref, act, save_refs, o_ref):
+    """The forward's single output write from its fp32 reductions: bias
+    and activation for one weight operand, the gate ``silu(g) * u`` for
+    two.  The backward's residuals (the pre-activation, or g and u) are
+    stored first."""
+    zs = [r[...] for r in acc_refs]
+    if b_ref is not None:
+        zs[0] = zs[0] + b_ref[0].astype(jnp.float32)
+    for r, z in zip(save_refs, zs):
+        r[0] = z.astype(r.dtype)
+    out = act_fwd(zs[0], act if len(zs) == 1 else "silu")
+    if len(zs) == 2:
+        out = out * zs[1]
+    o_ref[0] = out.astype(o_ref.dtype)
+
+
+def junction_fwd(x, ws, idx, bias, *, act: str = "none",
+                 bm: int | None = None, bn: int | None = None,
+                 save: bool = False, interpret: bool = False, counts=None):
+    """The forward of one junction product per unit: x [E, M, nib*bs],
+    weight operands ``ws`` (each [E, nob, kb, bs, bs]), shared idx
+    [nob, kb] -> [E, M, nob*bs].  One operand gives act(x_e @ W_e + b_e)
+    with bias [E, nob*bs]; two, (Wg, Wi), give the SiLU gate
+    silu(x_e @ Wg_e) * (x_e @ Wi_e), no bias (``bias`` None, ``act``
+    unused), both fan-in reductions side by side in VMEM scratch.
 
     Grid (E, M/bm, nob/bn): the expert dimension is the outermost grid
     axis; the pattern rides once in scalar prefetch and is reused by every
     unit.  One step computes bn output tiles — the kb fan-in slots reduce
     in-body into fp32 VMEM scratch, epilogue fused, single output write.
-    ``counts`` [E] int32: live rows per unit (see "live row counts")."""
+    ``counts`` [E] int32: live rows per unit (see "live row counts").
+    Returns ``(out, saved)``: with ``save``, saved is the backward's
+    residuals, (pre-activation,) or (g, u); else None."""
+    n = len(ws)
     E, M, _ = x.shape
-    _, nob, kb, bs, _ = w.shape
+    _, nob, kb, bs, _ = ws[0].shape
     nib = x.shape[2] // bs
-    cbm, cbn = choose_tiles(M, nob, kb, bs, nib, x.dtype.itemsize, E=E)
-    bm = cbm if bm is None else bm
-    bn = cbn if bn is None else bn
-    if nob % bn:
-        bn = 1
-    assert M % bm == 0, f"M={M} must be a multiple of bm={bm} (pad in ops.py)"
+    bm, bn = _fwd_tiles(M, nob, kb, bs, nib, x.dtype.itemsize, E, n, bm, bn)
+    n_out = 1 + (n if save else 0)
+    with_bias = bias is not None
 
-    def fwd_kernel(idx_ref, *refs):
+    def kernel(idx_ref, *refs):
         cnt_ref = None
         if counts is not None:
             cnt_ref, *refs = refs
-        x_ref, w_ref, b_ref, *rest = refs
-        acc_ref = rest[-1]
-        o_ref = rest[0]
+        x_ref, *refs = refs
+        w_refs, refs = refs[:n], refs[n:]
+        b_ref = refs.pop(0) if with_bias else None
+        o_ref, save_refs, acc_refs = refs[0], refs[1:n_out], refs[n_out:]
         ob0 = pl.program_id(2) * bn
 
         def _compute():
             for j in range(bn):
-                acc = jnp.zeros((bm, bs), jnp.float32)
+                accs = [jnp.zeros((bm, bs), jnp.float32) for _ in ws]
                 for k in range(kb):
                     ib = idx_ref[ob0 + j, k]
                     xk = x_ref[0, :, pl.ds(ib * bs, bs)]
-                    acc = acc + jnp.dot(xk, w_ref[0, j, k],
+                    accs = [a + jnp.dot(xk, w_ref[0, j, k],
                                         preferred_element_type=jnp.float32)
-                acc_ref[:, j * bs:(j + 1) * bs] = acc
-            s = acc_ref[...] + b_ref[0].astype(jnp.float32)
-            if save_pre:
-                rest[1][0] = s.astype(rest[1].dtype)
-            o_ref[0] = act_fwd(s, act).astype(o_ref.dtype)
+                            for a, w_ref in zip(accs, w_refs)]
+                for acc_ref, a in zip(acc_refs, accs):
+                    acc_ref[:, j * bs:(j + 1) * bs] = a
+            _fwd_out(acc_refs, b_ref, act, save_refs, o_ref)
 
         _run_live(_compute, cnt_ref, bm, 1)
 
-    out_shape = [jax.ShapeDtypeStruct((E, M, nob * bs), x.dtype)]
-    out_specs = [pl.BlockSpec((1, bm, bn * bs), lambda e, m, o, idx: (e, m, o))]
-    if save_pre:
-        out_shape.append(jax.ShapeDtypeStruct((E, M, nob * bs), x.dtype))
-        out_specs.append(pl.BlockSpec((1, bm, bn * bs),
-                                      lambda e, m, o, idx: (e, m, o)))
-
-    in_specs = [
-        # full activation row block, resident across bundle steps
-        pl.BlockSpec((1, bm, nib * bs), lambda e, m, o, idx: (e, m, 0)),
-        pl.BlockSpec((1, bn, kb, bs, bs),
-                     lambda e, m, o, idx: (e, o, 0, 0, 0)),
-        pl.BlockSpec((1, 1, bn * bs), lambda e, m, o, idx: (e, 0, o)),
-    ]
+    operands = [x, *ws] + ([bias.reshape(E, 1, -1)] if with_bias else [])
+    out_specs = [pl.BlockSpec((1, bm, bn * bs),
+                              lambda e, m, o, *_: (e, m, o))] * n_out
     prefetch = (idx,) if counts is None else (idx, counts)
     rows = lambda im: _rows_outer(im, bm, nob // bn)
     outs = pl.pallas_call(
-        fwd_kernel,
-        name=_kernel_name("junction_fwd", counts),
+        kernel,
+        name=_kernel_name(f"junction_{_gate(ws)}fwd", counts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(E, M // bm, nob // bn),
-            in_specs=_counted_specs(in_specs, counts, rows),
+            in_specs=_counted_specs(
+                _fwd_in_specs(bm, bn, nib, kb, bs, n, with_bias), counts,
+                rows),
             out_specs=_counted_specs(out_specs, counts, rows),
-            scratch_shapes=[pltpu.VMEM((bm, bn * bs), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((bm, bn * bs), jnp.float32)
+                            for _ in ws],
         ),
-        out_shape=out_shape,
+        out_shape=[jax.ShapeDtypeStruct((E, M, nob * bs), x.dtype)] * n_out,
         interpret=interpret,
-    )(*prefetch, x, w, bias.reshape(E, 1, -1))
-    return (outs[0], outs[1]) if save_pre else (outs[0], None)
+    )(*prefetch, *operands)
+    return outs[0], (tuple(outs[1:]) if save else None)
+
+
+def fwd(x, w, idx, bias, *, act: str = "none", bm: int | None = None,
+        bn: int | None = None, save_pre: bool = False,
+        interpret: bool = False, counts=None):
+    """x [E, M, nib*bs], w [E, nob, kb, bs, bs], shared idx [nob, kb],
+    bias [E, nob*bs] -> (act(x_e @ W_e + b_e) [E, M, nob*bs], the
+    pre-activation if save_pre else None): ``junction_fwd`` of one
+    weight operand."""
+    y, saved = junction_fwd(x, (w,), idx, bias, act=act, bm=bm, bn=bn,
+                            save=save_pre, interpret=interpret, counts=counts)
+    return y, (saved[0] if save_pre else None)
 
 
 def gated_fwd(x, wg, wi, idx, *, bm: int | None = None,
               bn: int | None = None, save_res: bool = False,
               interpret: bool = False, counts=None):
-    """Fused SiLU-gate FFN entry: silu(x_e @ Wg_e) * (x_e @ Wi_e) in one
-    pass — both kb fan-in reductions accumulate side by side in VMEM
-    scratch, the gate epilogue fuses before the single output write.
-    Returns (h, g_pre, u) — the pre-activation g and the linear branch u
-    are emitted only when save_res (backward residuals).  ``counts``: live
-    rows per unit, as in ``fwd``."""
-    E, M, _ = x.shape
-    _, nob, kb, bs, _ = wg.shape
-    nib = x.shape[2] // bs
-    cbm, cbn = choose_tiles(M, nob, kb, bs, nib, x.dtype.itemsize, E=E,
-                            n_weight_operands=2)
-    bm = cbm if bm is None else bm
-    bn = cbn if bn is None else bn
-    if nob % bn:
-        bn = 1
-    assert M % bm == 0, f"M={M} must be a multiple of bm={bm} (pad in ops.py)"
-
-    def gated_fwd_kernel(idx_ref, *refs):
-        cnt_ref = None
-        if counts is not None:
-            cnt_ref, *refs = refs
-        x_ref, wg_ref, wi_ref, *rest = refs
-        accg_ref, accu_ref = rest[-2], rest[-1]
-        h_ref = rest[0]
-        ob0 = pl.program_id(2) * bn
-
-        def _compute():
-            for j in range(bn):
-                ag = jnp.zeros((bm, bs), jnp.float32)
-                au = jnp.zeros((bm, bs), jnp.float32)
-                for k in range(kb):
-                    ib = idx_ref[ob0 + j, k]
-                    xk = x_ref[0, :, pl.ds(ib * bs, bs)]
-                    ag = ag + jnp.dot(xk, wg_ref[0, j, k],
-                                      preferred_element_type=jnp.float32)
-                    au = au + jnp.dot(xk, wi_ref[0, j, k],
-                                      preferred_element_type=jnp.float32)
-                accg_ref[:, j * bs:(j + 1) * bs] = ag
-                accu_ref[:, j * bs:(j + 1) * bs] = au
-            g = accg_ref[...]
-            u = accu_ref[...]
-            if save_res:
-                rest[1][0] = g.astype(rest[1].dtype)
-                rest[2][0] = u.astype(rest[2].dtype)
-            h_ref[0] = (act_fwd(g, "silu") * u).astype(h_ref.dtype)
-
-        _run_live(_compute, cnt_ref, bm, 1)
-
-    out_shape = [jax.ShapeDtypeStruct((E, M, nob * bs), x.dtype)]
-    out_specs = [pl.BlockSpec((1, bm, bn * bs), lambda e, m, o, idx: (e, m, o))]
-    if save_res:
-        for _ in range(2):
-            out_shape.append(jax.ShapeDtypeStruct((E, M, nob * bs), x.dtype))
-            out_specs.append(pl.BlockSpec((1, bm, bn * bs),
-                                          lambda e, m, o, idx: (e, m, o)))
-
-    in_specs = [
-        pl.BlockSpec((1, bm, nib * bs), lambda e, m, o, idx: (e, m, 0)),
-        pl.BlockSpec((1, bn, kb, bs, bs),
-                     lambda e, m, o, idx: (e, o, 0, 0, 0)),
-        pl.BlockSpec((1, bn, kb, bs, bs),
-                     lambda e, m, o, idx: (e, o, 0, 0, 0)),
-    ]
-    prefetch = (idx,) if counts is None else (idx, counts)
-    rows = lambda im: _rows_outer(im, bm, nob // bn)
-    outs = pl.pallas_call(
-        gated_fwd_kernel,
-        name=_kernel_name("junction_gated_fwd", counts),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=(E, M // bm, nob // bn),
-            in_specs=_counted_specs(in_specs, counts, rows),
-            out_specs=_counted_specs(out_specs, counts, rows),
-            scratch_shapes=[pltpu.VMEM((bm, bn * bs), jnp.float32),
-                            pltpu.VMEM((bm, bn * bs), jnp.float32)],
-        ),
-        out_shape=out_shape,
-        interpret=interpret,
-    )(*prefetch, x, wg, wi)
-    return (outs[0], outs[1], outs[2]) if save_res else (outs[0], None, None)
+    """The fused SiLU-gate FFN entry silu(x_e @ Wg_e) * (x_e @ Wi_e):
+    ``junction_fwd`` of two weight operands.  Returns (h, g_pre, u) —
+    the gate's pre-activation g and the linear branch u (the backward's
+    residuals) only when save_res, else None."""
+    h, saved = junction_fwd(x, (wg, wi), idx, None, bm=bm, bn=bn,
+                            save=save_res, interpret=interpret, counts=counts)
+    return (h, *saved) if save_res else (h, None, None)
 
 
 # ------------------------------------------------------ quantized forward
@@ -622,71 +576,95 @@ def _slot_x_scale(xk, xs):
     return xs
 
 
-def fwd_int8(x, wq, idx, w_scale, bias, *, act: str = "none",
-             x_scale=None, bm: int | None = None, bn: int | None = None,
-             interpret: bool = False):
-    """int8 forward: x [E, M, nib*bs] fp, wq [E, nob, kb, bs, bs] int8,
-    shared idx [nob, kb], w_scale [E, nob, kb] f32 on scalar prefetch,
-    bias [E, nob*bs] -> act(dequant(xq @ wq) + b) [E, M, nob*bs].
-    Optional x_scale [E] f32 switches activation quantization from
-    dynamic per-row to calibrated static per-unit."""
+def junction_fwd_int8(x, ws, idx, scales, bias, *, act: str = "none",
+                      x_scale=None, bm: int | None = None,
+                      bn: int | None = None, interpret: bool = False):
+    """int8 forward: x [E, M, nib*bs] fp, int8 weight operands ``ws``
+    (each [E, nob, kb, bs, bs]) with their per-block scales ``scales``
+    (each [E, nob, kb] f32, on scalar prefetch), shared idx [nob, kb] ->
+    [E, M, nob*bs]: act(dequant(xq @ wq) + b) with bias [E, nob*bs] for
+    one operand, silu(dequant(xq @ wgq)) * dequant(xq @ wiq) for two
+    (``bias`` None), the activation codes shared.  Optional x_scale [E]
+    f32 switches activation quantization from dynamic per-row to
+    calibrated static per-unit."""
+    n = len(ws)
     E, M, _ = x.shape
-    _, nob, kb, bs, _ = wq.shape
+    _, nob, kb, bs, _ = ws[0].shape
     nib = x.shape[2] // bs
-    cbm, cbn = choose_tiles(M, nob, kb, bs, nib, x.dtype.itemsize, E=E)
-    bm = cbm if bm is None else bm
-    bn = cbn if bn is None else bn
-    if nob % bn:
-        bn = 1
-    assert M % bm == 0, f"M={M} must be a multiple of bm={bm} (pad in ops.py)"
+    bm, bn = _fwd_tiles(M, nob, kb, bs, nib, x.dtype.itemsize, E, n, bm, bn)
     has_xs = x_scale is not None
+    with_bias = bias is not None
 
-    def fwd_int8_kernel(*refs):
-        if has_xs:
-            idx_ref, sc_ref, xs_ref, x_ref, w_ref, b_ref, o_ref, acc_ref = refs
-        else:
-            idx_ref, sc_ref, x_ref, w_ref, b_ref, o_ref, acc_ref = refs
+    def kernel(idx_ref, *refs):
+        sc_refs, refs = refs[:n], list(refs[n:])
+        xs_ref = refs.pop(0) if has_xs else None
+        x_ref, *refs = refs
+        w_refs, refs = refs[:n], refs[n:]
+        b_ref = refs.pop(0) if with_bias else None
+        o_ref, *acc_refs = refs
         e = pl.program_id(0)
         ob0 = pl.program_id(2) * bn
         for j in range(bn):
-            acc = jnp.zeros((bm, bs), jnp.float32)
+            accs = [jnp.zeros((bm, bs), jnp.float32) for _ in ws]
             for k in range(kb):
                 ib = idx_ref[ob0 + j, k]
                 xk = x_ref[0, :, pl.ds(ib * bs, bs)].astype(jnp.float32)
                 sx = _slot_x_scale(xk, xs_ref[e] if has_xs else None)
                 xq = jnp.clip(jnp.round(xk / sx), -127, 127
                               ).astype(jnp.int8)
-                p = jnp.dot(xq, w_ref[0, j, k],
-                            preferred_element_type=jnp.int32)
+                ps = [jnp.dot(xq, w_ref[0, j, k],
+                              preferred_element_type=jnp.int32)
+                      for w_ref in w_refs]
                 # dequant into the fp32 reduction slot; grouping matches
                 # the jnp sim exactly (see core/quantize._int8_apply)
-                acc = acc + p.astype(jnp.float32) * (
+                accs = [a + p.astype(jnp.float32) * (
                     sx * sc_ref[e, ob0 + j, k])
-            acc_ref[:, j * bs:(j + 1) * bs] = acc
-        s = acc_ref[...] + b_ref[0].astype(jnp.float32)
-        o_ref[0] = act_fwd(s, act).astype(o_ref.dtype)
+                    for a, p, sc_ref in zip(accs, ps, sc_refs)]
+            for acc_ref, a in zip(acc_refs, accs):
+                acc_ref[:, j * bs:(j + 1) * bs] = a
+        _fwd_out(acc_refs, b_ref, act, (), o_ref)
 
-    prefetch = (idx, w_scale) + ((x_scale,) if has_xs else ())
+    prefetch = (idx, *scales) + ((x_scale,) if has_xs else ())
+    operands = [x, *ws] + ([bias.reshape(E, 1, -1)] if with_bias else [])
     out = pl.pallas_call(
-        fwd_int8_kernel,
-        name="junction_fwd_int8",
+        kernel,
+        name=f"junction_{_gate(ws)}fwd_int8",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(E, M // bm, nob // bn),
-            in_specs=[
-                pl.BlockSpec((1, bm, nib * bs), lambda e, m, o, *_: (e, m, 0)),
-                pl.BlockSpec((1, bn, kb, bs, bs),
-                             lambda e, m, o, *_: (e, o, 0, 0, 0)),
-                pl.BlockSpec((1, 1, bn * bs), lambda e, m, o, *_: (e, 0, o)),
-            ],
+            in_specs=_fwd_in_specs(bm, bn, nib, kb, bs, n, with_bias),
             out_specs=[pl.BlockSpec((1, bm, bn * bs),
                                     lambda e, m, o, *_: (e, m, o))],
-            scratch_shapes=[pltpu.VMEM((bm, bn * bs), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((bm, bn * bs), jnp.float32)
+                            for _ in ws],
         ),
         out_shape=[jax.ShapeDtypeStruct((E, M, nob * bs), x.dtype)],
         interpret=interpret,
-    )(*prefetch, x, wq, bias.reshape(E, 1, -1))
+    )(*prefetch, *operands)
     return out[0]
+
+
+def fwd_int8(x, wq, idx, w_scale, bias, *, act: str = "none",
+             x_scale=None, bm: int | None = None, bn: int | None = None,
+             interpret: bool = False):
+    """int8 forward of one weight operand: x [E, M, nib*bs] fp, wq
+    [E, nob, kb, bs, bs] int8, shared idx [nob, kb], w_scale [E, nob, kb]
+    f32, bias [E, nob*bs] -> act(dequant(xq @ wq) + b) [E, M, nob*bs]
+    (``junction_fwd_int8``)."""
+    return junction_fwd_int8(x, (wq,), idx, (w_scale,), bias, act=act,
+                             x_scale=x_scale, bm=bm, bn=bn,
+                             interpret=interpret)
+
+
+def gated_fwd_int8(x, wgq, wiq, idx, wg_scale, wi_scale, *, x_scale=None,
+                   bm: int | None = None, bn: int | None = None,
+                   interpret: bool = False):
+    """int8 twin of gated_fwd: silu(dequant(xq @ wgq)) * dequant(xq @
+    wiq), per-branch scales [E, nob, kb] (``junction_fwd_int8`` of two
+    weight operands)."""
+    return junction_fwd_int8(x, (wgq, wiq), idx, (wg_scale, wi_scale), None,
+                             x_scale=x_scale, bm=bm, bn=bn,
+                             interpret=interpret)
 
 
 def fwd_fxp(x, wq, idx, qfmt, lut, bias, *, bm: int | None = None,
@@ -757,81 +735,6 @@ def fwd_fxp(x, wq, idx, qfmt, lut, bias, *, bm: int | None = None,
     return out[0]
 
 
-def gated_fwd_int8(x, wgq, wiq, idx, wg_scale, wi_scale, *, x_scale=None,
-                   bm: int | None = None, bn: int | None = None,
-                   interpret: bool = False):
-    """int8 twin of gated_fwd: silu(dequant(xq @ wgq)) * dequant(xq @
-    wiq) — shared in-body activation codes, per-branch scale prefetch
-    leaves [E, nob, kb], two fp32 scratch accumulators."""
-    E, M, _ = x.shape
-    _, nob, kb, bs, _ = wgq.shape
-    nib = x.shape[2] // bs
-    cbm, cbn = choose_tiles(M, nob, kb, bs, nib, x.dtype.itemsize, E=E,
-                            n_weight_operands=2)
-    bm = cbm if bm is None else bm
-    bn = cbn if bn is None else bn
-    if nob % bn:
-        bn = 1
-    assert M % bm == 0, f"M={M} must be a multiple of bm={bm} (pad in ops.py)"
-    has_xs = x_scale is not None
-
-    def gated_fwd_int8_kernel(*refs):
-        if has_xs:
-            (idx_ref, scg_ref, sci_ref, xs_ref, x_ref, wg_ref, wi_ref,
-             h_ref, accg_ref, accu_ref) = refs
-        else:
-            (idx_ref, scg_ref, sci_ref, x_ref, wg_ref, wi_ref,
-             h_ref, accg_ref, accu_ref) = refs
-        e = pl.program_id(0)
-        ob0 = pl.program_id(2) * bn
-        for j in range(bn):
-            ag = jnp.zeros((bm, bs), jnp.float32)
-            au = jnp.zeros((bm, bs), jnp.float32)
-            for k in range(kb):
-                ib = idx_ref[ob0 + j, k]
-                xk = x_ref[0, :, pl.ds(ib * bs, bs)].astype(jnp.float32)
-                sx = _slot_x_scale(xk, xs_ref[e] if has_xs else None)
-                xq = jnp.clip(jnp.round(xk / sx), -127, 127
-                              ).astype(jnp.int8)
-                pg = jnp.dot(xq, wg_ref[0, j, k],
-                             preferred_element_type=jnp.int32)
-                pu = jnp.dot(xq, wi_ref[0, j, k],
-                             preferred_element_type=jnp.int32)
-                ag = ag + pg.astype(jnp.float32) * (
-                    sx * scg_ref[e, ob0 + j, k])
-                au = au + pu.astype(jnp.float32) * (
-                    sx * sci_ref[e, ob0 + j, k])
-            accg_ref[:, j * bs:(j + 1) * bs] = ag
-            accu_ref[:, j * bs:(j + 1) * bs] = au
-        g = accg_ref[...]
-        u = accu_ref[...]
-        h_ref[0] = (act_fwd(g, "silu") * u).astype(h_ref.dtype)
-
-    prefetch = (idx, wg_scale, wi_scale) + ((x_scale,) if has_xs else ())
-    out = pl.pallas_call(
-        gated_fwd_int8_kernel,
-        name="junction_gated_fwd_int8",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=(E, M // bm, nob // bn),
-            in_specs=[
-                pl.BlockSpec((1, bm, nib * bs), lambda e, m, o, *_: (e, m, 0)),
-                pl.BlockSpec((1, bn, kb, bs, bs),
-                             lambda e, m, o, *_: (e, o, 0, 0, 0)),
-                pl.BlockSpec((1, bn, kb, bs, bs),
-                             lambda e, m, o, *_: (e, o, 0, 0, 0)),
-            ],
-            out_specs=[pl.BlockSpec((1, bm, bn * bs),
-                                    lambda e, m, o, *_: (e, m, o))],
-            scratch_shapes=[pltpu.VMEM((bm, bn * bs), jnp.float32),
-                            pltpu.VMEM((bm, bn * bs), jnp.float32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((E, M, nob * bs), x.dtype)],
-        interpret=interpret,
-    )(*prefetch, x, wgq, wiq)
-    return out[0]
-
-
 # ------------------------------------------------------------------ dx
 def _rev_dot(dz, wb):
     """dz [bm, bs_out] x forward-layout bundle wb [bs_in, bs_out] ->
@@ -876,11 +779,22 @@ def _run_copies(copies, method: str):
             pl.when(cond)(fn)
 
 
-def dx(dy, w, rev_ob, rev_t, rev_cnt, res, *, act: str = "none",
-       bm: int | None = None, interpret: bool = False, counts=None):
+def _gate_dz(dh, g, u, dtype):
+    """Both branch gradients of the gate h = silu(g) * u from its fp32
+    residual tiles: dz_g = dh * u * silu'(g), dz_u = dh * silu(g)."""
+    dzg = (dh * u * act_bwd(g, "silu")).astype(dtype)
+    dzu = (dh * act_fwd(g, "silu")).astype(dtype)
+    return dzg, dzu
+
+
+def junction_dx(dy, ws, rev_ob, rev_t, rev_cnt, res, *, act: str = "none",
+                bm: int | None = None, interpret: bool = False, counts=None):
     """dy [E, M, nob*bs] -> dx [E, M, nib*bs] via the shared reverse
-    (fan-out) pattern against the forward-layout weights w
-    [E, nob, kb, bs, bs].
+    (fan-out) pattern against the forward-layout weight operands ``ws``
+    (each [E, nob, kb, bs, bs]).  ``res`` holds the forward's residuals:
+    none (act "none"), the pre-activation or output ``act`` needs, or the
+    gate's (g, u) with two operands, whose two branch gradients reduce
+    against their own reverse bundles in the same fb loop.
 
     The reverse weight bundles are DMA'd in-kernel: w stays in HBM
     (memory_space=ANY, viewed flat over the (nob, kb) slot dims) and the
@@ -894,27 +808,26 @@ def dx(dy, w, rev_ob, rev_t, rev_cnt, res, *, act: str = "none",
     slots (f >= rev_cnt[i], (0,0) sentinels) prefetch an in-bounds bundle
     whose contribution is where-masked, so zero-fan-out input blocks
     yield exact-zero dx rows even for non-finite dy.  The activation
-    gradient is recomputed per dy block from the residual.  ``counts``:
-    live rows per unit, as in ``fwd``."""
+    gradient is recomputed per dy block from the residuals.  ``counts``:
+    live rows per unit, as in ``junction_fwd``."""
+    n = len(ws)
     E, M, _ = dy.shape
-    _, nob, kb, bs, _ = w.shape
+    _, nob, kb, bs, _ = ws[0].shape
     nib, fb = rev_ob.shape
-    has_res = act != "none"
     if bm is None:
-        bm = bwd_bm(M, nob * (2 if has_res else 1), bs, dy.dtype.itemsize)
+        bm = bwd_bm(M, nob * (1 + len(res)), bs, dy.dtype.itemsize)
     assert M % bm == 0
     npair = (fb + 1) // 2
-    w_flat = w.reshape(E, nob * kb, bs, bs)
+    w_flats = [w.reshape(E, nob * kb, bs, bs) for w in ws]
 
-    def dx_kernel(rev_ob_ref, rev_t_ref, rev_cnt_ref, *refs):
+    def kernel(rev_ob_ref, rev_t_ref, rev_cnt_ref, *refs):
         cnt_ref = None
         if counts is not None:
             cnt_ref, *refs = refs
-        if has_res:
-            dy_ref, res_ref, w_hbm, o_ref, wbuf, sems = refs
-        else:
-            dy_ref, w_hbm, o_ref, wbuf, sems = refs
-            res_ref = None
+        dy_ref, *refs = refs
+        res_refs, refs = refs[:len(res)], refs[len(res):]
+        w_hbms, o_ref, bufs, sems = (refs[:n], refs[n], refs[n + 1:2 * n + 1],
+                                     refs[2 * n + 1])
         e = pl.program_id(0)
         i = pl.program_id(2)
         cnt = rev_cnt_ref[i]
@@ -924,8 +837,29 @@ def dx(dy, w, rev_ob, rev_t, rev_cnt, res, *, act: str = "none",
 
         def copies(buf, p):
             f0 = 2 * p
-            s1 = slot(f0 + 1) if f0 + 1 < fb else None
-            return _pair_copies(w_hbm, wbuf, sems, e, slot(f0), s1, buf)
+            second = lambda: slot(f0 + 1) if f0 + 1 < fb else None
+            if n == 1:   # order: the pair's second slot is read first
+                s1 = second()
+                return _pair_copies(w_hbms[0], bufs[0], sems, e, slot(f0), s1,
+                                    buf)
+            s0, s1 = slot(f0), second()
+            return [c for t in range(n) for c in _pair_copies(
+                w_hbms[t], bufs[t], sems.at[t], e, s0, s1, buf)]
+
+        def dzs(f):
+            """The pre-activation gradient tiles of reverse slot f, one
+            per weight operand."""
+            if n == 2:
+                cols = pl.ds(rev_ob_ref[i, f] * bs, bs)
+                return _gate_dz(*(r[0, :, cols].astype(jnp.float32)
+                                  for r in (dy_ref, *res_refs)), dy_ref.dtype)
+            ob = rev_ob_ref[i, f]
+            dyb = dy_ref[0, :, pl.ds(ob * bs, bs)]
+            if not res_refs:
+                return (dyb,)
+            gr = act_bwd(
+                res_refs[0][0, :, pl.ds(ob * bs, bs)].astype(jnp.float32), act)
+            return ((dyb.astype(jnp.float32) * gr).astype(dyb.dtype),)
 
         def _compute():
             _run_copies(copies(0, 0), "start")
@@ -936,110 +870,14 @@ def dx(dy, w, rev_ob, rev_t, rev_cnt, res, *, act: str = "none",
                 _run_copies(copies(p % 2, p), "wait")
                 for j in range(min(2, fb - 2 * p)):
                     f = 2 * p + j
-                    ob = rev_ob_ref[i, f]
-                    dyb = dy_ref[0, :, pl.ds(ob * bs, bs)]
-                    if has_res:
-                        gr = act_bwd(
-                            res_ref[0, :, pl.ds(ob * bs, bs)].astype(jnp.float32),
-                            act)
-                        dz = (dyb.astype(jnp.float32) * gr).astype(dyb.dtype)
+                    dz = dzs(f)
+                    if n == 1:   # order: the mask before the product
+                        acc = acc + jnp.where(
+                            f < cnt, _rev_dot(dz[0], bufs[0][p % 2, j]), 0.0)
                     else:
-                        dz = dyb
-                    acc = acc + jnp.where(f < cnt,
-                                          _rev_dot(dz, wbuf[p % 2, j]), 0.0)
-            o_ref[0] = acc.astype(o_ref.dtype)
-
-        _run_live(_compute, cnt_ref, bm, 1)
-
-    in_specs = [pl.BlockSpec((1, bm, nob * bs),
-                             lambda e, m, i, *_: (e, m, 0))]
-    inputs = [dy]
-    if has_res:
-        in_specs.append(pl.BlockSpec((1, bm, nob * bs),
-                                     lambda e, m, i, *_: (e, m, 0)))
-        inputs.append(res)
-    in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
-    inputs.append(w_flat)
-
-    out_specs = [pl.BlockSpec((1, bm, bs), lambda e, m, i, *_: (e, m, i))]
-    prefetch = (rev_ob, rev_t, rev_cnt)
-    if counts is not None:
-        prefetch += (counts,)
-    rows = lambda im: _rows_outer(im, bm, nib)
-    return pl.pallas_call(
-        dx_kernel,
-        name=_kernel_name("junction_dx", counts),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=(E, M // bm, nib),
-            in_specs=_counted_specs(in_specs, counts, rows),
-            out_specs=_counted_specs(out_specs, counts, rows)[0],
-            scratch_shapes=[pltpu.VMEM((2, 2, bs, bs), w.dtype),
-                            pltpu.SemaphoreType.DMA((2, 2))],
-        ),
-        out_shape=jax.ShapeDtypeStruct((E, M, nib * bs), dy.dtype),
-        interpret=interpret,
-    )(*prefetch, *inputs)
-
-
-def gated_dx(dh, wg, wi, rev_ob, rev_t, rev_cnt, g, u, *,
-             bm: int | None = None, interpret: bool = False, counts=None):
-    """Fused two-branch dx for the gated FFN: both branch grads
-    (dz_g = dh * u * silu'(g), dz_u = dh * silu(g)) are recomputed per dy
-    block from the saved residuals and reduced against their reverse
-    bundles in the same fb loop — one pass over dh/g/u per input block,
-    with BOTH weight streams double-buffered HBM→VMEM in-kernel and the
-    same pairwise contiguous-run descriptor coalescing as ``dx``.
-    ``counts``: live rows per unit, as in ``fwd``."""
-    E, M, _ = dh.shape
-    _, nob, kb, bs, _ = wg.shape
-    nib, fb = rev_ob.shape
-    if bm is None:
-        bm = bwd_bm(M, 3 * nob, bs, dh.dtype.itemsize)
-    assert M % bm == 0
-    npair = (fb + 1) // 2
-    wg_flat = wg.reshape(E, nob * kb, bs, bs)
-    wi_flat = wi.reshape(E, nob * kb, bs, bs)
-
-    def gated_dx_kernel(rev_ob_ref, rev_t_ref, rev_cnt_ref, *refs):
-        cnt_ref = None
-        if counts is not None:
-            cnt_ref, *refs = refs
-        (dh_ref, g_ref, u_ref, wg_hbm, wi_hbm, o_ref, wgbuf, wibuf,
-         sems) = refs
-        e = pl.program_id(0)
-        i = pl.program_id(2)
-        cnt = rev_cnt_ref[i]
-
-        def slot(f):
-            return rev_ob_ref[i, f] * kb + rev_t_ref[i, f]
-
-        def copies(buf, p):
-            f0 = 2 * p
-            s0 = slot(f0)
-            s1 = slot(f0 + 1) if f0 + 1 < fb else None
-            return (_pair_copies(wg_hbm, wgbuf, sems.at[0], e, s0, s1, buf)
-                    + _pair_copies(wi_hbm, wibuf, sems.at[1], e, s0, s1, buf))
-
-        def _compute():
-            _run_copies(copies(0, 0), "start")
-            acc = jnp.zeros((bm, bs), jnp.float32)
-            for p in range(npair):
-                if p + 1 < npair:
-                    _run_copies(copies((p + 1) % 2, p + 1), "start")
-                _run_copies(copies(p % 2, p), "wait")
-                for j in range(min(2, fb - 2 * p)):
-                    f = 2 * p + j
-                    cols = pl.ds(rev_ob_ref[i, f] * bs, bs)
-                    dhb = dh_ref[0, :, cols].astype(jnp.float32)
-                    gb = g_ref[0, :, cols].astype(jnp.float32)
-                    ub = u_ref[0, :, cols].astype(jnp.float32)
-                    dzg = (dhb * ub * act_bwd(gb, "silu")
-                           ).astype(dh_ref.dtype)
-                    dzu = (dhb * act_fwd(gb, "silu")).astype(dh_ref.dtype)
-                    part = (_rev_dot(dzg, wgbuf[p % 2, j])
-                            + _rev_dot(dzu, wibuf[p % 2, j]))
-                    acc = acc + jnp.where(f < cnt, part, 0.0)
+                        part = (_rev_dot(dz[0], bufs[0][p % 2, j])
+                                + _rev_dot(dz[1], bufs[1][p % 2, j]))
+                        acc = acc + jnp.where(f < cnt, part, 0.0)
             o_ref[0] = acc.astype(o_ref.dtype)
 
         _run_live(_compute, cnt_ref, bm, 1)
@@ -1052,216 +890,199 @@ def gated_dx(dh, wg, wi, rev_ob, rev_t, rev_cnt, g, u, *,
         prefetch += (counts,)
     rows = lambda im: _rows_outer(im, bm, nib)
     return pl.pallas_call(
-        gated_dx_kernel,
-        name=_kernel_name("junction_gated_dx", counts),
+        kernel,
+        name=_kernel_name(f"junction_{_gate(ws)}dx", counts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(E, M // bm, nib),
-            in_specs=_counted_specs([row, row, row, hbm, hbm], counts, rows),
+            in_specs=_counted_specs([row] * (1 + len(res)) + [hbm] * n,
+                                    counts, rows),
             out_specs=_counted_specs(out_specs, counts, rows)[0],
-            scratch_shapes=[pltpu.VMEM((2, 2, bs, bs), wg.dtype),
-                            pltpu.VMEM((2, 2, bs, bs), wi.dtype),
-                            pltpu.SemaphoreType.DMA((2, 2, 2))],
+            scratch_shapes=[pltpu.VMEM((2, 2, bs, bs), w.dtype) for w in ws]
+            + [pltpu.SemaphoreType.DMA((2, 2) if n == 1 else (n, 2, 2))],
         ),
-        out_shape=jax.ShapeDtypeStruct((E, M, nib * bs), dh.dtype),
+        out_shape=jax.ShapeDtypeStruct((E, M, nib * bs), dy.dtype),
         interpret=interpret,
-    )(*prefetch, dh, g, u, wg_flat, wi_flat)
+    )(*prefetch, dy, *res, *w_flats)
+
+
+def dx(dy, w, rev_ob, rev_t, rev_cnt, res, *, act: str = "none",
+       bm: int | None = None, interpret: bool = False, counts=None):
+    """dy [E, M, nob*bs] -> dx [E, M, nib*bs] against one weight operand
+    w [E, nob, kb, bs, bs]; ``res`` the residual ``act`` needs (ignored
+    for act "none"): ``junction_dx``."""
+    return junction_dx(dy, (w,), rev_ob, rev_t, rev_cnt,
+                       () if act == "none" else (res,), act=act, bm=bm,
+                       interpret=interpret, counts=counts)
+
+
+def gated_dx(dh, wg, wi, rev_ob, rev_t, rev_cnt, g, u, *,
+             bm: int | None = None, interpret: bool = False, counts=None):
+    """The gated FFN's dx: both branch grads (dz_g = dh * u * silu'(g),
+    dz_u = dh * silu(g)) recomputed per dy block from the saved
+    residuals, both weight streams double-buffered HBM→VMEM in-kernel:
+    ``junction_dx`` of two weight operands."""
+    return junction_dx(dh, (wg, wi), rev_ob, rev_t, rev_cnt, (g, u), bm=bm,
+                       interpret=interpret, counts=counts)
 
 
 # ------------------------------------------------------------------ dw (+db)
-def dw(x, dy, idx, res, *, act: str = "none", with_bias: bool = True,
-       bm: int | None = None, interpret: bool = False, counts=None):
-    """(dw [E, nob, kb, bs, bs] fp32, db [E, nob*bs] fp32 or None) — grid
-    (E, nob, M/bm) with the M reduction innermost into fp32 VMEM scratch,
-    flushed once per (unit, output block).  The kb gathered input blocks
-    arrive through scalar-prefetch BlockSpec index_maps — the interleaver
-    as a DMA descriptor — and, for biased layers, db accumulates from the
-    same fused dz prologue (with_bias=False skips it entirely).
-    ``counts``: live rows per unit, as in ``fwd`` (dead row tiles add
-    nothing)."""
+def _grad_specs(bm: int, bs: int, kb: int, n_rows: int):
+    """BlockSpecs of a weight-gradient grid (E, nob, M/bm)'s row inputs:
+    the ``n_rows`` [bm, bs] tiles of output block o (dy and residuals),
+    then the kb gathered input blocks, whose index maps read the pattern
+    from scalar prefetch — the interleaver as a DMA descriptor."""
+    row = pl.BlockSpec((1, bm, bs), lambda e, o, m, *_: (e, m, o))
+    return [row] * n_rows + [
+        pl.BlockSpec((1, bm, bs),
+                     lambda e, o, m, idx, *_, k=k: (e, m, idx[o, k]))
+        for k in range(kb)]
+
+
+def _zero_grads(accs, accb_ref):
+    for acc in accs:
+        acc[...] = jnp.zeros(acc.shape, jnp.float32)
+    if accb_ref is not None:
+        accb_ref[...] = jnp.zeros(accb_ref.shape, jnp.float32)
+
+
+def _grad_tile(dy_ref, res_refs, x_refs, accs, accb_ref, act):
+    """One row tile of the weight-gradient reduction: the pre-activation
+    gradient recomputed from the residuals (the activation's, or both gate
+    branches' from (g, u) for two weight operands), every fan-in slot's
+    ``x^T dz`` added into each operand's fp32 VMEM scratch, and the bias
+    gradient into its own."""
+    two = len(accs) == 2
+    dzf = None
+    if two:
+        dzs = _gate_dz(*(r[0].astype(jnp.float32)
+                         for r in (dy_ref, *res_refs)), dy_ref.dtype)
+    elif res_refs:
+        grad = act_bwd(res_refs[0][0].astype(jnp.float32), act)
+        dzf = dy_ref[0].astype(jnp.float32) * grad
+        dzs = (dzf.astype(dy_ref.dtype),)
+    else:
+        dzs = (dy_ref[0],)
+    for k, x_ref in enumerate(x_refs):
+        # order: two operands read the x tile once, before both
+        # accumulators; one reads it after its accumulator
+        shared = x_ref[0].T if two else None
+        for acc, dz in zip(accs, dzs):
+            acc[k] = acc[k] + jnp.dot(
+                x_ref[0].T if shared is None else shared, dz,
+                preferred_element_type=jnp.float32)
+    if accb_ref is not None:
+        s = dzf if dzf is not None else dy_ref[0].astype(jnp.float32)
+        accb_ref[...] = accb_ref[...] + jnp.sum(s, axis=0, keepdims=True)
+
+
+def _grad_bm(M: int, kb: int, bs: int, n_w: int, itemsize: int) -> int:
+    """The weight-gradient kernels' default row tile (kb + 3 row blocks
+    for one weight operand, kb + 5 for two)."""
+    return bwd_bm(M, kb + 1 + 2 * n_w, bs, itemsize)
+
+
+def junction_dw(x, dy, idx, res, *, act: str = "none",
+                with_bias: bool = True, bm: int | None = None,
+                interpret: bool = False, counts=None):
+    """(dws, db): the weight gradients [E, nob, kb, bs, bs] fp32, one per
+    weight operand, and db [E, nob*bs] fp32 (None without ``with_bias``).
+    ``res`` holds the forward's residuals as ``junction_dx`` takes them;
+    the gate's (g, u) give two weight gradients (and no bias).
+
+    Grid (E, nob, M/bm) with the M reduction innermost into fp32 VMEM
+    scratch, flushed once per (unit, output block).  The kb gathered
+    input blocks arrive through scalar-prefetch BlockSpec index_maps —
+    the interleaver as a DMA descriptor — and, for biased layers, db
+    accumulates from the same fused dz prologue.  ``counts``: live rows
+    per unit, as in ``junction_fwd`` (dead row tiles add nothing)."""
     E, M, _ = x.shape
     nob, kb = idx.shape
     bs = dy.shape[2] // nob
-    has_res = act != "none"
+    n = 2 if len(res) == 2 else 1
     if bm is None:
-        bm = bwd_bm(M, kb + 3, bs, x.dtype.itemsize)
+        bm = _grad_bm(M, kb, bs, n, x.dtype.itemsize)
     assert M % bm == 0
     nm = M // bm
+    n_in, n_out = 1 + len(res) + kb, n + with_bias
 
-    def dw_kernel(idx_ref, *refs):
+    def kernel(idx_ref, *refs):
         cnt_ref = None
         if counts is not None:
             cnt_ref, *refs = refs
-        n_in = (2 if has_res else 1) + kb
-        dy_ref = refs[0]
-        res_ref = refs[1] if has_res else None
-        x_refs = refs[n_in - kb:n_in]
-        if with_bias:
-            dw_ref, db_ref, accw_ref, accb_ref = refs[n_in:]
-        else:
-            dw_ref, accw_ref = refs[n_in:]
+        dy_ref, res_refs = refs[0], refs[1:n_in - kb]
+        x_refs, outs = refs[n_in - kb:n_in], refs[n_in:n_in + n_out]
+        accs = refs[n_in + n_out:]
+        accb_ref = accs[n] if with_bias else None
+        accs = accs[:n]
         m = pl.program_id(2)
 
         @pl.when(m == 0)
         def _zero():
-            accw_ref[...] = jnp.zeros((kb, bs, bs), jnp.float32)
-            if with_bias:
-                accb_ref[...] = jnp.zeros((1, bs), jnp.float32)
+            _zero_grads(accs, accb_ref)
 
-        def _accumulate():
-            if has_res:
-                grad = act_bwd(res_ref[0].astype(jnp.float32), act)
-                dzf = dy_ref[0].astype(jnp.float32) * grad
-                dz = dzf.astype(dy_ref.dtype)
-            else:
-                dzf = None
-                dz = dy_ref[0]
-            for k in range(kb):
-                accw_ref[k] = accw_ref[k] + jnp.dot(
-                    x_refs[k][0].T, dz, preferred_element_type=jnp.float32)
-            if with_bias:
-                s = dzf if dzf is not None else dy_ref[0].astype(jnp.float32)
-                accb_ref[...] = accb_ref[...] + jnp.sum(s, axis=0,
-                                                        keepdims=True)
-
-        _run_live(_accumulate, cnt_ref, bm, 2)
+        _run_live(lambda: _grad_tile(dy_ref, res_refs, x_refs, accs, accb_ref,
+                                     act), cnt_ref, bm, 2)
 
         @pl.when(m == nm - 1)
         def _flush():
-            dw_ref[...] = accw_ref[...][None, None]
+            for o_ref, acc in zip(outs, accs):
+                o_ref[...] = acc[...][None, None]
             if with_bias:
-                db_ref[0] = accb_ref[...]
-
-    in_specs = [pl.BlockSpec((1, bm, bs), lambda e, o, m, idx: (e, m, o))]
-    inputs = [dy]
-    if has_res:
-        in_specs.append(pl.BlockSpec((1, bm, bs),
-                                     lambda e, o, m, idx: (e, m, o)))
-        inputs.append(res)
-    for k in range(kb):
-        in_specs.append(pl.BlockSpec(
-            (1, bm, bs), lambda e, o, m, idx, k=k: (e, m, idx[o, k])))
-        inputs.append(x)
+                outs[n][0] = accb_ref[...]
 
     out_specs = [pl.BlockSpec((1, 1, kb, bs, bs),
-                              lambda e, o, m, idx: (e, o, 0, 0, 0))]
-    out_shape = [jax.ShapeDtypeStruct((E, nob, kb, bs, bs), jnp.float32)]
-    scratch = [pltpu.VMEM((kb, bs, bs), jnp.float32)]
+                              lambda e, o, m, *_: (e, o, 0, 0, 0))] * n
+    out_shape = [jax.ShapeDtypeStruct((E, nob, kb, bs, bs), jnp.float32)] * n
+    scratch = [pltpu.VMEM((kb, bs, bs), jnp.float32) for _ in range(n)]
     if with_bias:
-        out_specs.append(pl.BlockSpec((1, 1, bs), lambda e, o, m, idx: (e, 0, o)))
+        out_specs.append(pl.BlockSpec((1, 1, bs),
+                                      lambda e, o, m, *_: (e, 0, o)))
         out_shape.append(jax.ShapeDtypeStruct((E, 1, nob * bs), jnp.float32))
         scratch.append(pltpu.VMEM((1, bs), jnp.float32))
 
     prefetch = (idx,) if counts is None else (idx, counts)
     rows = lambda im: _rows_inner(im, bm)
     outs = pl.pallas_call(
-        dw_kernel,
-        name=_kernel_name("junction_dw", counts),
+        kernel,
+        name=_kernel_name("junction_gated_dw" if n == 2 else "junction_dw",
+                          counts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(prefetch),
             grid=(E, nob, nm),
-            in_specs=_counted_specs(in_specs, counts, rows),
+            in_specs=_counted_specs(_grad_specs(bm, bs, kb, 1 + len(res)),
+                                    counts, rows),
             out_specs=_counted_specs(out_specs, counts, rows),
             scratch_shapes=scratch,
         ),
         out_shape=out_shape,
         interpret=interpret,
-    )(*prefetch, *inputs)
-    if with_bias:
-        return outs[0], outs[1].reshape(E, -1)
-    return outs[0], None
+    )(*prefetch, dy, *res, *[x] * kb)
+    db = outs[n].reshape(E, -1) if with_bias else None
+    return tuple(outs[:n]), db
 
 
-def _gated_accumulate(dh_ref, g_ref, u_ref, x_refs, accg_ref, accu_ref,
-                      kb: int):
-    """One row tile of the gated junction's two weight-gradient
-    reductions: both branch grads recomputed from the (g, u) residuals,
-    then every fan-in slot's ``x^T dz`` added into its VMEM scratch."""
-    dhb = dh_ref[0].astype(jnp.float32)
-    gb = g_ref[0].astype(jnp.float32)
-    ub = u_ref[0].astype(jnp.float32)
-    dzg = (dhb * ub * act_bwd(gb, "silu")).astype(dh_ref.dtype)
-    dzu = (dhb * act_fwd(gb, "silu")).astype(dh_ref.dtype)
-    for k in range(kb):
-        xT = x_refs[k][0].T
-        accg_ref[k] = accg_ref[k] + jnp.dot(
-            xT, dzg, preferred_element_type=jnp.float32)
-        accu_ref[k] = accu_ref[k] + jnp.dot(
-            xT, dzu, preferred_element_type=jnp.float32)
+def dw(x, dy, idx, res, *, act: str = "none", with_bias: bool = True,
+       bm: int | None = None, interpret: bool = False, counts=None):
+    """(dw [E, nob, kb, bs, bs] fp32, db [E, nob*bs] fp32 or None) of one
+    weight operand, ``res`` the residual ``act`` needs (ignored for act
+    "none"): ``junction_dw``."""
+    (dwv,), db = junction_dw(x, dy, idx, () if act == "none" else (res,),
+                             act=act, with_bias=with_bias, bm=bm,
+                             interpret=interpret, counts=counts)
+    return dwv, db
 
 
 def gated_dw(x, dh, idx, g, u, *, bm: int | None = None,
              interpret: bool = False, counts=None):
-    """(dwg, dwi) [E, nob, kb, bs, bs] fp32 for the fused gated FFN — the
-    two branch grads are recomputed in the prologue from the (g, u)
-    residuals and both M reductions accumulate innermost into separate
-    VMEM scratch buffers, flushed once per (unit, output block).
-    ``counts``: live rows per unit, as in ``dw``."""
-    E, M, _ = x.shape
-    nob, kb = idx.shape
-    bs = dh.shape[2] // nob
-    if bm is None:
-        bm = bwd_bm(M, kb + 5, bs, x.dtype.itemsize)
-    assert M % bm == 0
-    nm = M // bm
-
-    def gated_dw_kernel(idx_ref, *refs):
-        cnt_ref = None
-        if counts is not None:
-            cnt_ref, *refs = refs
-        dh_ref, g_ref, u_ref = refs[:3]
-        x_refs = refs[3:3 + kb]
-        dwg_ref, dwi_ref, accg_ref, accu_ref = refs[3 + kb:]
-        m = pl.program_id(2)
-
-        @pl.when(m == 0)
-        def _zero():
-            accg_ref[...] = jnp.zeros((kb, bs, bs), jnp.float32)
-            accu_ref[...] = jnp.zeros((kb, bs, bs), jnp.float32)
-
-        def _accumulate():
-            _gated_accumulate(dh_ref, g_ref, u_ref, x_refs, accg_ref,
-                              accu_ref, kb)
-
-        _run_live(_accumulate, cnt_ref, bm, 2)
-
-        @pl.when(m == nm - 1)
-        def _flush():
-            dwg_ref[...] = accg_ref[...][None, None]
-            dwi_ref[...] = accu_ref[...][None, None]
-
-    row = pl.BlockSpec((1, bm, bs), lambda e, o, m, idx: (e, m, o))
-    in_specs = [row, row, row]
-    inputs = [dh, g, u]
-    for k in range(kb):
-        in_specs.append(pl.BlockSpec(
-            (1, bm, bs), lambda e, o, m, idx, k=k: (e, m, idx[o, k])))
-        inputs.append(x)
-
-    wout = pl.BlockSpec((1, 1, kb, bs, bs), lambda e, o, m, idx: (e, o, 0, 0, 0))
-    prefetch = (idx,) if counts is None else (idx, counts)
-    rows = lambda im: _rows_inner(im, bm)
-    outs = pl.pallas_call(
-        gated_dw_kernel,
-        name=_kernel_name("junction_gated_dw", counts),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=(E, nob, nm),
-            in_specs=_counted_specs(in_specs, counts, rows),
-            out_specs=_counted_specs([wout, wout], counts, rows),
-            scratch_shapes=[pltpu.VMEM((kb, bs, bs), jnp.float32),
-                            pltpu.VMEM((kb, bs, bs), jnp.float32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((E, nob, kb, bs, bs), jnp.float32),
-                   jax.ShapeDtypeStruct((E, nob, kb, bs, bs), jnp.float32)],
-        interpret=interpret,
-    )(*prefetch, *inputs)
-    return outs[0], outs[1]
+    """(dwg, dwi) [E, nob, kb, bs, bs] fp32 for the fused gated FFN, both
+    branch grads recomputed in the prologue from the (g, u) residuals:
+    ``junction_dw`` of the gate."""
+    return junction_dw(x, dh, idx, (g, u), with_bias=False, bm=bm,
+                       interpret=interpret, counts=counts)[0]
 
 
 # --------------------------------------------------- fused BP+UP (update_dw)
-N_SCALAR_PREFETCH_UPDATE = 2    # (idx, hyp) — alias indices count these
-
 # The health detector's output is one int32 lane row per unit, [E, 1, 128]:
 # a vector-shaped block the TPU can store to (Mosaic refuses scalar stores
 # to VMEM), revisited across every (ob, m) step of unit e.  Every lane of
@@ -1351,95 +1172,103 @@ def _epilogue_step(h, acc, w32, mom, vel, with_health):
     return new_w32, m1, v2, ok
 
 
-def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
-              vel_b=None, act: str = "none", with_bias: bool = True,
-              bm: int | None = None, with_health: bool = False,
-              interpret: bool = False, counts=None):
-    """The fused UP stage: the ``dw`` gradient reduction with the
-    optimizer update applied in the flush epilogue — returns
-    ``(new_w, new_b, new_mom, new_mom_b, new_vel, new_vel_b, health)``
-    (None where the operand is absent) instead of ``(dw, db)``, with
-    every parameter operand aliased to its output
-    (``input_output_aliases``), so the weight gradient never leaves VMEM
-    scratch and the parameters are rewritten in place.
+def _apply_updates(h, accs, ins, outs, ix, with_health):
+    """The flush epilogue's optimizer step for one family of parameter
+    tiles (a junction's weight operands, or its bias): ``ins``/``outs``
+    hold each tensor's (param, slots...) refs, read and written at block
+    index ``ix``.  Every tensor's step first, then the slots' writes, then
+    the parameters'.  Returns the tiles' health verdicts."""
+    steps = [_epilogue_step(h, acc[...], p[0][ix].astype(jnp.float32),
+                            p[1][ix] if len(p) > 1 else None,
+                            p[2][ix] if len(p) > 2 else None, with_health)
+             for acc, p in zip(accs, ins)]
+    for s in (1, 2):
+        for st, o in zip(steps, outs):
+            if len(o) > s:
+                o[s][ix] = st[s]
+    for st, o in zip(steps, outs):
+        o[0][ix] = st[0].astype(o[0].dtype)
+    return [st[3] for st in steps]
 
-    hyp is the scalar-prefetched per-unit ``[E, HYP_K]`` table of the
+
+def junction_update(x, dy, idx, res, ws, moms, vels, b, mom_b, vel_b, hyp, *,
+                    act: str = "none", bm: int | None = None,
+                    with_health: bool = False, interpret: bool = False,
+                    counts=None):
+    """The fused UP stage for weight operands ``ws``: the ``junction_dw``
+    gradient reduction with the optimizer update applied in the flush
+    epilogue, every parameter operand aliased to its output
+    (``input_output_aliases``), so the weight gradient never leaves VMEM
+    scratch and the parameters are rewritten in place.  ``moms``/``vels``
+    are the accumulator slots mirroring ws (both empty: SGD; moms alone:
+    SGD+momentum; both: Adam m, v), all fp32; ``b`` the bias (None
+    without) with its slots ``mom_b``/``vel_b`` (None where absent).
+    Returns ``(new_ws, new_b, new_moms, new_mom_b, new_vels, new_vel_b,
+    health)``: () for absent weight slots, None for an absent bias, bias
+    slot or health output.
+
+    Same grid, BlockSpecs and default row tile as ``junction_dw``, so the
+    fp32 accumulation order matches the two-pass path exactly (parity to
+    fp32 round-off).  hyp is the per-unit ``[E, HYP_K]`` table of the
     module docstring's column registry (any shape ``normalize_hyp``
-    accepts) — the epilogue reads row ``e = program_id(0)``, so each
-    junction unit updates under its own hyperparameters.  The
-    accumulator slots select the optimizer statically: mom/mom_b alone
-    → SGD(+momentum), plus vel/vel_b → Adam (m, v); all slots fp32.
-    Same grid, BlockSpecs and default row tile as ``dw``, so the fp32
-    accumulation order matches the two-pass path exactly (parity to
-    fp32 round-off).
+    accepts), row ``e = program_id(0)`` read in the epilogue.
 
     ``with_health=True`` adds a tiny non-aliased ``[E]`` int32 output
-    riding the same flush: each (e, ob) epilogue OR-reduces
-    ``isfinite`` over the accumulator tiles it just wrote (both m and v
-    for Adam, and the bias update for biased layers) and accumulates one
-    count into unit e's slot — the in-kernel divergence detector (one
-    VMEM compare per tile; the gradient still never materializes in
-    HBM).  health[e] > 0 means unit e wrote at least one non-finite
-    parameter tile this step.
+    riding the same flush: each (e, ob) epilogue OR-reduces ``isfinite``
+    over the accumulator tiles it just wrote (m and v for Adam, every
+    weight operand, and the bias update) and accumulates one count into
+    unit e's slot — the in-kernel divergence detector.  health[e] > 0
+    means unit e wrote at least one non-finite parameter tile this step.
 
-    ``counts``: live rows per unit, as in ``dw``; every (unit, output
-    block) is still updated, a unit with no live row from its moments."""
+    ``counts``: live rows per unit, as in ``junction_dw``; every (unit,
+    output block) is still updated, a unit with no live row from its
+    moments."""
     E, M, _ = x.shape
     nob, kb = idx.shape
     bs = dy.shape[2] // nob
-    has_res = act != "none"
-    has_mom = mom is not None
-    has_vel = vel is not None
-    assert not has_vel or has_mom, "Adam (vel) requires the mom slot too"
-    assert not (has_vel and with_bias) or vel_b is not None
+    n = len(ws)
+    with_bias = b is not None
     hyp = normalize_hyp(hyp, E)
     if bm is None:
-        bm = bwd_bm(M, kb + 3, bs, x.dtype.itemsize)
+        bm = _grad_bm(M, kb, bs, n, x.dtype.itemsize)
     assert M % bm == 0
     nm = M // bm
+    # the parameter operands, each aliased to the output at its position:
+    # every weight operand, then their m slots, then their v slots; then
+    # the bias and its slots
+    per_w = 1 + len(moms) // n + len(vels) // n
+    params = [*ws, *moms, *vels]
+    if with_bias:
+        params += [a.reshape(E, 1, -1)
+                   for a in (b, mom_b, vel_b)[:per_w]]
+    n_p = len(params)
+    n_in = 1 + len(res) + kb
 
-    def fused_update_dw(idx_ref, hyp_ref, *refs):
+    def tensors(refs, t):
+        """The (param, slots...) refs of weight operand t; t == n: the
+        bias's."""
+        if t == n:
+            return refs[n * per_w:]
+        return refs[t:n * per_w:n]
+
+    def kernel(idx_ref, hyp_ref, *refs):
         cnt_ref = None
         if counts is not None:
             cnt_ref, *refs = refs
-        n_lead = 2 if has_res else 1
-        dy_ref = refs[0]
-        res_ref = refs[1] if has_res else None
-        x_refs = refs[n_lead:n_lead + kb]
-        pos = n_lead + kb
-        w_ref = refs[pos]
-        pos += 1
-        mom_ref = refs[pos] if has_mom else None
-        pos += int(has_mom)
-        vel_ref = refs[pos] if has_vel else None
-        pos += int(has_vel)
-        b_ref = refs[pos] if with_bias else None
-        pos += int(with_bias)
-        mom_b_ref = refs[pos] if (has_mom and with_bias) else None
-        pos += int(has_mom and with_bias)
-        vel_b_ref = refs[pos] if (has_vel and with_bias) else None
-        pos += int(has_vel and with_bias)
-        outs = list(refs[pos:])
-        new_w_ref = outs.pop(0)
-        new_mom_ref = outs.pop(0) if has_mom else None
-        new_vel_ref = outs.pop(0) if has_vel else None
-        new_b_ref = outs.pop(0) if with_bias else None
-        new_mom_b_ref = outs.pop(0) if (has_mom and with_bias) else None
-        new_vel_b_ref = outs.pop(0) if (has_vel and with_bias) else None
-        health_ref = outs.pop(0) if with_health else None
-        if with_bias:
-            accw_ref, accb_ref = outs
-        else:
-            (accw_ref,) = outs
+        dy_ref, res_refs, x_refs = (refs[0], refs[1:n_in - kb],
+                                    refs[n_in - kb:n_in])
+        ins, outs = refs[n_in:n_in + n_p], refs[n_in + n_p:n_in + 2 * n_p]
+        health_ref = refs[n_in + 2 * n_p] if with_health else None
+        accs = refs[n_in + 2 * n_p + with_health:]
+        accb_ref = accs[n] if with_bias else None
+        accs = accs[:n]
         e = pl.program_id(0)
         o = pl.program_id(1)
         m = pl.program_id(2)
 
         @pl.when(m == 0)
         def _zero():
-            accw_ref[...] = jnp.zeros((kb, bs, bs), jnp.float32)
-            if with_bias:
-                accb_ref[...] = jnp.zeros((1, bs), jnp.float32)
+            _zero_grads(accs, accb_ref)
 
         if with_health:
             # health slot e is revisited across every (o, m) step: init once
@@ -1447,283 +1276,102 @@ def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
             def _zero_health():
                 health_ref[...] = jnp.zeros(health_ref.shape, jnp.int32)
 
-        def _accumulate():
-            if has_res:
-                grad = act_bwd(res_ref[0].astype(jnp.float32), act)
-                dzf = dy_ref[0].astype(jnp.float32) * grad
-                dz = dzf.astype(dy_ref.dtype)
-            else:
-                dzf = None
-                dz = dy_ref[0]
-            for k in range(kb):
-                accw_ref[k] = accw_ref[k] + jnp.dot(
-                    x_refs[k][0].T, dz, preferred_element_type=jnp.float32)
-            if with_bias:
-                s = dzf if dzf is not None else dy_ref[0].astype(jnp.float32)
-                accb_ref[...] = accb_ref[...] + jnp.sum(s, axis=0,
-                                                        keepdims=True)
-
-        _run_live(_accumulate, cnt_ref, bm, 2)
+        _run_live(lambda: _grad_tile(dy_ref, res_refs, x_refs, accs, accb_ref,
+                                     act), cnt_ref, bm, 2)
 
         @pl.when(m == nm - 1)
         def _apply():
             def h(col):
                 return hyp_ref[e, col]
 
-            new_w32, nmv, nvv, ok = _epilogue_step(
-                h, accw_ref[...], w_ref[0, 0].astype(jnp.float32),
-                mom_ref[0, 0] if has_mom else None,
-                vel_ref[0, 0] if has_vel else None, with_health)
-            if has_mom:
-                new_mom_ref[0, 0] = nmv
-            if has_vel:
-                new_vel_ref[0, 0] = nvv
-            new_w_ref[0, 0] = new_w32.astype(new_w_ref.dtype)
+            oks = _apply_updates(h, accs, [tensors(ins, t) for t in range(n)],
+                                 [tensors(outs, t) for t in range(n)],
+                                 (0, 0), with_health)
             if with_bias:
-                new_b32, nmb, nvb, okb = _epilogue_step(
-                    h, accb_ref[...], b_ref[0].astype(jnp.float32),
-                    mom_b_ref[0] if has_mom else None,
-                    vel_b_ref[0] if has_vel else None, with_health)
-                if has_mom:
-                    new_mom_b_ref[0] = nmb
-                if has_vel:
-                    new_vel_b_ref[0] = nvb
-                new_b_ref[0] = new_b32.astype(new_b_ref.dtype)
-                if with_health:
-                    ok = jnp.logical_and(ok, okb)
+                oks += _apply_updates(h, [accb_ref], [tensors(ins, n)],
+                                      [tensors(outs, n)], (0,), with_health)
             if with_health:
-                _flag_unhealthy(health_ref, ok)
+                _flag_unhealthy(health_ref, functools.reduce(jnp.logical_and,
+                                                             oks))
 
-    in_specs = [pl.BlockSpec((1, bm, bs), lambda e, o, m, *_: (e, m, o))]
-    inputs = [dy]
-    if has_res:
-        in_specs.append(pl.BlockSpec((1, bm, bs),
-                                     lambda e, o, m, *_: (e, m, o)))
-        inputs.append(res)
-    for k in range(kb):
-        in_specs.append(pl.BlockSpec(
-            (1, bm, bs), lambda e, o, m, idx, hyp, k=k: (e, m, idx[o, k])))
-        inputs.append(x)
-
-    wspec = pl.BlockSpec((1, 1, kb, bs, bs), lambda e, o, m, *_: (e, o, 0, 0, 0))
+    wspec = pl.BlockSpec((1, 1, kb, bs, bs),
+                         lambda e, o, m, *_: (e, o, 0, 0, 0))
     # per-unit bias operands ride as [E, 1, N]: the block's last two dims
     # (1, bs) then equal / tile the array's for every E
     bspec = pl.BlockSpec((1, 1, bs), lambda e, o, m, *_: (e, 0, o))
-    aliases: dict[int, int] = {}
-    out_specs, out_shape = [], []
-
-    n_prefetch = N_SCALAR_PREFETCH_UPDATE + (counts is not None)
-
-    def alias_io(arr, spec):
-        """Parameter operand riding in AND out through the same BlockSpec —
-        the in-place update contract."""
-        aliases[n_prefetch + len(inputs)] = len(out_shape)
-        in_specs.append(spec)
-        inputs.append(arr)
-        out_specs.append(spec)
-        out_shape.append(jax.ShapeDtypeStruct(arr.shape, arr.dtype))
-
-    alias_io(w, wspec)
-    if has_mom:
-        alias_io(mom, wspec)
-    if has_vel:
-        alias_io(vel, wspec)
-    if with_bias:
-        alias_io(b.reshape(E, 1, -1), bspec)
-        if has_mom:
-            alias_io(mom_b.reshape(E, 1, -1), bspec)
-        if has_vel:
-            alias_io(vel_b.reshape(E, 1, -1), bspec)
+    param_specs = [wspec] * (n * per_w) + [bspec] * (n_p - n * per_w)
+    out_specs = param_specs + ([HEALTH_SPEC] if with_health else [])
+    out_shape = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in params]
     if with_health:
-        out_specs.append(HEALTH_SPEC)
         out_shape.append(_health_shape(E))
-
-    scratch = [pltpu.VMEM((kb, bs, bs), jnp.float32)]
+    scratch = [pltpu.VMEM((kb, bs, bs), jnp.float32) for _ in ws]
     if with_bias:
         scratch.append(pltpu.VMEM((1, bs), jnp.float32))
 
     prefetch = (idx, hyp) if counts is None else (idx, hyp, counts)
+    aliases = {len(prefetch) + n_in + i: i for i in range(n_p)}
     rows = lambda im: _rows_inner(im, bm)
     outs = pl.pallas_call(
-        fused_update_dw,
-        name=_kernel_name("junction_update_dw", counts),
+        kernel,
+        name=_kernel_name(f"junction_update_{_gate(ws)}dw", counts),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=n_prefetch,
+            num_scalar_prefetch=len(prefetch),
             grid=(E, nob, nm),
-            in_specs=_counted_specs(in_specs, counts, rows),
+            in_specs=_counted_specs(
+                _grad_specs(bm, bs, kb, 1 + len(res)) + param_specs, counts,
+                rows),
             out_specs=_counted_specs(out_specs, counts, rows),
             scratch_shapes=scratch,
         ),
         out_shape=out_shape,
         input_output_aliases=aliases,
         interpret=interpret,
-    )(*prefetch, *inputs)
-    outs = list(outs)
-    new_w = outs.pop(0)
-    new_mom = outs.pop(0) if has_mom else None
-    new_vel = outs.pop(0) if has_vel else None
-    unit_rows = lambda a: a.reshape(E, -1)
-    new_b = unit_rows(outs.pop(0)) if with_bias else None
-    new_mom_b = unit_rows(outs.pop(0)) if (has_mom and with_bias) else None
-    new_vel_b = unit_rows(outs.pop(0)) if (has_vel and with_bias) else None
-    health = outs.pop(0)[:, 0, 0] if with_health else None
-    return new_w, new_b, new_mom, new_mom_b, new_vel, new_vel_b, health
+    )(*prefetch, dy, *res, *[x] * kb, *params)
+    groups = [tuple(outs[s * n:(s + 1) * n]) for s in range(per_w)]
+    groups += [()] * (3 - per_w)
+    bias_outs = [a.reshape(E, -1) for a in outs[n * per_w:n_p]]
+    bias_outs += [None] * (3 - len(bias_outs))
+    health = outs[n_p][:, 0, 0] if with_health else None
+    return (groups[0], bias_outs[0], groups[1], bias_outs[1], groups[2],
+            bias_outs[2], health)
+
+
+def update_dw(x, dy, idx, res, w, b, mom, mom_b, hyp, *, vel=None,
+              vel_b=None, act: str = "none", with_bias: bool = True,
+              bm: int | None = None, with_health: bool = False,
+              interpret: bool = False, counts=None):
+    """The fused UP stage of one weight operand (``junction_update``):
+    returns ``(new_w, new_b, new_mom, new_mom_b, new_vel, new_vel_b,
+    health)``, None where the operand is absent.  The accumulator slots
+    select the optimizer statically: mom/mom_b alone → SGD(+momentum),
+    plus vel/vel_b → Adam (m, v); ``res`` is the residual ``act`` needs
+    (ignored for act "none")."""
+    assert vel is None or mom is not None, \
+        "Adam (vel) requires the mom slot too"
+    assert not (vel is not None and with_bias) or vel_b is not None
+    one = lambda a: () if a is None else (a,)
+    (nw,), nb, nm, nmb, nv, nvb, health = junction_update(
+        x, dy, idx, () if act == "none" else (res,), (w,), one(mom),
+        one(vel), b if with_bias else None, mom_b, vel_b, hyp, act=act,
+        bm=bm, with_health=with_health, interpret=interpret, counts=counts)
+    return (nw, nb, nm[0] if nm else None, nmb, nv[0] if nv else None, nvb,
+            health)
 
 
 def update_gated_dw(x, dh, idx, g, u, wg, wi, mg, mi, hyp, *, vg=None,
                     vi=None, bm: int | None = None,
                     with_health: bool = False, interpret: bool = False,
                     counts=None):
-    """Fused BP+UP for the gated junction: both branch gradients reduce
-    into VMEM scratch exactly as in ``gated_dw`` and the flush epilogue
-    applies the optimizer update to BOTH weight streams in place —
-    returns ``(new_wg, new_wi, new_mg, new_mi, new_vg, new_vi, health)``
-    (absent slots None), all parameter outputs aliased to their inputs.
-    hyp is the per-unit ``[E, HYP_K]`` table (any shape ``normalize_hyp``
-    accepts), row ``e`` read in the epilogue; the slots select the
-    optimizer statically — mg/mi alone → SGD(+momentum), plus vg/vi →
-    Adam.  ``with_health=True`` appends the non-aliased ``[E]``
-    int32 divergence detector (see ``update_dw``): the epilogue checks
-    BOTH branch update tiles for non-finites.  ``counts``: live rows per
-    unit, as in ``update_dw``."""
-    E, M, _ = x.shape
-    nob, kb = idx.shape
-    bs = dh.shape[2] // nob
-    has_mom = mg is not None
-    has_vel = vg is not None
-    assert not has_vel or (has_mom and vi is not None), \
+    """Fused BP+UP for the gated junction (``junction_update`` of two
+    weight operands, no bias): both branch gradients reduce into VMEM
+    scratch as in ``gated_dw`` and the flush epilogue updates BOTH
+    weight streams in place — returns ``(new_wg, new_wi, new_mg,
+    new_mi, new_vg, new_vi, health)`` (absent slots None); mg/mi alone
+    → SGD(+momentum), plus vg/vi → Adam."""
+    assert vg is None or (mg is not None and vi is not None), \
         "Adam (vg/vi) requires the mg/mi slots too"
-    hyp = normalize_hyp(hyp, E)
-    if bm is None:
-        bm = bwd_bm(M, kb + 5, bs, x.dtype.itemsize)
-    assert M % bm == 0
-    nm = M // bm
-
-    def fused_update_gated_dw(idx_ref, hyp_ref, *refs):
-        cnt_ref = None
-        if counts is not None:
-            cnt_ref, *refs = refs
-        dh_ref, g_ref, u_ref = refs[:3]
-        x_refs = refs[3:3 + kb]
-        pos = 3 + kb
-        wg_ref, wi_ref = refs[pos], refs[pos + 1]
-        pos += 2
-        if has_mom:
-            mg_ref, mi_ref = refs[pos], refs[pos + 1]
-            pos += 2
-        if has_vel:
-            vg_ref, vi_ref = refs[pos], refs[pos + 1]
-            pos += 2
-        outs = list(refs[pos:])
-        new_wg_ref = outs.pop(0)
-        new_wi_ref = outs.pop(0)
-        if has_mom:
-            new_mg_ref = outs.pop(0)
-            new_mi_ref = outs.pop(0)
-        if has_vel:
-            new_vg_ref = outs.pop(0)
-            new_vi_ref = outs.pop(0)
-        health_ref = outs.pop(0) if with_health else None
-        accg_ref, accu_ref = outs
-        e = pl.program_id(0)
-        o = pl.program_id(1)
-        m = pl.program_id(2)
-
-        @pl.when(m == 0)
-        def _zero():
-            accg_ref[...] = jnp.zeros((kb, bs, bs), jnp.float32)
-            accu_ref[...] = jnp.zeros((kb, bs, bs), jnp.float32)
-
-        if with_health:
-            @pl.when(jnp.logical_and(o == 0, m == 0))
-            def _zero_health():
-                health_ref[...] = jnp.zeros(health_ref.shape, jnp.int32)
-
-        def _accumulate():
-            _gated_accumulate(dh_ref, g_ref, u_ref, x_refs, accg_ref,
-                              accu_ref, kb)
-
-        _run_live(_accumulate, cnt_ref, bm, 2)
-
-        @pl.when(m == nm - 1)
-        def _apply():
-            def h(col):
-                return hyp_ref[e, col]
-
-            new_g32, nmg, nvg, okg = _epilogue_step(
-                h, accg_ref[...], wg_ref[0, 0].astype(jnp.float32),
-                mg_ref[0, 0] if has_mom else None,
-                vg_ref[0, 0] if has_vel else None, with_health)
-            new_i32, nmi, nvi, oki = _epilogue_step(
-                h, accu_ref[...], wi_ref[0, 0].astype(jnp.float32),
-                mi_ref[0, 0] if has_mom else None,
-                vi_ref[0, 0] if has_vel else None, with_health)
-            if has_mom:
-                new_mg_ref[0, 0] = nmg
-                new_mi_ref[0, 0] = nmi
-            if has_vel:
-                new_vg_ref[0, 0] = nvg
-                new_vi_ref[0, 0] = nvi
-            new_wg_ref[0, 0] = new_g32.astype(new_wg_ref.dtype)
-            new_wi_ref[0, 0] = new_i32.astype(new_wi_ref.dtype)
-            if with_health:
-                _flag_unhealthy(health_ref, jnp.logical_and(okg, oki))
-
-    row = pl.BlockSpec((1, bm, bs), lambda e, o, m, *_: (e, m, o))
-    in_specs = [row, row, row]
-    inputs = [dh, g, u]
-    for k in range(kb):
-        in_specs.append(pl.BlockSpec(
-            (1, bm, bs), lambda e, o, m, idx, hyp, k=k: (e, m, idx[o, k])))
-        inputs.append(x)
-
-    wspec = pl.BlockSpec((1, 1, kb, bs, bs), lambda e, o, m, *_: (e, o, 0, 0, 0))
-    aliases: dict[int, int] = {}
-    out_specs, out_shape = [], []
-    n_prefetch = N_SCALAR_PREFETCH_UPDATE + (counts is not None)
-
-    def alias_io(arr):
-        aliases[n_prefetch + len(inputs)] = len(out_shape)
-        in_specs.append(wspec)
-        inputs.append(arr)
-        out_specs.append(wspec)
-        out_shape.append(jax.ShapeDtypeStruct(arr.shape, arr.dtype))
-
-    alias_io(wg)
-    alias_io(wi)
-    if has_mom:
-        alias_io(mg)
-        alias_io(mi)
-    if has_vel:
-        alias_io(vg)
-        alias_io(vi)
-    if with_health:
-        out_specs.append(HEALTH_SPEC)
-        out_shape.append(_health_shape(E))
-
-    prefetch = (idx, hyp) if counts is None else (idx, hyp, counts)
-    rows = lambda im: _rows_inner(im, bm)
-    outs = pl.pallas_call(
-        fused_update_gated_dw,
-        name=_kernel_name("junction_update_gated_dw", counts),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=n_prefetch,
-            grid=(E, nob, nm),
-            in_specs=_counted_specs(in_specs, counts, rows),
-            out_specs=_counted_specs(out_specs, counts, rows),
-            scratch_shapes=[pltpu.VMEM((kb, bs, bs), jnp.float32),
-                            pltpu.VMEM((kb, bs, bs), jnp.float32)],
-        ),
-        out_shape=out_shape,
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(*prefetch, *inputs)
-    outs = list(outs)
-    new_wg = outs.pop(0)
-    new_wi = outs.pop(0)
-    new_mg = outs.pop(0) if has_mom else None
-    new_mi = outs.pop(0) if has_mom else None
-    new_vg = outs.pop(0) if has_vel else None
-    new_vi = outs.pop(0) if has_vel else None
-    health = outs.pop(0)[:, 0, 0] if with_health else None
-    return new_wg, new_wi, new_mg, new_mi, new_vg, new_vi, health
+    nws, _, nms, _, nvs, _, health = junction_update(
+        x, dh, idx, (g, u), (wg, wi), () if mg is None else (mg, mi),
+        () if vg is None else (vg, vi), None, None, None, hyp, bm=bm,
+        with_health=with_health, interpret=interpret, counts=counts)
+    return (*nws, *(nms or (None, None)), *(nvs or (None, None)), health)

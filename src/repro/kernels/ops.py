@@ -29,9 +29,6 @@ through ``input_output_aliasing`` — so ``dw`` never round-trips HBM (the
 paper's concurrent BP/UP pipeline; Dey et al. 2017's interleaved FF/BP/UP
 edge processor).
 
-``block_sparse_matmul`` / ``expert_block_sparse_matmul`` /
-``expert_gated_matmul`` remain as thin aliases over ``junction_matmul``.
-
 Kernels execute in interpret mode off-TPU (the container is CPU-only);
 on TPU ``interpret=False`` (the default auto-detects the backend).
 
@@ -103,12 +100,6 @@ def _kernel_scope(name: str, spec: KernelSpec):
     return jax.named_scope(tag)
 
 
-def _counted(counts) -> dict:
-    """Keyword arguments of a kernel call for the live row counts: none
-    at all without them, so an uncounted call is the same program."""
-    return {} if counts is None else {"counts": counts}
-
-
 def _bwd_tile(spec, counts) -> dict:
     """Counted calls run every kernel on the forward's row tile, so the
     live tiles (and the rows they cover) are the same in each."""
@@ -116,34 +107,26 @@ def _bwd_tile(spec, counts) -> dict:
 
 
 def _fwd_call(spec, x, ws, b, idx, save: bool, counts=None):
-    """(y, res) through the forward kernels; res is the backward residual
-    ((g, u) for gated, pre-activation or y for plain activations, None
-    otherwise) — emitted only when ``save``."""
-    if spec.gated:
-        h, g, u = bsm.gated_fwd(x, ws[0], ws[1], idx, bm=spec.bm, bn=spec.bn,
-                                save_res=save, interpret=spec.interpret,
-                                **_counted(counts))
-        return h, ((g, u) if save else None)
-    needs_pre = spec.act in bsm.ACT_NEEDS_PRE
-    y, pre = bsm.fwd(x, ws[0], idx, b, act=spec.act, bm=spec.bm, bn=spec.bn,
-                     save_pre=save and needs_pre, interpret=spec.interpret,
-                     **_counted(counts))
+    """(y, res) through the forward kernel; res is the backward's
+    residuals — (g, u) for the gate, (pre-activation,) or (y,) for plain
+    activations, () for none — emitted only when ``save``."""
+    keep = save and (spec.gated or spec.act in bsm.ACT_NEEDS_PRE)
+    y, saved = bsm.junction_fwd(x, ws, idx, None if spec.gated else b,
+                                act=spec.act, bm=spec.bm, bn=spec.bn,
+                                save=keep, interpret=spec.interpret,
+                                counts=counts)
     if not save:
         return y, None
-    return y, (pre if needs_pre else (y if spec.act != "none" else None))
+    return y, saved or ((y,) if spec.act != "none" else ())
 
 
 def _dx_call(spec, ws, res, dy, rev_ob, rev_t, rev_cnt, counts=None):
     """BP through the reverse pattern — the reverse weight bundles are
     DMA'd HBM→VMEM inside the kernel from the forward-layout weights (no
     XLA w[rev_ob, rev_t] pre-gather)."""
-    kw = dict(_counted(counts), **_bwd_tile(spec, counts))
-    if spec.gated:
-        g, u = res
-        return bsm.gated_dx(dy, ws[0], ws[1], rev_ob, rev_t, rev_cnt, g, u,
-                            interpret=spec.interpret, **kw)
-    return bsm.dx(dy, ws[0], rev_ob, rev_t, rev_cnt, res, act=spec.act,
-                  interpret=spec.interpret, **kw)
+    return bsm.junction_dx(dy, ws, rev_ob, rev_t, rev_cnt, res, act=spec.act,
+                           interpret=spec.interpret, counts=counts,
+                           **_bwd_tile(spec, counts))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -163,19 +146,14 @@ def _junction_fwd(spec, x, ws, b, idx, rev_ob, rev_t, rev_cnt, counts):
 def _junction_bwd(spec, saved, dy):
     x, ws, res, idx, rev_ob, rev_t, rev_cnt, counts = saved
     dxv = _dx_call(spec, ws, res, dy, rev_ob, rev_t, rev_cnt, counts)
-    kw = dict(_counted(counts), **_bwd_tile(spec, counts))
-    if spec.gated:
-        g, u = res
-        dwg, dwi = bsm.gated_dw(x, dy, idx, g, u, interpret=spec.interpret,
-                                **kw)
-        dws = (dwg.astype(ws[0].dtype), dwi.astype(ws[1].dtype))
-        db = jnp.zeros((dy.shape[0], dy.shape[2]), jnp.float32)
-        return dxv, dws, db, None, None, None, None, None
-    dwv, dbv = bsm.dw(x, dy, idx, res, act=spec.act,
-                      with_bias=spec.has_bias, interpret=spec.interpret, **kw)
+    dws, dbv = bsm.junction_dw(x, dy, idx, res, act=spec.act,
+                               with_bias=spec.has_bias,
+                               interpret=spec.interpret, counts=counts,
+                               **_bwd_tile(spec, counts))
     if dbv is None:  # bias-free layer: the zero-bias operand gets zeros
         dbv = jnp.zeros((dy.shape[0], dy.shape[2]), jnp.float32)
-    return dxv, (dwv.astype(ws[0].dtype),), dbv, None, None, None, None, None
+    dws = tuple(d.astype(w.dtype) for d, w in zip(dws, ws))
+    return dxv, dws, dbv, None, None, None, None, None
 
 
 _junction_core.defvjp(_junction_fwd, _junction_bwd)
@@ -221,30 +199,24 @@ def _junction_update_bwd(spec, saved, dy):
     (x, ws, b, res, moms, mom_b, vels, vel_b, hyp, idx, rev_ob, rev_t,
      rev_cnt, counts) = saved
     dxv = _dx_call(spec, ws, res, dy, rev_ob, rev_t, rev_cnt, counts)
-    kw = dict(_counted(counts), **_bwd_tile(spec, counts))
+    kw = dict(with_health=spec.with_health, interpret=spec.interpret,
+              counts=counts, **_bwd_tile(spec, counts))
+    slot = lambda t, i=0: t[i] if t else None
+    # through the per-form names, which a test may replace
     if spec.gated:
-        g, u = res
-        nwg, nwi, nmg, nmi, nvg, nvi, flags = bsm.update_gated_dw(
-            x, dy, idx, g, u, ws[0], ws[1],
-            moms[0] if moms else None, moms[1] if moms else None,
-            hyp, vg=vels[0] if vels else None,
-            vi=vels[1] if vels else None,
-            with_health=spec.with_health, interpret=spec.interpret, **kw)
-        new_ws = (nwg, nwi)
-        new_moms = (nmg, nmi) if moms else ()
-        new_vels = (nvg, nvi) if vels else ()
+        *new, flags = bsm.update_gated_dw(
+            x, dy, idx, *res, *ws, slot(moms), slot(moms, 1), hyp,
+            vg=slot(vels), vi=slot(vels, 1), **kw)
+        new_ws = tuple(new[:2])
+        new_moms = tuple(new[2:4]) if moms else ()
+        new_vels = tuple(new[4:]) if vels else ()
         new_b = jnp.zeros_like(b)    # gated junctions carry no bias
-        new_mom_b = ()
-        new_vel_b = ()
+        new_mom_b = new_vel_b = ()
     else:
         nw, nb, nm, nmb, nv, nvb, flags = bsm.update_dw(
-            x, dy, idx, res, ws[0], b if spec.has_bias else None,
-            moms[0] if moms else None,
-            mom_b[0] if mom_b else None,
-            hyp, vel=vels[0] if vels else None,
-            vel_b=vel_b[0] if vel_b else None,
-            act=spec.act, with_bias=spec.has_bias,
-            with_health=spec.with_health, interpret=spec.interpret, **kw)
+            x, dy, idx, slot(res), ws[0], b if spec.has_bias else None,
+            slot(moms), slot(mom_b), hyp, vel=slot(vels),
+            vel_b=slot(vel_b), act=spec.act, with_bias=spec.has_bias, **kw)
         new_ws = (nw,)
         new_moms = (nm,) if moms else ()
         new_vels = (nv,) if vels else ()
@@ -352,14 +324,13 @@ def _junction_quant(x, w, idx, *, wi, bias, act, interpret, bm, bn,
         if spec.quant == "fxp":
             y = bsm.fwd_fxp(x3, w5, idx, qfmt, qlut, b, bm=spec.bm,
                             bn=spec.bn, interpret=spec.interpret)
-        elif spec.gated:
-            y = bsm.gated_fwd_int8(x3, w5, wi5, idx, lift(w_scale),
-                                   lift(wi_scale), x_scale=xs, bm=spec.bm,
-                                   bn=spec.bn, interpret=spec.interpret)
         else:
-            y = bsm.fwd_int8(x3, w5, idx, lift(w_scale), b, act=spec.act,
-                             x_scale=xs, bm=spec.bm, bn=spec.bn,
-                             interpret=spec.interpret)
+            ws, scales = ((w5, wi5), (w_scale, wi_scale)) if gated else (
+                (w5,), (w_scale,))
+            y = bsm.junction_fwd_int8(
+                x3, ws, idx, tuple(map(lift, scales)), None if gated else b,
+                act=spec.act, x_scale=xs, bm=spec.bm, bn=spec.bn,
+                interpret=spec.interpret)
     y = y[:, :M]
     return y.reshape(*lead, nob * bs) if single else y
 
@@ -525,31 +496,6 @@ def junction_train_update(x, w, idx, rev_ob, rev_t, rev_cnt, *, hyp,
                                   rev_cnt, counts)
     y = y[:, :M]
     return y.reshape(*lead, nob * bs) if single else y
-
-
-def block_sparse_matmul(x, w, idx, rev_ob, rev_t, rev_cnt, bias=None,
-                        act: str = "none", interpret: bool | None = None,
-                        bm: int | None = None, bn: int | None = None):
-    """Single-junction alias: x [..., n_in], w [nob, kb, bs, bs]."""
-    return junction_matmul(x, w, idx, rev_ob, rev_t, rev_cnt, bias=bias,
-                           act=act, interpret=interpret, bm=bm, bn=bn)
-
-
-def expert_block_sparse_matmul(x, w, idx, rev_ob, rev_t, rev_cnt, bias=None,
-                               act: str = "none",
-                               interpret: bool | None = None,
-                               bm: int | None = None, bn: int | None = None):
-    """Expert-batched alias: x [E, M, n_in], w [E, nob, kb, bs, bs]."""
-    return junction_matmul(x, w, idx, rev_ob, rev_t, rev_cnt, bias=bias,
-                           act=act, interpret=interpret, bm=bm, bn=bn)
-
-
-def expert_gated_matmul(x, wg, wi, idx, rev_ob, rev_t, rev_cnt,
-                        interpret: bool | None = None,
-                        bm: int | None = None, bn: int | None = None):
-    """Gated-expert alias: silu(x_e @ Wg_e) * (x_e @ Wi_e) in one pass."""
-    return junction_matmul(x, wg, idx, rev_ob, rev_t, rev_cnt, wi=wi,
-                           interpret=interpret, bm=bm, bn=bn)
 
 
 # ------------------------------------------------------------ fixed point

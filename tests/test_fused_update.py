@@ -607,7 +607,7 @@ def test_dx_coalesces_contiguous_reverse_runs():
             jnp.asarray(rc))
 
     def f(x, w):
-        return jnp.sum(ops.block_sparse_matmul(x, w, *args) * co)
+        return jnp.sum(ops.junction_matmul(x, w, *args) * co)
 
     def g(x, w):
         return jnp.sum(ref.block_sparse_matmul(x, w, args[0]) * co)

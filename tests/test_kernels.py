@@ -19,9 +19,9 @@ def test_block_sparse_fwd(n_in, n_out, density, dtype):
     w = (jax.random.normal(jax.random.PRNGKey(1),
                            (pat.n_out_blocks, pat.fan_in_blocks, 128, 128))
          * 0.05).astype(dtype)
-    y = ops.block_sparse_matmul(x, w, jnp.asarray(pat.idx),
-                                jnp.asarray(pat.rev_ob), jnp.asarray(pat.rev_t),
-                                jnp.asarray(pat.rev_cnt))
+    y = ops.junction_matmul(x, w, jnp.asarray(pat.idx),
+                            jnp.asarray(pat.rev_ob), jnp.asarray(pat.rev_t),
+                            jnp.asarray(pat.rev_cnt))
     yr = ref.block_sparse_matmul(x, w, jnp.asarray(pat.idx))
     tol = 1e-3 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(y, np.float32),
@@ -39,7 +39,7 @@ def test_block_sparse_grads_vs_oracle():
                    jnp.asarray(pat.rev_cnt))
     co = jax.random.normal(jax.random.PRNGKey(2), (128, 384))
 
-    f = lambda x, w: jnp.sum(ops.block_sparse_matmul(x, w, idx, rob, rt, rc) * co)
+    f = lambda x, w: jnp.sum(ops.junction_matmul(x, w, idx, rob, rt, rc) * co)
     g = lambda x, w: jnp.sum(ref.block_sparse_matmul(x, w, idx) * co)
     dx1, dw1 = jax.grad(f, (0, 1))(x, w)
     dx2, dw2 = jax.grad(g, (0, 1))(x, w)
@@ -53,9 +53,9 @@ def test_block_sparse_bias_and_lead_dims():
     w = jax.random.normal(jax.random.PRNGKey(1),
                           (pat.n_out_blocks, pat.fan_in_blocks, 128, 128)) * 0.1
     b = jax.random.normal(jax.random.PRNGKey(2), (128,))
-    y = ops.block_sparse_matmul(x, w, jnp.asarray(pat.idx),
-                                jnp.asarray(pat.rev_ob), jnp.asarray(pat.rev_t),
-                                jnp.asarray(pat.rev_cnt), bias=b)
+    y = ops.junction_matmul(x, w, jnp.asarray(pat.idx),
+                            jnp.asarray(pat.rev_ob), jnp.asarray(pat.rev_t),
+                            jnp.asarray(pat.rev_cnt), bias=b)
     yr = ref.block_sparse_matmul(x.reshape(15, 256), w, jnp.asarray(pat.idx)) + b
     np.testing.assert_allclose(np.asarray(y).reshape(15, 128), np.asarray(yr),
                                rtol=1e-3, atol=1e-3)
@@ -226,7 +226,7 @@ def test_block_sparse_vjp_fused_epilogue(bs, act):
                         jnp.asarray(pat.rev_t), jnp.asarray(pat.rev_cnt))
 
     def f_pallas(x, w, b):
-        y = ops.block_sparse_matmul(x, w, idx, rob, rt, rc, bias=b, act=act)
+        y = ops.junction_matmul(x, w, idx, rob, rt, rc, bias=b, act=act)
         return jnp.sum(y * co)
 
     def f_jnp(x, w, b):
@@ -261,8 +261,7 @@ def test_expert_block_sparse_matmul_vs_vmap_oracle(act):
     co = jax.random.normal(ks[3], (E, M, 6 * bs))
 
     def f_pallas(x, w, b):
-        y = ops.expert_block_sparse_matmul(x, w, idx, rob, rt, rc,
-                                           bias=b, act=act)
+        y = ops.junction_matmul(x, w, idx, rob, rt, rc, bias=b, act=act)
         return jnp.sum(y * co)
 
     def f_jnp(x, w, b):
@@ -298,7 +297,7 @@ def test_expert_gated_matmul_vs_vmap_oracle():
     co = jax.random.normal(ks[3], (E, M, 6 * bs))
 
     def f_pallas(x, wg, wi):
-        h = ops.expert_gated_matmul(x, wg, wi, idx, rob, rt, rc)
+        h = ops.junction_matmul(x, wg, idx, rob, rt, rc, wi=wi)
         return jnp.sum(h * co)
 
     def f_jnp(x, wg, wi):
@@ -326,7 +325,7 @@ def test_single_junction_is_e1_wrapper_no_expert_family():
     assert not dupes, f"expert_* duplicate kernel family resurfaced: {dupes}"
     assert not hasattr(bsm, "EXPERT_TUNE_TABLE"), "second tune table resurfaced"
     assert callable(ops.junction_matmul)
-    # the compat aliases must be thin (no separate custom_vjp cores)
+    # no pre-unification custom_vjp core survives beside junction_matmul
     for n in ("_bsm_core", "_ebsm_core", "_egated_core"):
         assert not hasattr(ops, n), f"pre-unification custom_vjp {n} survives"
 
@@ -367,31 +366,16 @@ def test_gated_single_junction_e1_parity():
 
 
 def test_tune_table_key_migration():
-    """Every pre-refactor tune-table key resolves to the same tiles
-    through the merged table: PR 1's 4-key (M, nob, kb, bs) schema, the
-    transitional 5-key, and PR 2's 6-key expert schema."""
+    """Every TUNE_TABLE entry is keyed in the one schema (E, M, nob, kb,
+    bs, n_weight_operands) and is what choose_tiles returns for that
+    configuration."""
     from repro.kernels import block_sparse_matmul as bsm
 
-    pre_refactor = {
-        # PR 1 TUNE_TABLE entries (single junction, one weight operand)
-        (12544, 4, 2, 128): (512, 4),
-        (4096, 32, 2, 128): (256, 8),
-        # PR 2 EXPERT_TUNE_TABLE entry (gated: two weight operands)
-        (4, 1280, 4, 2, 128, 2): (256, 4),
-    }
-    for key, want in pre_refactor.items():
-        canon = bsm.canonical_tune_key(key)
-        assert len(canon) == 6
-        assert bsm.TUNE_TABLE[canon] == want, (key, canon)
-    # the chooser actually hits them through its canonical lookup
-    assert bsm.choose_tiles(12544, 4, 2, 128, 8, 4) == (512, 4)
-    assert bsm.choose_tiles(4096, 32, 2, 128, 8, 4) == (256, 8)
-    assert bsm.choose_tiles(1280, 4, 2, 128, 8, 4,
-                            E=4, n_weight_operands=2) == (256, 4)
-    # 5-key transitional schema pins n_weight_operands=1
-    assert bsm.canonical_tune_key((4, 1280, 4, 2, 128)) == (4, 1280, 4, 2, 128, 1)
-    with pytest.raises(ValueError):
-        bsm.canonical_tune_key((1, 2, 3))
+    assert bsm.TUNE_TABLE
+    for key, want in bsm.TUNE_TABLE.items():
+        E, M, nob, kb, bs, n_w = key
+        assert bsm.choose_tiles(M, nob, kb, bs, 8, 4, E=E,
+                                n_weight_operands=n_w) == want, key
 
 
 def test_dx_zero_fanout_rows_exact_zero():
