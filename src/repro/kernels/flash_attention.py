@@ -16,15 +16,17 @@ kpos < Sk term), mirroring the fxp_qmatmul pad-to-tile contract.
 ``flash_decode`` — the serve-path variant: one query per slot against a
 block-paged KV pool.  The per-slot page table and sequence lengths ride
 scalar prefetch exactly like the junction kernels' pattern indices; the
-KV pool stays in HBM (memory_space=ANY) and each page is gathered
-HBM→VMEM with the same double-buffered ``make_async_copy`` idiom as the
-reverse-weight DMA in block_sparse_matmul.dx — while page j is reduced
-into the online-softmax state, page j+1 is in flight.  Pages past a
-slot's length are skipped entirely (matching-predicate start/wait), so
-a ragged batch does no DMA for dead tail pages; a zero-length slot
-(free/prefilling — the engine points it at the scratch page) produces
-exact zeros.  Fixed shapes throughout: slot refill and page-table swaps
-change only the prefetched integers, never the traced graph.
+KV pool stays in HBM (memory_space=ANY).  A grid step covers a group of
+G page-table entries (``decode_pages_per_step``, from the shapes), each
+page gathered HBM→VMEM by its own ``make_async_copy`` into one
+double-buffered group — the idiom of the reverse-weight DMA in
+block_sparse_matmul.dx: while group g is reduced into the online-softmax
+state, group g+1 is in flight.  Pages past a slot's length are never
+read (matching-predicate start/wait per page), so a ragged batch does no
+DMA for dead tail pages and a group wholly past the length does
+nothing; a zero-length slot produces exact zeros.  Fixed shapes
+throughout: slot refill and page-table swaps change only the prefetched
+integers, never the traced graph.
 
 Causal masking from absolute block offsets; fully-masked tiles contribute
 exp(-inf)=0 naturally.  Validated against naive oracles over
@@ -147,6 +149,23 @@ def mha(q, k, v, *, causal: bool = True, window: int = 0,
 
 
 # ===================================================== paged decode kernel
+DECODE_GROUP_BYTES = 1 << 20    # K bytes a decode grid step reads, at most
+
+
+def decode_pages_per_step(ps: int, hd: int, itemsize: int, maxp: int) -> int:
+    """Pages of the slot's page table ``flash_decode`` reads per grid step:
+    the largest power of two whose K block stays within
+    ``DECODE_GROUP_BYTES`` (so a group holds 0.5-1 MiB of K, and the
+    double-buffered K and V at most 4 MiB of VMEM), clamped to
+    [1, maxp].  Pages of 16 rows of stablelm-3b's 32 x 80 bf16 heads
+    (80 KiB) read 8 a step; narrower rows or smaller pages read more."""
+    page = ps * hd * itemsize
+    g = 1
+    while 2 * g * page <= DECODE_GROUP_BYTES:
+        g *= 2
+    return min(g, maxp)
+
+
 def _head_mask(hkv: int, hd: int):
     """[Hkv, Hkv*D] bool: row h marks the lanes of kv head h in the merged
     heads x head_dim row."""
@@ -156,17 +175,20 @@ def _head_mask(hkv: int, hd: int):
     return jnp.logical_and(lane >= head, lane < head + d)
 
 
-def _decode_kernel(maxp: int, ps: int, hkv: int, scale: float,
+def _decode_kernel(maxp: int, ps: int, G: int, hkv: int, scale: float,
                    pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                    kbuf, vbuf, sems, qbd_s, m_s, l_s, acc_s):
-    """One slot (grid axis 0) against its pages (axis 1).  Every query row
-    r and kv head h is one row r*Hkv + h of the softmax state; the queries
-    are held block-diagonally (row r*Hkv + h keeps query r's head-h lanes
-    and zeros elsewhere), so a page's scores for all heads are one matmul
-    against the merged [ps, Hkv*D] page and no lane slice at a head
-    boundary (80 lanes for stablelm) ever happens."""
+    """One slot (grid axis 0) against its page groups (axis 1): group g
+    is the G page-table entries from g*G, each page DMA'd into its ps
+    rows of one [G*ps, Hkv*D] buffer.  Every query row r and kv head h
+    is one row r*Hkv + h of the softmax state; the queries are held
+    block-diagonally (row r*Hkv + h keeps query r's head-h lanes and
+    zeros elsewhere), so a group's scores for all heads are one matmul
+    against the merged rows and no lane slice at a head boundary (80
+    lanes for stablelm) ever happens."""
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    g = pl.program_id(1)
+    ng = pl.num_programs(1)
     n = len_ref[b]
     rep, hd = q_ref.shape[1], q_ref.shape[2]
     cd = qbd_s.dtype
@@ -175,18 +197,29 @@ def _decode_kernel(maxp: int, ps: int, hkv: int, scale: float,
     prec = (jax.lax.Precision.HIGHEST if cd == jnp.float32
             else jax.lax.Precision.DEFAULT)
 
-    def copies(buf, page):
+    def live(page):
+        # a page is read only if it holds one of the slot's tokens: no
+        # DMA past the length, and never the sentinel page of a tail
+        return jnp.logical_and(page < maxp, page * ps < n)
+
+    def copies(buf, page, i):
         pid = pt_ref[b, page]
-        return (pltpu.make_async_copy(k_hbm.at[pid], kbuf.at[buf],
+        rows = pl.ds(i * ps, ps)
+        return (pltpu.make_async_copy(k_hbm.at[pid], kbuf.at[buf, rows],
                                       sems.at[buf, 0]),
-                pltpu.make_async_copy(v_hbm.at[pid], vbuf.at[buf],
+                pltpu.make_async_copy(v_hbm.at[pid], vbuf.at[buf, rows],
                                       sems.at[buf, 1]))
 
-    def start(buf, page):
-        for c in copies(buf, page):
-            c.start()
+    def start(buf, group):
+        for i in range(G):
+            page = group * G + i
 
-    @pl.when(j == 0)
+            @pl.when(live(page))
+            def _():
+                for c in copies(buf, page, i):
+                    c.start()
+
+    @pl.when(g == 0)
     def _init():
         q = q_ref[0].astype(jnp.float32)                       # [rep, hd]
         qbd = jnp.where(_head_mask(hkv, hd)[None], q[:, None, :], 0.0)
@@ -194,25 +227,42 @@ def _decode_kernel(maxp: int, ps: int, hkv: int, scale: float,
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
-        pl.when(n > 0)(lambda: start(0, 0))
+        start(0, 0)
 
-    # prefetch page j+1 while page j is reduced; predicate matches the
-    # wait below so skipped tail pages never touch the semaphores
-    @pl.when(jnp.logical_and(j + 1 < maxp, (j + 1) * ps < n))
+    # prefetch group g+1 while group g is reduced; each page's predicate
+    # matches its wait below, so a page that is not read touches no
+    # semaphore
+    @pl.when(g + 1 < ng)
     def _next():
-        start((j + 1) % 2, j + 1)
+        start((g + 1) % 2, g + 1)
 
-    @pl.when(j * ps < n)
+    @pl.when(g * (G * ps) < n)
     def _compute():
-        for c in copies(j % 2, j):
-            c.wait()
-        kp = kbuf[j % 2].astype(cd)                            # [ps, hd]
-        vp = vbuf[j % 2].astype(cd)
+        buf = g % 2
+        for i in range(G):
+            page = g * G + i
+
+            @pl.when(live(page))
+            def _():
+                for c in copies(buf, page, i):
+                    c.wait()
+
+            # a page not read leaves stale rows (another slot's, or
+            # uninitialised VMEM) in the buffer: its scores are masked
+            # below, and its values are zeroed so that 0 * NaN never
+            # reaches the accumulator
+            @pl.when(jnp.logical_not(live(page)))
+            def _():
+                vbuf[buf, pl.ds(i * ps, ps)] = jnp.zeros((ps, hd),
+                                                         vbuf.dtype)
+
+        kp = kbuf[buf].astype(cd)                              # [G*ps, hd]
+        vp = vbuf[buf].astype(cd)
         s = jax.lax.dot_general(qbd_s[...], kp, (((1,), (1,)), ((), ())),
                                 precision=prec,
                                 preferred_element_type=jnp.float32) * scale
-        kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < n, s, NEG_INF)                    # [R, ps]
+        kpos = g * (G * ps) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(kpos < n, s, NEG_INF)                    # [R, G*ps]
         m_prev = m_s[...]                                      # [R, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -223,7 +273,7 @@ def _decode_kernel(maxp: int, ps: int, hkv: int, scale: float,
             p.astype(cd), vp, precision=prec,
             preferred_element_type=jnp.float32)               # [R, hd]
 
-    @pl.when(j == maxp - 1)
+    @pl.when(g == ng - 1)
     def _finish():
         # row r*Hkv + h holds head h's output on head h's lanes
         out = (acc_s[...] / jnp.maximum(l_s[...], 1e-30)).reshape(
@@ -233,6 +283,7 @@ def _decode_kernel(maxp: int, ps: int, hkv: int, scale: float,
 
 
 def flash_decode(q, k_pool, v_pool, page_table, seq_lens, *,
+                 pages_per_step: int | None = None,
                  interpret: bool | None = None):
     """Single-query decode attention over a block-paged KV pool.
 
@@ -246,13 +297,19 @@ def flash_decode(q, k_pool, v_pool, page_table, seq_lens, *,
     seq_lens [B] int32 — valid tokens per slot (0 for free slots).
 
     Returns [B, Hkv, rep, D].  The page table and lengths ride scalar
-    prefetch; pages are DMA'd HBM→VMEM double-buffered, with tail pages
-    past a slot's length skipped.  seq_lens == 0 yields exact zeros.
+    prefetch; the grid is (B, ceil(maxp / G)), each step reading a group
+    of G pages (``decode_pages_per_step`` from the shapes unless
+    ``pages_per_step`` pins it) by one DMA per page HBM→VMEM,
+    double-buffered, with pages past a slot's length never read.
+    seq_lens == 0 yields exact zeros.
     """
     B, Hkv, rep, D = q.shape
     P, ps, hd = k_pool.shape
     assert hd == Hkv * D, (k_pool.shape, q.shape)
     maxp = page_table.shape[1]
+    G = pages_per_step or decode_pages_per_step(
+        ps, hd, k_pool.dtype.itemsize, maxp)
+    assert 1 <= G <= maxp, (G, maxp)
     if interpret is None:
         from repro.kernels import ops
         interpret = ops._auto_interpret()
@@ -266,21 +323,21 @@ def flash_decode(q, k_pool, v_pool, page_table, seq_lens, *,
     # pallas_call, in a --profile trace
     with jax.named_scope(f"flash_decode_B{B}_H{Hkv}x{rep}_ps{ps}"):
         out = pl.pallas_call(
-            functools.partial(_decode_kernel, maxp, ps, Hkv, scale),
+            functools.partial(_decode_kernel, maxp, ps, G, Hkv, scale),
             name="flash_decode",
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
-                grid=(B, maxp),
+                grid=(B, pl.cdiv(maxp, G)),
                 in_specs=[
-                    pl.BlockSpec((1, rep, hd), lambda b, j, *_: (b, 0, 0)),
+                    pl.BlockSpec((1, rep, hd), lambda b, g, *_: (b, 0, 0)),
                     pl.BlockSpec(memory_space=pl.ANY),
                     pl.BlockSpec(memory_space=pl.ANY),
                 ],
                 out_specs=pl.BlockSpec((1, rep, hd),
-                                       lambda b, j, *_: (b, 0, 0)),
+                                       lambda b, g, *_: (b, 0, 0)),
                 scratch_shapes=[
-                    pltpu.VMEM((2, ps, hd), k_pool.dtype),      # k page bufs
-                    pltpu.VMEM((2, ps, hd), v_pool.dtype),      # v page bufs
+                    pltpu.VMEM((2, G * ps, hd), k_pool.dtype),  # k groups
+                    pltpu.VMEM((2, G * ps, hd), v_pool.dtype),  # v groups
                     pltpu.SemaphoreType.DMA((2, 2)),
                     pltpu.VMEM((R, hd), cd),                    # queries
                     pltpu.VMEM((R, 1), jnp.float32),            # running max

@@ -143,6 +143,8 @@ def main():
               f"peak_pages={st['peak_pages']}/{st['num_pages']} "
               f"occupancy={st['decode_slot_ticks']}/"
               f"{args.slots * st['decode_ticks']} "
+              f"groups={st['decode_live_groups']}/"
+              f"{st['decode_grid_groups']} "
               f"traces={st['decode_traces']}/{st['prefill_traces']} "
               f"p50_lat={percentile(waits, 50) * 1e3:.1f}ms "
               f"p99_lat={percentile(waits, 99) * 1e3:.1f}ms")

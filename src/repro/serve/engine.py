@@ -29,9 +29,10 @@ is a page-table swap, never a cache copy.  Scheduler invariants:
   pointed at it during a decode tick, so their masked garbage writes
   never touch live pages;
 * decode attends through kernels/flash_attention.flash_decode under
-  engine="pallas" (page table on scalar prefetch, double-buffered
-  per-page HBM→VMEM DMA — the junction engine's prefetch+DMA idiom
-  applied to attention) and the gather+masked-softmax reference on jnp.
+  engine="pallas" (page table on scalar prefetch, a group of pages per
+  grid step, double-buffered per-page HBM→VMEM DMA — the junction
+  engine's prefetch+DMA idiom applied to attention) and the
+  gather+masked-softmax reference on jnp.
 
 Sampling is greedy or by temperature (one fold_in subkey per tick); a
 slot whose logits go non-finite is terminated and counted
@@ -49,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
+from repro.kernels.flash_attention import decode_pages_per_step
 from repro.models import model as M
 from repro.obs import telemetry as obs
 from repro.serve.paged import PagePool
@@ -255,6 +257,12 @@ class ContinuousEngine:
     ``stats["decode_slot_ticks"]`` sums the decoding slots over the
     decode ticks (occupancy is that over ``slots * decode_ticks``) and
     ``stats["peak_pages"]`` is the page high-water mark.
+    ``stats["decode_live_groups"]`` sums, over the decode ticks and the
+    decoding slots, the page groups ``flash_decode`` reads (a slot of n
+    tokens reads ceil(n / (G*ps)), G from ``decode_pages_per_step``);
+    ``stats["decode_grid_groups"]`` sums the groups its grid walks
+    (slots * ceil(maxp / G) a tick): the first over the second is the
+    share of the decode grid that does work.
 
     Each scheduler iteration runs inside the ``obs.span``s
     ``repro.serve.{admit,prefill,decode}`` (``fetch`` nested in the last
@@ -354,6 +362,10 @@ class ContinuousEngine:
         with obs.span("serve.setup", rec):
             pool_acct = PagePool(num_pages, ps)
             pool = M.make_paged_cache(self.cfg, num_pages, ps)
+            leaf = jax.tree.leaves(pool)[0]
+            G = decode_pages_per_step(ps, leaf.shape[-1],
+                                      leaf.dtype.itemsize, maxp)
+            rows, grid_groups = G * ps, B * -(-maxp // G)
             slots = [_Slot() for _ in range(B)]
             # FIFO within arrival order (stable sort keeps submission order)
             queue = collections.deque(sorted(requests,
@@ -369,6 +381,7 @@ class ContinuousEngine:
         n_arrived = 0
         tick = 0
         decode_ticks = prefill_chunks = decode_slot_ticks = 0
+        live_groups = all_groups = 0
         pf_cursor = 0               # round-robin over prefilling slots
         t_iter = t_serve0           # start of this scheduler iteration
 
@@ -495,6 +508,13 @@ class ContinuousEngine:
                         jnp.asarray(positions), jnp.asarray(pt), key)
                     decode_ticks += 1
                     decode_slot_ticks += len(dec)
+                    # the kernel attends positions + 1 tokens a slot
+                    live = int(np.sum(positions[dec] // rows + 1))
+                    live_groups += live
+                    all_groups += grid_groups
+                    if rec is not None:
+                        rec.count("serve.decode_live_groups", live)
+                        rec.count("serve.decode_grid_groups", grid_groups)
                     with obs.span("serve.fetch", rec):
                         tok, bad = np.asarray(tok), np.asarray(bad)
                     for i in dec:
@@ -520,6 +540,8 @@ class ContinuousEngine:
             "ticks": tick, "decode_ticks": decode_ticks,
             "prefill_chunks": prefill_chunks,
             "decode_slot_ticks": decode_slot_ticks,
+            "decode_live_groups": live_groups,
+            "decode_grid_groups": all_groups,
             "peak_pages": pool_acct.peak_in_use,
             "num_pages": num_pages, "page_size": ps,
             "wall_s": time.perf_counter() - t_serve0,

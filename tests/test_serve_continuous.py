@@ -96,6 +96,109 @@ def test_flash_decode_matches_reference_hd80(Hkv, rep):
         assert not np.any(np.asarray(got, np.float32)[0])
 
 
+# ------------------------------------------------ flash_decode page groups
+def _tail_sentinel_table(lens, ps, maxp):
+    """Page table giving each slot its own pages for its length, the
+    rest of each row the sentinel page 0."""
+    pt = np.zeros((len(lens), maxp), np.int32)
+    nxt = 1
+    for b, n in enumerate(lens):
+        for j in range(-(-n // ps)):
+            pt[b, j] = nxt
+            nxt += 1
+    return jnp.asarray(pt)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("Hkv,rep,D", [(4, 1, 80), (2, 4, 64)],
+                         ids=["mha_hd80", "gqa"])
+@pytest.mark.parametrize("G", [1, 2, 3, "maxp"])
+def test_flash_decode_page_groups_match_reference(G, Hkv, rep, D, dtype):
+    """G pages a grid step, maxp = 7 not a multiple of G = 2 or 3: lengths
+    at every group edge match the reference.  The TPU interpreter fills
+    unwritten VMEM with NaN and page 0 (every row's tail) is NaN too, so
+    a page read past a slot's length, or a stale buffer row that reaches
+    the accumulator, shows as a NaN."""
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels.flash_attention import flash_decode, paged_decode_ref
+    ps, maxp = 8, 7
+    G = maxp if G == "maxp" else G
+    lens = sorted({min(n, maxp * ps) for n in (
+        0, 1, ps, G * ps - 1, G * ps, G * ps + 1, maxp * ps)})
+    B = len(lens)
+    P = 1 + sum(-(-n // ps) for n in lens)
+    dt = jnp.dtype(dtype)
+    ks = jax.random.split(jax.random.PRNGKey(G), 3)
+    q = jax.random.normal(ks[0], (B, Hkv, rep, D)).astype(dt)
+    k_pool, v_pool = (jax.random.normal(k, (P, ps, Hkv * D)).astype(dt)
+                      for k in ks[1:])
+    pt = _tail_sentinel_table(lens, ps, maxp)
+    sl = jnp.asarray(lens, jnp.int32)
+    want = paged_decode_ref(q, k_pool, v_pool, pt, sl)
+    got = flash_decode(q, k_pool.at[0].set(jnp.nan),
+                       v_pool.at[0].set(jnp.nan), pt, sl, pages_per_step=G,
+                       interpret=pltpu.InterpretParams())
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    assert got.shape == q.shape and got.dtype == dt
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+    assert not np.any(np.asarray(got, np.float32)[0])   # length 0
+
+
+def test_decode_pages_per_step():
+    """The group size from shapes alone: 8 pages of stablelm-3b's KV
+    (16 x 32 heads x 80, bf16) a step, more for narrower rows, fewer for
+    f32, never more than the page table nor fewer than one."""
+    from repro.kernels.flash_attention import decode_pages_per_step
+    assert decode_pages_per_step(16, 2560, 2, 160) == 8
+    assert decode_pages_per_step(16, 1024, 2, 160) == 32
+    assert decode_pages_per_step(16, 2560, 4, 160) == 4
+    assert decode_pages_per_step(16, 2560, 2, 5) == 5
+    assert decode_pages_per_step(16, 64, 4, 3) == 3
+    assert decode_pages_per_step(1024, 8192, 4, 160) == 1
+
+
+def test_decode_group_counters(setup, monkeypatch):
+    """stats["decode_live_groups"] and ["decode_grid_groups"] (and the
+    Recorder's counts of the same names) are the arithmetic of each
+    tick's decoding slots: ceil((position + 1) / (G * ps)) a slot, and
+    slots x ceil(maxp / G) a tick, here with G pinned to 2 through the
+    byte budget the kernel and the engine share."""
+    from repro.kernels import flash_attention as fa
+    from repro.obs.telemetry import Recorder
+    cfg, params, prompts = setup
+    ps, max_seq = 8, 40                     # maxp 5: groups of 2 pages
+    page = ps * cfg.kv_heads * cfg.head_dim * 4
+    monkeypatch.setattr(fa, "DECODE_GROUP_BYTES", 2 * page)
+    rec = Recorder()
+    ce = ContinuousEngine(
+        dataclasses.replace(cfg, engine="pallas"), params,
+        ServeConfig(eos_token=-1, slots=2, page_size=ps, prefill_chunk=8,
+                    max_seq=max_seq), recorder=rec)
+    seen = []
+    tick = ce._tick
+
+    def spy(params, pool, token, positions, page_table, key):
+        seen.append((np.asarray(positions), np.asarray(page_table)))
+        return tick(params, pool, token, positions, page_table, key)
+
+    ce._tick = spy
+    ce.serve([Request(rid=i, prompt=prompts[i][: 3 + 4 * i],
+                      max_new_tokens=6 + 5 * i) for i in range(4)])
+    live = grid = 0
+    for pos, pt in seen:
+        dec = pt[:, 0] > 0                  # free slots read scratch page 0
+        live += sum(-(-(int(p) + 1) // (2 * ps)) for p in pos[dec])
+        grid += 2 * 3
+    assert live > len(seen)                 # some slots span two groups
+    st = ce.stats
+    assert (st["decode_live_groups"], st["decode_grid_groups"]) == (live,
+                                                                    grid)
+    assert rec.counters["serve.decode_live_groups"] == live
+    assert rec.counters["serve.decode_grid_groups"] == grid
+
+
 # -------------------------------------------------------- engine semantics
 @pytest.mark.parametrize("engine", ["jnp", "pallas"])
 def test_continuous_matches_static_greedy(setup, engine):

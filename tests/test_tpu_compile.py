@@ -146,12 +146,18 @@ def test_update_gated_dw_with_health_compiles(chip, E):
               kernel="junction_update_gated_dw")
 
 
-@pytest.mark.parametrize("hkv,rep,hd", [(32, 1, 80), (8, 8, 128)],
-                         ids=["mha_hd80", "gqa_hd128"])
-def test_flash_decode_compiles(chip, hkv, rep, hd):
+@pytest.mark.parametrize("slots,hkv,rep,hd,maxp,G", [
+    (8, 32, 1, 80, 36, 8), (8, 8, 8, 128, 36, 32),
+    (32, 32, 1, 80, 160, 8), (32, 8, 8, 128, 160, 32)],
+    ids=["mha_hd80", "gqa_hd128", "serve_cell_mha_hd80", "gqa_hd128_maxp160"])
+def test_flash_decode_compiles(chip, slots, hkv, rep, hd, maxp, G):
     """stablelm-3b's head_dim 80 is not a lane multiple: the merged
-    [P, ps, Hkv*hd] page layout keeps every DMA and load lane-aligned."""
-    slots, ps, maxp = 8, 16, 36
+    [P, ps, Hkv*hd] page layout keeps every DMA and load lane-aligned.
+    The serve cell's shapes (32 slots, pages of 16, max_seq 2560) read
+    groups of 8 pages a grid step, GQA's narrower rows 32; both grids
+    fit the default scoped VMEM."""
+    ps = 16
+    assert fa.decode_pages_per_step(ps, hkv * hd, 2, maxp) == G
     pool = chip((slots * maxp + 1, ps, hkv * hd))
     _compiled(lambda q, k, v, pt, n: fa.flash_decode(q, k, v, pt, n,
                                                      interpret=False),
